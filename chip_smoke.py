@@ -1,0 +1,596 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+Drives the main training path once, through the entry points a user
+calls, at the full width of the flagship model:
+
+    gluon.model_zoo.vision.resnet50_v1 (1000 classes, 224², NHWC + s2d)
+      -> parallel.ShardedTrainer(dtype="bfloat16")
+      -> capture.capture(trainer)
+      -> steps on a device-resident batch
+
+Weights are random, made from a seed; nothing is read from the network.
+Phases (any failure -> non-zero exit, no result line):
+
+  Device   jax.devices() must be TPU devices; versions, where the one
+           compile cache lives, whether the native record loader built.
+  Train    batch 256 on a {"dp": 1} mesh: 1 compile step + 5 steps; loss
+           finite and moving, every array on the TPU, one captured
+           executable, no eager fallback, no elastic OOM retry, a perf
+           ledger entry with flops and bytes. Then ten eager
+           gluon.Trainer.step calls of the MNIST MLP (eager donation).
+  Kernels  the Pallas flash kernel (forward, backward, one ring hop with
+           a traced offset), paged decode attention (bf16 and int8 KV)
+           and the int8 conv / FC ops, compiled, against the XLA dense
+           composition in f32-highest.
+  Four chips (when >= 4 are visible) the Train phase again on {"dp": 4}
+           at global batch 1024 in this same process, kvstore='tpu'
+           push/pull over four devices, and one {"sp": 4} flash ring.
+
+One process uses the chip: this script starts no process that needs it.
+Last stdout line on success, with the device as jax reports it:
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+Without an accelerator it exits 1 with one line and never continues on
+the CPU. ``--cpu-rehearsal`` (debugging only, never the default) runs
+the same code at toy sizes with kernels in interpret mode, says so on
+every phase line, and prints no result line.
+"""
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+import traceback
+
+# bf16 tolerance of the repo's own attention tests
+# (tests/test_ring_attention.py::test_bf16_inputs): 3e-2 absolute on
+# O(1) outputs; gradients are held to the same bound relative to the
+# reference's largest magnitude.
+BF16_ATOL = 3e-2
+
+FULL = {"image": 224, "batch": 256, "steps": 5,
+        "flash": [(2, 12, 1024, 64), (1, 4, 8192, 128)],
+        "hop": (1, 4, 1024, 64),
+        "decode": {"b": 8, "h": 12, "d": 64, "page": 16, "pages": 256},
+        # ResNet-18 stage-2 3x3 conv and the classifier, batch 128
+        "conv": {"data": (128, 128, 28, 28), "weight": (128, 128, 3, 3)},
+        "fc": {"data": (128, 512), "weight": (1000, 512)},
+        "ring_t": 4096}
+REHEARSAL = {"image": 64, "batch": 8, "steps": 2,
+             "flash": [(1, 2, 256, 64)],
+             "hop": (1, 2, 128, 64),
+             "decode": {"b": 2, "h": 2, "d": 32, "page": 8, "pages": 8},
+             "conv": {"data": (2, 8, 8, 8), "weight": (8, 8, 3, 3)},
+             "fc": {"data": (4, 32), "weight": (16, 32)},
+             "ring_t": 512}
+
+
+class SmokeFailure(AssertionError):
+    pass
+
+
+def check(cond, what):
+    if not cond:
+        raise SmokeFailure(what)
+
+
+def log(msg):
+    print(msg, flush=True)
+
+
+class CompileCounter:
+    """jax's own persistent-cache counters: compile requests that could
+    use the cache, and how many of them it answered."""
+
+    def __init__(self):
+        import jax
+
+        self.requests = self.hits = 0
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_event(self, event, **_):
+        if event == "/jax/compilation_cache/compile_requests_use_cache":
+            self.requests += 1
+        elif event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+
+    def line(self):
+        return (f"compile cache: {self.requests} requests, {self.hits} "
+                f"hits, {self.requests - self.hits} compiled")
+
+
+# ------------------------------------------------------------------ Device
+
+def device_phase(rehearsal):
+    import jax
+    import jaxlib
+
+    import mxnet_tpu as mx
+    from mxnet_tpu.io.record_pipeline import native_available
+    from mxnet_tpu.observability import perf
+    from mxnet_tpu.tune import schedule
+
+    dev = perf.device_record()
+    if dev["platform"] != "tpu" and not rehearsal:
+        sys.exit(f"chip_smoke: no chip — jax.devices() reports "
+                 f"{dev['count']} {dev['platform']} device(s) (JAX_PLATFORMS="
+                 f"{os.environ.get('JAX_PLATFORMS')!r}); not continuing "
+                 "on the CPU")
+    try:
+        import libtpu
+
+        libtpu_version = libtpu.__version__
+    except ImportError:
+        libtpu_version = "not installed"
+    log(f"device: platform={dev['platform']} kind={dev['kind']!r} "
+        f"count={dev['count']} | jax {jax.__version__} jaxlib "
+        f"{jaxlib.__version__} libtpu {libtpu_version} | JAX_PLATFORMS="
+        f"{os.environ.get('JAX_PLATFORMS')!r}")
+    log(f"compile cache dir: {jax.config.jax_compilation_cache_dir} "
+        f"(JAX_COMPILATION_CACHE_DIR="
+        f"{os.environ.get('JAX_COMPILATION_CACHE_DIR')!r})")
+    log(f"default context: {mx.current_context()} | native record loader: "
+        f"{native_available()} | schedule backend: "
+        f"{schedule.resolve_backend()}")
+    check(rehearsal or mx.current_context().device_type == "tpu",
+          "default context is not the chip")
+    return dev
+
+
+# ------------------------------------------------------------------- Train
+
+def build_net(image, tag):
+    import mxnet_tpu as mx
+    from mxnet_tpu.gluon.model_zoo import vision
+
+    t0 = time.perf_counter()
+    mx.random.seed(0)
+    net = vision.resnet50_v1(layout="NHWC", stem="s2d")
+    net.initialize(mx.initializer.Xavier())
+    net(mx.nd.zeros((2, 3, image, image))).wait_to_read()  # materialize
+    log(f"{tag} resnet50_v1 initialize + eager materializing forward: "
+        f"{time.perf_counter() - t0:.1f} s")
+    return net
+
+
+def make_batch(batch, image):
+    import numpy as np
+
+    rng = np.random.RandomState(0)
+    x = rng.rand(batch, 3, image, image).astype(np.float32)
+    y = (rng.rand(batch) * 1000).astype(np.float32)
+    return x, y
+
+
+def on_platform(tree, platform):
+    import jax
+
+    return all(d.platform == platform
+               for leaf in jax.tree_util.tree_leaves(tree)
+               for d in leaf.devices())
+
+
+def train_phase(net, n_chips, batch, sz, platform, tag):
+    """ShardedTrainer + capture on a {"dp": n_chips} mesh; returns the
+    trainer, the device-resident batch and the first-step loss."""
+    import math
+
+    import jax
+
+    from mxnet_tpu import capture, gluon, parallel
+    from mxnet_tpu.observability import perf
+    from mxnet_tpu.resilience import elastic
+
+    mesh = parallel.create_mesh({"dp": n_chips}, jax.devices()[:n_chips])
+    trainer = parallel.ShardedTrainer(
+        net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
+        {"learning_rate": 0.1, "momentum": 0.9}, mesh=mesh,
+        dtype="bfloat16")
+    step = capture.capture(trainer)
+    x, y = make_batch(batch, sz["image"])
+    xd = jax.device_put(x, trainer.batch_sharding)
+    yd = jax.device_put(y, trainer.batch_sharding)
+
+    capture.reset_stats()
+    oom0 = elastic.stats()["elastic_oom_events"]
+    ledger0 = set(perf.ledger())
+    t0 = time.perf_counter()
+    loss = step(xd, yd)
+    loss.block_until_ready()
+    compile_s = time.perf_counter() - t0
+    losses, step_s = [float(loss)], []
+    for _ in range(sz["steps"]):
+        t0 = time.perf_counter()
+        loss = step(xd, yd)
+        loss.block_until_ready()
+        step_s.append(time.perf_counter() - t0)
+        losses.append(float(loss))
+    log(f"{tag} dp={n_chips} batch={batch}: compile+first step "
+        f"{compile_s:.1f} s; steps "
+        f"{' '.join(f'{s * 1e3:.1f}' for s in step_s)} ms "
+        f"(wall around block_until_ready, {batch / min(step_s):.0f} img/s "
+        f"best); loss {' '.join(f'{v:.4f}' for v in losses)}")
+
+    check(all(math.isfinite(v) for v in losses), f"non-finite loss {losses}")
+    check(len(set(losses)) == len(losses), f"loss does not move: {losses}")
+    state = (trainer.params, trainer.aux, trainer.opt_state, xd, yd, loss)
+    check(on_platform(state, platform),
+          f"an array of the train phase is not on the {platform}")
+    s = capture.stats()
+    check(s["capture_fallback_eager"] == 0 and s["capture_retraces"] == 0
+          and s["capture_misses"] == 1,
+          f"capture did not stay on one executable: {s}")
+    check(elastic.stats()["elastic_oom_events"] == oom0,
+          "the step did not fit and was re-run as microbatches")
+    entries = [e for key, e in perf.ledger().items()
+               if key not in ledger0 and e["label"] == "sharded_step"]
+    check(len(entries) == 1 and entries[0]["flops"]
+          and entries[0]["bytes_accessed"],
+          f"perf ledger has no flops/bytes for this sharded_step: {entries}")
+    e = entries[0]
+    log(f"{tag} ledger sharded_step: {e['flops'] / 1e12:.2f} TFLOP, "
+        f"{e['bytes_accessed'] / 1e9:.1f} GB accessed, peak HBM "
+        f"{e['peak_hbm_bytes'] / 1e9:.2f} GB, compile "
+        f"{e['compile_ms'] / 1e3:.1f} s (XLA cost analysis); capture "
+        f"{ {k: v for k, v in s.items() if v} }")
+    return trainer, (xd, yd), losses[0]
+
+
+def eager_phase(kvstore, tag):
+    """Ten eager gluon.Trainer.step calls of the MNIST MLP
+    (examples/train_mnist.py's 128-64-10) on the default context: eager
+    donation is on for non-CPU devices, so a buffer deleted under a live
+    reader shows here."""
+    import math
+
+    import numpy as np
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import autograd, gluon
+
+    ctx = mx.current_context()
+    mx.random.seed(1)
+    net = gluon.nn.HybridSequential(prefix="smoke_mlp_")
+    with net.name_scope():
+        net.add(gluon.nn.Dense(128, activation="relu"),
+                gluon.nn.Dense(64, activation="relu"), gluon.nn.Dense(10))
+    net.initialize(mx.initializer.Xavier())
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 0.05, "momentum": 0.9},
+                            kvstore=kvstore)
+    loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
+    rng = np.random.RandomState(0)
+    centers = rng.rand(10, 784).astype(np.float32)
+    losses = []
+    for _ in range(10):
+        label = rng.randint(0, 10, 128)
+        data = centers[label] + rng.randn(128, 784).astype(np.float32) * 0.15
+        with autograd.record():
+            loss = loss_fn(net(mx.nd.array(data)),
+                           mx.nd.array(label.astype(np.float32)))
+        loss.backward()
+        trainer.step(128)
+        losses.append(float(loss.mean().asnumpy()))
+    log(f"{tag} eager MLP x10 on {ctx} kvstore={kvstore!r}: loss "
+        f"{losses[0]:.4f} -> {losses[-1]:.4f}")
+    check(all(math.isfinite(v) for v in losses) and losses[-1] < losses[0],
+          f"eager MLP did not train: {losses}")
+    for p in net.collect_params().values():
+        check(p.data().context == ctx and p.grad().context == ctx,
+              f"{p.name} left {ctx}")
+
+
+# ----------------------------------------------------------------- Kernels
+
+def dense_attention(q, k, v, causal, q_off=0, k_off=0):
+    """The XLA dense composition in f32-highest — the reference."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    with jax.default_matmul_precision("highest"):
+        q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+        if causal:
+            qpos = q_off + jnp.arange(q.shape[2])
+            kpos = k_off + jnp.arange(k.shape[2])
+            s = jnp.where(qpos[:, None] >= kpos[None, :], s, -1e30)
+        return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
+
+
+def max_err(a, b):
+    import jax.numpy as jnp
+
+    return float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                 - b.astype(jnp.float32))))
+
+
+def kernels_phase(sz, interpret, tag):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import mxnet_tpu as mx
+    from mxnet_tpu.ops.decode_attention import (kv_dequantize, kv_quantize,
+                                                paged_decode_attention)
+    from mxnet_tpu import tune
+    from mxnet_tpu.ops.pallas_kernels import (flash_attention_with_grad,
+                                              flash_attention_with_lse)
+
+    def qkv(shape, seed):
+        rs = np.random.RandomState(seed)
+        return [jnp.asarray(rs.randn(*shape) * 0.5, jnp.bfloat16)
+                for _ in range(3)]
+
+    # flash forward + backward, causal, bf16
+    for shape in sz["flash"]:
+        q, k, v = qkv(shape, 0)
+
+        def flash(q, k, v):
+            return flash_attention_with_grad(q, k, v, causal=True,
+                                             interpret=interpret)
+
+        def loss(fn):
+            return lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32)
+                                           ** 2)
+
+        def dense(q, k, v):
+            return dense_attention(q, k, v, True)
+
+        t0 = time.perf_counter()
+        out = jax.jit(flash)(q, k, v)
+        grads = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(q, k, v)
+        jax.block_until_ready((out, grads))
+        dt = time.perf_counter() - t0
+        ref = jax.jit(dense)(q, k, v)
+        gref = jax.jit(jax.grad(loss(dense), argnums=(0, 1, 2)))(q, k, v)
+        ferr = max_err(out, ref)
+        gerr = [max_err(g, r) / max(1.0, float(jnp.max(jnp.abs(r))))
+                for g, r in zip(grads, gref)]
+        log(f"{tag} flash fwd+bwd {shape} bf16 causal: fwd err {ferr:.4f}, "
+            f"grad err/scale {' '.join(f'{e:.4f}' for e in gerr)} "
+            f"(tol {BF16_ATOL}); compile+run {dt:.1f} s")
+        check(ferr <= BF16_ATOL and max(gerr) <= BF16_ATOL,
+              f"flash {shape} outside bf16 tolerance: {ferr} {gerr}")
+
+    # one ring hop: traced, non-zero k_offset (the scalar-prefetch path)
+    q, k, v = qkv(sz["hop"], 1)
+    t = sz["hop"][2]
+    hop = jax.jit(lambda q, k, v, qo, ko: flash_attention_with_lse(
+        q, k, v, causal=True, q_offset=qo, k_offset=ko,
+        interpret=interpret))
+    for qo, ko in ((2 * t, t), (t, t)):
+        out, lse = hop(q, k, v, jnp.int32(qo), jnp.int32(ko))
+        err = max_err(out, dense_attention(q, k, v, True, qo, ko))
+        log(f"{tag} ring hop {sz['hop']} q_offset={qo} k_offset={ko}: "
+            f"err {err:.4f}")
+        check(err <= BF16_ATOL and bool(jnp.all(jnp.isfinite(lse))),
+              f"ring hop q_offset={qo} k_offset={ko}: err {err}")
+
+    b_, h_, t_, d_ = sz["flash"][0]
+    blocks = tune.schedule.flash_fwd_blocks(b_ * h_, t_, d_, "bfloat16",
+                                            interpret=interpret)
+    st = tune.stats()
+    log(f"{tag} schedule table on backend "
+        f"{tune.schedule.resolve_backend(interpret)!r}: "
+        f"{st['autotune_table_hits']} hits, {st['autotune_table_misses']} "
+        f"misses; flash blocks at {sz['flash'][0]}: {blocks} (a miss runs "
+        "the legalized default)")
+
+    # paged decode attention over a >= 4k-token cache, bf16 and int8 KV
+    g = sz["decode"]
+    b, h, d, page, pages = g["b"], g["h"], g["d"], g["page"], g["pages"]
+    rs = np.random.RandomState(2)
+    qd = jnp.asarray(rs.randn(b, h, d) * 0.3, jnp.bfloat16)
+    kp, vp = [jnp.asarray(rs.randn(b * pages + 1, page, h, d) * 0.3,
+                          jnp.bfloat16) for _ in range(2)]
+    table = jnp.asarray(rs.permutation(b * pages).reshape(b, pages) + 1,
+                        jnp.int32)
+    lengths = jnp.asarray(rs.randint(page, pages * page + 1, b), jnp.int32)
+
+    def decode_ref(kp, vp):
+        # gather every sequence's pages and run the dense composition
+        kk = kp[table].reshape(b, pages * page, h, d).transpose(0, 2, 1, 3)
+        vv = vp[table].reshape(b, pages * page, h, d).transpose(0, 2, 1, 3)
+        with jax.default_matmul_precision("highest"):
+            s = jnp.einsum("bhd,bhkd->bhk", qd.astype(jnp.float32),
+                           kk.astype(jnp.float32)) / np.sqrt(d)
+            s = jnp.where(jnp.arange(pages * page)[None, None, :]
+                          < lengths[:, None, None], s, -1e30)
+            return jnp.einsum("bhk,bhkd->bhd", jax.nn.softmax(s, -1),
+                              vv.astype(jnp.float32))
+
+    out = jax.jit(lambda *a: paged_decode_attention(
+        *a, interpret=interpret))(qd, kp, vp, table, lengths)
+    err = max_err(out, decode_ref(kp, vp))
+    k8, ks = kv_quantize(kp.astype(jnp.float32))
+    v8, vs = kv_quantize(vp.astype(jnp.float32))
+    out8 = jax.jit(lambda q, k, v, tb, ln, ks, vs: paged_decode_attention(
+        q, k, v, tb, ln, k_scales=ks, v_scales=vs,
+        interpret=interpret))(qd, k8, v8, table, lengths, ks, vs)
+    err8 = max_err(out8, decode_ref(kv_dequantize(k8, ks),
+                                    kv_dequantize(v8, vs)))
+    log(f"{tag} paged decode b={b} h={h} d={d} cache={pages * page} tokens: "
+        f"bf16 err {err:.4f}, int8-KV err {err8:.4f} (vs dense on the "
+        "dequantized cache)")
+    check(err <= BF16_ATOL and err8 <= BF16_ATOL,
+          f"paged decode outside tolerance: bf16 {err}, int8 {err8}")
+
+    # int8 -> int32 conv and FC at one ResNet-18 layer shape, exact
+    rs = np.random.RandomState(3)
+
+    def s8(shape):
+        return rs.randint(-127, 128, shape).astype(np.int8)
+
+    one = mx.nd.array(np.float32([1.0]))
+    rng = (-one, one, -one, one)
+    data, weight = s8(sz["conv"]["data"]), s8(sz["conv"]["weight"])
+    # (no_bias=True ignores the bias slot; the weight fills it)
+    wq = mx.nd.array(weight, dtype="int8")
+    out = mx.nd.contrib.quantized_conv(
+        mx.nd.array(data, dtype="int8"), wq, wq, *rng, kernel=(3, 3),
+        pad=(1, 1), num_filter=weight.shape[0], no_bias=True)[0]
+    ref = jax.lax.conv_general_dilated(
+        jnp.asarray(data, jnp.int32), jnp.asarray(weight, jnp.int32),
+        (1, 1), ((1, 1), (1, 1)),
+        dimension_numbers=("NCHW", "OIHW", "NCHW"))
+    check(out.dtype == np.int32 and bool(jnp.array_equal(out.data_, ref)),
+          "quantized_conv differs from the int32 convolution")
+    data, weight = s8(sz["fc"]["data"]), s8(sz["fc"]["weight"])
+    wq = mx.nd.array(weight, dtype="int8")
+    out = mx.nd.contrib.quantized_fully_connected(
+        mx.nd.array(data, dtype="int8"), wq, wq, *rng,
+        num_hidden=weight.shape[0], no_bias=True)[0]
+    ref = jnp.asarray(data, jnp.int32) @ jnp.asarray(weight, jnp.int32).T
+    check(out.dtype == np.int32 and bool(jnp.array_equal(out.data_, ref)),
+          "quantized_fully_connected differs from the int32 matmul")
+    log(f"{tag} int8 conv {sz['conv']['data']}x{sz['conv']['weight']} and "
+        f"FC {sz['fc']['data']}x{sz['fc']['weight']}: int32 results exact "
+        f"on {out.context}")
+
+
+# -------------------------------------------------------------- Four chips
+
+def four_chip_phase(net, one_chip, sz, platform, interpret, tag):
+    """dp=4 at global batch 4x, checked against the one-chip trainer's
+    own loss function on the same global batch and the same weights."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import parallel
+
+    batch = 4 * sz["batch"]
+    trainer, (xd, yd), first = train_phase(net, 4, batch, sz, platform, tag)
+    check(all(len(p.sharding.device_set) == 4
+              for p in trainer.params.values()),
+          "a parameter is not placed on all four chips")
+    rows = [s.data.shape[0] for s in xd.addressable_shards]
+    check(rows == [sz["batch"]] * 4,
+          f"batch shards are {rows}, not 4 x {sz['batch']}")
+    in_use = [d.memory_stats()["bytes_in_use"] if d.memory_stats() else None
+              for d in jax.devices()[:4]]
+    log(f"{tag} bytes_in_use per chip: {in_use}")
+    check(platform != "tpu" or all(b and b > 100e6 for b in in_use),
+          f"a chip holds next to nothing: {in_use}")
+    check(trainer._capture_fp != one_chip._capture_fp,
+          "the dp=1 and dp=4 step programs share a capture fingerprint")
+
+    # the one-chip reference: the SAME compute_loss the step differentiates
+    # (ShardedTrainer._make_compute_loss), forward only, on the same global
+    # batch, from the same initial weights the net still holds
+    dev0 = jax.devices()[0]
+    x, y = make_batch(batch, sz["image"])
+    p0 = {k: jax.device_put(v, dev0)
+          for k, v in parallel.param_arrays(net).items()}
+    a0 = {k: jax.device_put(v, dev0)
+          for k, v in parallel.aux_arrays(net).items()}
+    ref = float(jax.jit(one_chip._make_compute_loss())(
+        p0, a0, jax.device_put(x, dev0), jax.device_put(y, dev0))[0])
+    log(f"{tag} first-step loss: dp=4 {first:.4f} vs one chip {ref:.4f} "
+        f"on the same {batch}-row batch")
+    check(abs(first - ref) <= BF16_ATOL * max(1.0, abs(ref)),
+          f"dp=4 loss {first} disagrees with the one-chip loss {ref}")
+
+    # kvstore='tpu': values committed to four different devices sum, and
+    # every puller gets the sum on ITS device; then the gluon.Trainer path
+    ctxs = [mx.Context(mx.current_context().device_type, i)
+            for i in range(4)]
+    kv = mx.kvstore.create("tpu")
+    kv.init("g", mx.nd.zeros((1024,), ctx=ctxs[0]))
+    kv.push("g", [mx.nd.ones((1024,), ctx=c) * (i + 1)
+                  for i, c in enumerate(ctxs)])
+    outs = [mx.nd.zeros((1024,), ctx=c) for c in ctxs]
+    kv.pull("g", out=outs)
+    for c, o in zip(ctxs, outs):
+        check(o.data_.devices() == {c.jax_device()}
+              and bool(np.all(o.asnumpy() == 10.0)),
+              f"kvstore('tpu') pull on {c}: devices {o.data_.devices()}, "
+              f"value {o.asnumpy()[:2]}")
+    log(f"{tag} kvstore('tpu') push/pull over {ctxs}: sum 10.0 on each")
+    eager_phase("tpu", tag)
+
+    # one {"sp": 4} ring with the flash kernel per hop
+    mesh = parallel.create_mesh({"sp": 4}, jax.devices()[:4])
+    rs = np.random.RandomState(4)
+    q, k, v = [jnp.asarray(rs.randn(1, 4, sz["ring_t"], 64) * 0.5,
+                           jnp.bfloat16) for _ in range(3)]
+    out = parallel.ring.ring_attention(q, k, v, mesh=mesh, causal=True,
+                                       impl="flash", interpret=interpret)
+    err = max_err(out, dense_attention(q, k, v, True))
+    log(f"{tag} ring attention sp=4 impl=flash T={sz['ring_t']}: err "
+        f"{err:.4f} over {len(out.sharding.device_set)} devices")
+    check(err <= BF16_ATOL and len(out.sharding.device_set) == 4,
+          f"sp=4 flash ring: err {err}")
+
+
+# -------------------------------------------------------------------- main
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--cpu-rehearsal", action="store_true",
+                    help="debugging only: toy sizes on the CPU, kernels in "
+                         "interpret mode; prints no result line")
+    args = ap.parse_args(argv)
+    rehearsal = args.cpu_rehearsal
+    sz = REHEARSAL if rehearsal else FULL
+    tag = "[CPU REHEARSAL — not a chip result]" if rehearsal else "[chip]"
+
+    t_start = time.perf_counter()
+    dev = device_phase(rehearsal)
+    if rehearsal and dev["platform"] == "tpu":
+        sys.exit("chip_smoke: --cpu-rehearsal is for a host without a chip")
+    counter = CompileCounter()
+    platform = dev["platform"]
+    failed = []
+    shared = {}
+
+    def run(name, fn):
+        # a phase boundary: report the failure with its traceback, run the
+        # remaining phases (one chip call should say everything it can),
+        # and fail the run at the end
+        t0 = time.perf_counter()
+        try:
+            fn()
+            log(f"{tag} phase {name}: ok ({time.perf_counter() - t0:.1f} s; "
+                f"{counter.line()})")
+        except Exception:
+            traceback.print_exc()
+            failed.append(name)
+            log(f"{tag} phase {name}: FAILED")
+
+    def train():
+        shared["net"] = build_net(sz["image"], tag)
+        shared["one_chip"] = train_phase(
+            shared["net"], 1, sz["batch"], sz, platform, tag)[0]
+        eager_phase("device", tag)
+
+    run("train", train)
+    run("kernels", lambda: kernels_phase(sz, rehearsal, tag))
+    if dev["count"] >= 4 and "one_chip" in shared:
+        run("four chips", lambda: four_chip_phase(
+            shared["net"], shared["one_chip"], sz, platform, rehearsal, tag))
+    elif dev["count"] >= 4:
+        failed.append("four chips (no one-chip trainer to compare with)")
+    else:
+        log(f"{tag} phase four chips: not run ({dev['count']} device(s))")
+
+    log(f"{tag} total {time.perf_counter() - t_start:.0f} s; "
+        f"{counter.line()}")
+    if failed:
+        log(f"{tag} FAILED phases: {', '.join(failed)}")
+        return 1
+    if rehearsal:
+        log("CPU REHEARSAL passed — this is not a chip result")
+        return 0
+    print(json.dumps({"ok": True, "device": dev}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

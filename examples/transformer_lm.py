@@ -69,9 +69,9 @@ def main():
     args = ap.parse_args()
 
     vocab = 64
-    # place on the accelerator when present — impl='flash' needs the
-    # Pallas kernel's TPU backend (mx.gpu maps to the TPU device)
-    ctx = mx.gpu() if mx.context.num_gpus() else mx.cpu()
+    # the default context is the chip when there is one; impl='flash'
+    # needs it (the Pallas kernel raises on a CPU device)
+    ctx = mx.current_context()
     with ctx:
         model = TransformerLM(vocab, args.units, args.heads, args.layers,
                               args.impl)

@@ -13,15 +13,33 @@ Usage mirrors the reference:
 from __future__ import annotations
 
 def _configure_jax():
-    """TPU-first numerics: float32 default (f64 is emulated/slow on TPU and
-    silently changes promotion semantics). Opt into x64 per-process with
-    MXNET_TPU_ENABLE_X64=1 (e.g. for float64 parity testing on CPU)."""
+    """Process-wide jax settings, resolved once at import.
+
+    Numerics: float32 default (f64 is emulated/slow on TPU and silently
+    changes promotion semantics). Opt into x64 per-process with
+    MXNET_TPU_ENABLE_X64=1 (e.g. for float64 parity testing on CPU).
+
+    Compile cache: ONE persistent XLA-executable cache per checkout.
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is jax's own setting and
+    nothing here touches it; otherwise the cache lives at the fixed
+    ``<checkout>/.jax_cache`` (the directory is part of what a later
+    process must find again, so never a temporary name, a pid or a
+    time). No other code sets a cache directory. Every executable is
+    kept, not only those past jax's 1 s compile-time floor: eager mode
+    compiles hundreds of small per-op programs, and a second process in
+    the same checkout should compile none of them again."""
     import os
 
-    if os.environ.get("MXNET_TPU_ENABLE_X64") == "1":
-        import jax
+    import jax
 
+    if os.environ.get("MXNET_TPU_ENABLE_X64") == "1":
         jax.config.update("jax_enable_x64", True)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(checkout, ".jax_cache"))
+    if not os.environ.get("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"):
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 
 _configure_jax()

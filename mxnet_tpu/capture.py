@@ -43,12 +43,13 @@ Three layers, all routed through the single sanctioned compile site
 3. **AOT compile cache** — with ``MXNET_TPU_COMPILE_CACHE=<dir>``,
    compiled programs are persisted as ``jax.export`` artifacts keyed by
    (program fingerprint, avals/sharding/donation signature, backend
-   topology) with the jax/jaxlib versions in the header, next to jax's
-   persistent XLA executable cache (``<dir>/xla``). A warm process
+   topology) with the jax/jaxlib versions in the header. A warm process
    deserializes the traced program (skipping Python tracing + lowering)
-   and re-links the XLA executable from the persistent cache (skipping
-   XLA compilation). Stale (version-mismatched) and corrupt artifacts
-   fall back to a fresh compile — never a crash.
+   and re-links the XLA executable from jax's persistent compilation
+   cache (skipping XLA compilation) — that cache's one directory is
+   resolved at import (``mxnet_tpu._configure_jax``), never here. Stale
+   (version-mismatched) and corrupt artifacts fall back to a fresh
+   compile — never a crash.
 
 Env knobs (docs/env_vars.md): ``MXNET_TPU_CAPTURE``,
 ``MXNET_TPU_COMPILE_CACHE``, ``MXNET_TPU_COMPILE_CACHE_MAX_MB``,
@@ -431,10 +432,9 @@ class CompileCache:
 
     Layout under the root: ``programs/<key>.aotx`` — a header (schema,
     jax/jaxlib versions, backend, payload SHA-256) followed by the
-    ``jax.export`` serialization of the traced program — and ``xla/``,
-    jax's persistent compilation cache of XLA *executables*, enabled for
-    the process when this cache is. A warm load therefore skips both
-    Python tracing/lowering (our artifact) and XLA compilation (jax's).
+    ``jax.export`` serialization of the traced program. A warm load
+    skips Python tracing/lowering; the XLA executable itself comes from
+    jax's persistent compilation cache (``mxnet_tpu._configure_jax``).
 
     Invalidation (docs/capture.md): the key hashes the caller's
     structural fingerprint + avals/sharding/donation signature + backend
@@ -448,70 +448,7 @@ class CompileCache:
     def __init__(self, root):
         self.root = root
         self.programs = os.path.join(root, "programs")
-        self.xla = os.path.join(root, "xla")
         os.makedirs(self.programs, exist_ok=True)
-        os.makedirs(self.xla, exist_ok=True)
-
-    def xla_subcache(self):
-        """Context manager pointing jax's persistent compilation cache at
-        ``<root>/xla`` for the duration of one capture/AOT compile, so
-        the XLA-executable layer persists too — WITHOUT leaving a
-        zero-threshold global cache armed for every unrelated jit in the
-        process. An operator-configured cache dir is left alone. The
-        sticky "cache checked" latch is reset on both transitions so the
-        scoped enable works mid-process."""
-        import contextlib
-
-        import jax
-
-        @contextlib.contextmanager
-        def scoped():
-            try:
-                # everything fallible (private-API import included) is
-                # probed BEFORE the first config.update, so an
-                # unsupported jax can never strand a partially-applied
-                # zero-threshold cache config on the whole process
-                prior_dir = jax.config.jax_compilation_cache_dir
-                if prior_dir:
-                    yield  # operator-configured: leave it alone
-                    return
-                prior = {
-                    "jax_compilation_cache_dir": prior_dir,
-                    "jax_persistent_cache_min_compile_time_secs":
-                        jax.config.jax_persistent_cache_min_compile_time_secs,
-                    "jax_persistent_cache_min_entry_size_bytes":
-                        jax.config.jax_persistent_cache_min_entry_size_bytes,
-                }
-                from jax._src import compilation_cache as _cc
-            except Exception:  # XLA layer unsupported: program layer only
-                yield
-                return
-            try:
-                jax.config.update("jax_compilation_cache_dir", self.xla)
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs", 0.0)
-                jax.config.update(
-                    "jax_persistent_cache_min_entry_size_bytes", 0)
-                _cc.reset_cache()
-            except Exception:
-                for k, v in prior.items():  # roll back a partial apply
-                    try:
-                        jax.config.update(k, v)
-                    except Exception:
-                        pass
-                yield
-                return
-            try:
-                yield
-            finally:
-                try:
-                    for k, v in prior.items():
-                        jax.config.update(k, v)
-                    _cc.reset_cache()
-                except Exception:
-                    pass
-
-        return scoped()
 
     # ------------------------------------------------------------------ keys
     def key(self, label, fingerprint, sig):
@@ -604,23 +541,22 @@ class CompileCache:
     def gc(self, limit_bytes=None):
         """Size-cap eviction: while the cache exceeds
         ``MXNET_TPU_COMPILE_CACHE_MAX_MB``, delete the oldest-mtime
-        files (program artifacts and XLA-cache entries alike)."""
+        program artifacts."""
         limit = _cache_limit_bytes() if limit_bytes is None else limit_bytes
         entries = []
         total = 0
-        for d in (self.programs, self.xla):
+        try:
+            names = os.listdir(self.programs)
+        except OSError:
+            names = []
+        for name in names:
+            p = os.path.join(self.programs, name)
             try:
-                names = os.listdir(d)
+                st = os.stat(p)
             except OSError:
                 continue
-            for name in names:
-                p = os.path.join(d, name)
-                try:
-                    st = os.stat(p)
-                except OSError:
-                    continue
-                entries.append((st.st_mtime, st.st_size, p))
-                total += st.st_size
+            entries.append((st.st_mtime, st.st_size, p))
+            total += st.st_size
         if total <= limit:
             return 0
         evicted = 0
@@ -659,12 +595,9 @@ def compile_cache():
 
 def _precompile(jitted, example_args):
     """Force trace + XLA compile now (build time), so first-step latency
-    never lands inside an armed watchdog guard. Falls back to the lazy
-    jitted callable for programs AOT lowering can't specialize."""
-    try:
-        return jitted.lower(*example_args).compile()
-    except Exception:
-        return jitted
+    never lands inside an armed watchdog guard and a compile failure
+    (a kernel Mosaic refuses, an OOM at link) surfaces here, named."""
+    return jitted.lower(*example_args).compile()
 
 
 def aot_compile(fn, *, label, fingerprint, example_args, sig=None,
@@ -673,8 +606,9 @@ def aot_compile(fn, *, label, fingerprint, example_args, sig=None,
     traced program via the AOT cache when enabled.
 
     Warm path: deserialize the artifact (skips Python tracing and
-    lowering) and compile its ``call`` — which the persistent XLA
-    subcache resolves to a stored executable (skips XLA compilation).
+    lowering) and compile its ``call`` — which jax's persistent
+    compilation cache resolves to a stored executable (skips XLA
+    compilation).
     Cold path: jit ``fn``, export with ``example_args``, store. Both
     paths execute the exported program form when a cache is configured,
     so cold and warm runs are bitwise-identical by construction.
@@ -718,14 +652,12 @@ def aot_compile(fn, *, label, fingerprint, example_args, sig=None,
         except Exception:
             # program not exportable (callbacks, unsupported primitive):
             # serve the plain executable; persistence is best-effort
-            with cache.xla_subcache():
-                return _ledger(_precompile(jitted, example_args))
+            return _ledger(_precompile(jitted, example_args))
     else:
         _STATS["aot_cache_hits"] += 1
     wrapped = _compile_jit(exported.call,
                            {"donate_argnums": donate_argnums or None})
-    with cache.xla_subcache():
-        return _ledger(_precompile(wrapped, example_args), aot_hit=aot_hit)
+    return _ledger(_precompile(wrapped, example_args), aot_hit=aot_hit)
 
 
 def _avals_sig(args):
@@ -1094,20 +1026,6 @@ class CapturedTrainerStep:
 
     # ------------------------------------------------------------------ build
     def _build(self, x_nd, y_nd, batch_size, sig):
-        """Discovery + capture + compile, with the XLA subcache scoped
-        around the WHOLE build when persistence is on: the discovery
-        pass's per-op eager executables then also resolve from the
-        persistent cache, so a warm cold-start skips those compiles too,
-        not just the whole-program one."""
-        import contextlib
-
-        cache = compile_cache()
-        scope = cache.xla_subcache() if cache is not None \
-            else contextlib.nullcontext()
-        with scope:
-            return self._build_inner(x_nd, y_nd, batch_size, sig)
-
-    def _build_inner(self, x_nd, y_nd, batch_size, sig):
         import jax.numpy as jnp
 
         from .jit import TraceSession

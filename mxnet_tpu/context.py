@@ -9,6 +9,7 @@ HBM pooling, streams and copy engines are PJRT's job.
 """
 from __future__ import annotations
 
+import os
 import threading
 
 from .base import MXNetError
@@ -75,26 +76,33 @@ class Context:
             return self._jax_device
         jax = _jax()
         dt = self.device_type
+        # Addressable devices only: under jax.distributed, jax.devices()
+        # is the GLOBAL list and device 0 may belong to another process.
         if dt in ("cpu", "cpu_pinned", "cpu_shared"):
-            # Addressable devices only: under jax.distributed, jax.devices()
-            # is the GLOBAL list and device 0 may belong to another process.
-            devs = [d for d in jax.devices("cpu")
-                    if d.process_index == jax.process_index()]
+            try:
+                devs = jax.local_devices(backend="cpu")
+            except RuntimeError as e:
+                raise MXNetError(
+                    f"{self}: jax's cpu backend is not initialised "
+                    f"(JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r} "
+                    "excludes it); add 'cpu' to JAX_PLATFORMS or leave it "
+                    "unset for explicit host placement") from e
         else:  # tpu / gpu both mean "the local accelerator"
             devs = _accelerator_devices()
-            if devs:
-                local = [d for d in devs
-                         if d.process_index == jax.process_index()]
-                devs = local or devs
             if not devs:
-                # Fall back to whatever the default platform offers (CPU when
-                # running the test suite with JAX_PLATFORMS=cpu).
-                devs = jax.local_devices()
+                raise MXNetError(
+                    f"{self}: no accelerator — jax.local_devices() reports "
+                    f"only {jax.default_backend()} devices. An accelerator "
+                    "context never resolves to the host CPU; use mx.cpu() "
+                    "or the default context")
         if self.device_id >= len(devs):
             raise MXNetError(
                 f"{self}: only {len(devs)} device(s) of this type are visible"
             )
         self._jax_device = devs[self.device_id]
+        if self._jax_device.platform != "cpu":
+            global _HELD_ACCELERATOR
+            _HELD_ACCELERATOR = self._jax_device.platform
         return self._jax_device
 
     def empty_cache(self):
@@ -105,10 +113,21 @@ class Context:
         gc.collect()
 
 
+# Platform of the accelerator this process has opened through a Context
+# (None while it has opened none). A chip belongs to one process: once
+# set, no child of this process can open the chip.
+_HELD_ACCELERATOR = None
+
+
+def held_accelerator():
+    """'tpu' once this process has resolved an accelerator context (and so
+    holds the chip), else None — answered without touching jax, so a
+    parent that must stay off the chip can ask."""
+    return _HELD_ACCELERATOR
+
+
 def _accelerator_devices():
-    jax = _jax()
-    devs = [d for d in jax.devices() if d.platform not in ("cpu",)]
-    return devs
+    return [d for d in _jax().local_devices() if d.platform != "cpu"]
 
 
 def cpu(device_id=0):
@@ -137,10 +156,14 @@ def num_gpus():
 
 
 def current_context():
+    """The innermost ``with ctx:`` scope, else the first local device of
+    jax's default backend: ``tpu(0)`` on a chip host, ``cpu(0)`` under
+    ``JAX_PLATFORMS=cpu`` — arrays, parameters and predictors created
+    without a ``ctx`` land on the chip when there is one."""
     stack = getattr(Context._default_ctx, "stack", None)
     if stack:
         return stack[-1]
-    return Context("cpu", 0)
+    return Context("cpu" if _jax().default_backend() == "cpu" else "tpu", 0)
 
 
 def context_from_jax_device(dev):

@@ -17,7 +17,7 @@ from collections import OrderedDict
 import numpy as _np
 
 from ..base import MXNetError
-from ..context import Context, cpu, current_context
+from ..context import Context, current_context
 from .. import ndarray as nd
 from .. import initializer
 
@@ -149,7 +149,10 @@ class Parameter:
         from ..jit import no_trace
         with autograd.pause(), no_trace():
             if data is None:
-                data = nd.zeros(self._shape, dtype=self.dtype, ctx=cpu())
+                # initialized where it will live: staging on the host
+                # would need a cpu backend the process may not have
+                # (JAX_PLATFORMS=tpu) and a transfer per parameter
+                data = nd.zeros(self._shape, dtype=self.dtype, ctx=ctx[0])
                 if isinstance(init, str):
                     init = initializer.create(init)
                 init(initializer.InitDesc(self.name), data)

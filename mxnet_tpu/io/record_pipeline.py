@@ -77,25 +77,31 @@ def load_native():
             return _lib
         _lib_tried = True
         path = _lib_path()
-        if not os.path.exists(path):
-            src = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__)))), "src", "io")
-            if os.path.isdir(src):
-                try:
-                    # Serialize the build across processes (multi-rank
-                    # launches all race here on a fresh checkout).
-                    os.makedirs(os.path.dirname(path), exist_ok=True)
-                    import fcntl
+        src = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))), "src", "io")
+        if os.path.isdir(src):
+            # In a checkout the binary is a build product of src/io: run
+            # make every time (a no-op when the binary is newer than its
+            # source), so a stale binary never shadows an edited source.
+            # A missing make / g++ / opencv is the visible downgrade, and
+            # a binary that could not be checked against its source is
+            # not loaded.
+            try:
+                # Serialize the build across processes (multi-rank
+                # launches all race here on a fresh checkout).
+                os.makedirs(os.path.dirname(path), exist_ok=True)
+                import fcntl
 
-                    with open(path + ".buildlock", "w") as lock:
-                        fcntl.flock(lock, fcntl.LOCK_EX)
-                        if not os.path.exists(path):
-                            subprocess.run(["make", "-C", src], check=True,
-                                           capture_output=True)
-                except (OSError, subprocess.CalledProcessError) as e:
-                    warnings.warn(f"native data pipeline build failed ({e}); "
-                                  "falling back to the Python loader")
-                    return None
+                with open(path + ".buildlock", "w") as lock:
+                    fcntl.flock(lock, fcntl.LOCK_EX)
+                    subprocess.run(["make", "-C", src], check=True,
+                                   capture_output=True)
+            except (OSError, subprocess.CalledProcessError) as e:
+                tail = (getattr(e, "stderr", None) or b"").decode(
+                    errors="replace")[-300:]
+                warnings.warn(f"native data pipeline build failed ({e}) "
+                              f"{tail}; falling back to the Python loader")
+                return None
         if not os.path.exists(path):
             return None
         try:
@@ -124,6 +130,9 @@ def load_native():
 
 
 def native_available():
+    """Whether the native (C++) record pipeline is in use; False means
+    the Python loader is — chip_smoke.py prints this so the downgrade is
+    visible."""
     return load_native() is not None
 
 

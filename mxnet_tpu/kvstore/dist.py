@@ -201,11 +201,10 @@ def init_distributed(coordinator=None, num_processes=None, process_id=None,
 
     Non-coordinator ranks first PROBE the coordinator's TCP endpoint
     under this retry/deadline loop and only then enter
-    jax.distributed.initialize. This matters: some jax/XLA versions
-    (e.g. 0.4.37) LOG(FATAL) and abort the whole process when the
-    coordination handshake times out, so the unreachable-peer case must
-    be caught before jax ever sees it. Rank 0 hosts the service and
-    needs no probe.
+    jax.distributed.initialize. This matters: the coordination client
+    LOG(FATAL)s and aborts the whole process when the handshake times
+    out, so the unreachable-peer case must be caught before jax ever
+    sees it. Rank 0 hosts the service and needs no probe.
 
     Raises DistConfigError for invalid env combinations and TimeoutError
     when the coordinator stays unreachable past the deadline.
@@ -321,25 +320,16 @@ def _safe_shutdown(jax):
 
 
 def _jax_dist_init(jax, coordinator, num_processes, process_id, remaining):
-    """One bootstrap attempt, bounded by the remaining deadline when this
-    jax version supports initialization_timeout (older versions fall back
-    to jax's internal default — the socket probe above still bounds the
-    unreachable-coordinator case)."""
-    try:
-        # CPU hosts run cross-process collectives over Gloo; without
-        # this the CPU backend refuses multiprocess computations
-        # outright. Must land before the backend initializes (it does:
-        # nothing may touch jax before jax.distributed.initialize).
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass  # older jax: CPU collectives are implicit or absent
-    kwargs = dict(coordinator_address=coordinator,
-                  num_processes=num_processes, process_id=process_id)
-    try:
-        jax.distributed.initialize(
-            initialization_timeout=max(1, int(remaining)), **kwargs)
-    except TypeError:
-        jax.distributed.initialize(**kwargs)
+    """One bootstrap attempt, bounded by the remaining deadline."""
+    # CPU hosts run cross-process collectives over Gloo; without this
+    # the CPU backend refuses multiprocess computations outright. Must
+    # land before the backend initializes (it does: nothing may touch
+    # jax before jax.distributed.initialize).
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
+    jax.distributed.initialize(
+        coordinator_address=coordinator, num_processes=num_processes,
+        process_id=process_id,
+        initialization_timeout=max(1, int(remaining)))
 
 
 def is_distributed():

@@ -107,6 +107,8 @@ class KVStore:
                 self._data[k]._set_data(merged._data)
 
     def pull(self, key, out=None, priority=0, ignore_sparse=True):
+        import jax
+
         from ..ops import registry as _registry
 
         keys, outs = _pairs(key, out)
@@ -122,8 +124,18 @@ class KVStore:
             store = self._data[k]
             if hasattr(store, "_force"):
                 _registry.mark_shared(store._force())
+            src = self._data[k]._data
+            src_dev = _single_device(src)
             for t in targets:
-                t._set_data(self._data[k]._data)
+                dev = t.context.jax_device()
+                if src_dev is not None and dev != src_dev:
+                    # a target on another device gets its own copy THERE;
+                    # sharing the store's buffer would leave a cell whose
+                    # context names one device and whose data sits on
+                    # another
+                    t._set_data(jax.device_put(src, dev))
+                else:
+                    t._set_data(src)
 
     def pushpull(self, key, value, out=None, priority=0):
         self.push(key, value, priority)
@@ -285,11 +297,19 @@ class KVStoreTPU(KVStore):
     def _reduce(self, values):
         if len(values) == 1:
             return values[0]
+        import jax
         import jax.numpy as jnp
 
-        datas = [v._data for v in values]
-        acc = datas[0]
-        for d in datas[1:]:
+        acc = values[0]._data
+        acc_dev = _single_device(acc)
+        for v in values[1:]:
+            d = v._data
+            if acc_dev is not None and \
+                    _single_device(d) not in (None, acc_dev):
+                # a value committed to another device: a jitted add
+                # refuses operands on different devices, so bring it
+                # over the interconnect first
+                d = jax.device_put(d, acc_dev)
             acc = jnp.add(acc, d)
         return NDArray(acc, values[0].context)
 
@@ -317,6 +337,18 @@ class KVStoreTPU(KVStore):
         worker-ring comparison."""
         self.state_fingerprint(named)  # folding must succeed everywhere
         return True
+
+
+def _single_device(data):
+    """The one device a concrete array lives on. None for a tracer (inside
+    a captured step placement belongs to the program) and for a global
+    array spanning several devices (its sharding already says where)."""
+    import jax
+
+    if isinstance(data, jax.core.Tracer):
+        return None
+    devs = data.devices()
+    return next(iter(devs)) if len(devs) == 1 else None
 
 
 def _pairs(key, value):

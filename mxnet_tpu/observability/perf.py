@@ -35,9 +35,11 @@ on, in three layers:
 3. **Derived gauges — MFU and roofline fraction.** From (1)+(2):
    ``mfu = flops / (device_s · peak_flops)`` and
    ``roofline_fraction = bytes_accessed / (device_s · peak_bw)`` per
-   executable, against nominal per-backend peaks (TPU / GPU / CPU
-   fallback; override with ``MXNET_TPU_PERF_PEAK_FLOPS`` /
-   ``MXNET_TPU_PERF_PEAK_GBPS``). Device time here is the full
+   executable, against the published peaks of the device the program
+   runs on (:data:`DEVICE_PEAKS`, keyed by jax ``device_kind``; a kind
+   that is not in the table leaves both gauges ``None`` — it never
+   borrows another device's number; ``MXNET_TPU_PERF_PEAK_FLOPS`` /
+   ``MXNET_TPU_PERF_PEAK_GBPS`` supply one). Device time here is the full
    dependency-chained wall (dispatch included) — an upper bound on
    device busy time, so the gauges are conservative.
 
@@ -59,7 +61,9 @@ from . import metrics as _metrics
 __all__ = ["LEDGER_FIELDS", "note_compile", "note_execution", "timed_call",
            "ledger", "device_timed_entries", "ledger_key",
            "combined_fingerprint", "snapshot", "clear", "update_gauges",
-           "device_time_enabled", "set_device_time", "nominal_peaks"]
+           "device_time_enabled", "set_device_time", "DEVICE_PEAKS",
+           "device_peaks", "nominal_peaks", "device_record",
+           "require_chip"]
 
 _LOCK = threading.Lock()
 _LEDGER: dict = {}
@@ -85,8 +89,8 @@ LEDGER_FIELDS = (
     "device_calls",          # dependency-chained timed executions (device mode)
     "device_ms",             # EWMA of blocked wall per execution (device mode)
     "dispatch_ms",           # EWMA of the async call returning (device mode)
-    "mfu",                   # flops / (device_s * nominal peak flops)
-    "roofline_fraction",     # bytes_accessed / (device_s * nominal HBM bandwidth)
+    "mfu",                   # flops / (device_s * peak flops); None for an unknown device_kind
+    "roofline_fraction",     # bytes_accessed / (device_s * peak HBM bandwidth); None likewise
     "t",                     # wall-clock of the latest compile
 )
 
@@ -98,15 +102,16 @@ _DEVICE_TIME = os.environ.get("MXNET_TPU_OBS_DEVICE_TIME", "").strip() in (
 # that a real regression shows within ~10 steps.
 _EWMA = 0.3
 
-# Nominal per-backend roofs for the MFU/roofline gauges: (flops/s,
-# HBM bytes/s). Order-of-magnitude nominals — TPU v4 bf16 MXU + HBM2e,
-# A100-class GPU, and a deliberately conservative CPU fallback so the
-# gauges are *defined* everywhere tests run. Real deployments override
-# per host with MXNET_TPU_PERF_PEAK_FLOPS / MXNET_TPU_PERF_PEAK_GBPS.
-_NOMINAL_PEAKS = {
-    "tpu": (275.0e12, 1228.0e9),
-    "gpu": (312.0e12, 2039.0e9),
-    "cpu": (2.0e11, 5.0e10),
+# THE peaks table: published per-chip peaks keyed by jax ``device_kind``
+# (``jax.devices()[0].device_kind``). bench.py, chip_smoke.py and the
+# MFU / roofline gauges all read it; there is no second copy.
+# Source: Google Cloud TPU documentation, "TPU v5e" (system
+# architecture): 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM2e at
+# 819 GB/s per chip.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"bf16_flops_per_s": 197.0e12,
+                    "int8_ops_per_s": 393.0e12,
+                    "hbm_bytes_per_s": 819.0e9},
 }
 
 
@@ -124,20 +129,62 @@ def set_device_time(flag):
     return prev
 
 
-def nominal_peaks(backend=None):
-    """(peak_flops_per_s, peak_hbm_bytes_per_s) for ``backend``
-    (default: jax's default backend, 'cpu' when jax is unavailable),
-    with the env overrides applied."""
-    if backend is None:
-        try:
-            import jax
+def device_record():
+    """The device record every measurement prints: ``{"platform",
+    "kind", "count"}`` exactly as jax reports them."""
+    import jax
 
-            backend = jax.default_backend()
-        except Exception:
-            backend = "cpu"
-    flops, bw = _NOMINAL_PEAKS.get(backend, _NOMINAL_PEAKS["cpu"])
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
+
+
+def require_chip():
+    """:func:`device_record`, or ``MXNetError`` when jax's default
+    backend is the CPU. A measurement path that finds no chip fails; it
+    never falls back to the CPU."""
+    from ..base import MXNetError
+
+    dev = device_record()
+    if dev["platform"] == "cpu":
+        raise MXNetError(
+            f"no accelerator: jax.devices() reports only {dev['count']} "
+            f"cpu device(s) (JAX_PLATFORMS="
+            f"{os.environ.get('JAX_PLATFORMS')!r}); device metrics come "
+            "from a chip run, never from XLA-CPU")
+    return dev
+
+
+def device_peaks(device_kind=None):
+    """The :data:`DEVICE_PEAKS` row for ``device_kind`` (default: the
+    first device of jax's default backend). An unknown kind raises
+    ``KeyError`` naming it — a measurement never borrows another
+    device's peak."""
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
     try:
-        flops = float(os.environ.get("MXNET_TPU_PERF_PEAK_FLOPS") or flops)
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device_kind {device_kind!r} "
+            f"(known: {sorted(DEVICE_PEAKS)}); add a sourced row to "
+            "observability.perf.DEVICE_PEAKS") from None
+
+
+def nominal_peaks(device_kind=None):
+    """(peak_flops_per_s, peak_hbm_bytes_per_s) the MFU / roofline gauges
+    divide by: the env overrides where set, else the table's bf16 and
+    HBM peaks for ``device_kind``, else ``None`` (gauge undefined)."""
+    try:
+        row = device_peaks(device_kind)
+        flops, bw = row["bf16_flops_per_s"], row["hbm_bytes_per_s"]
+    except KeyError:
+        flops = bw = None
+    try:
+        flops = float(os.environ.get("MXNET_TPU_PERF_PEAK_FLOPS") or 0) \
+            or flops
     except ValueError:
         pass
     try:
@@ -179,16 +226,12 @@ def combined_fingerprint(fingerprint, sig):
 
 def _cost_numbers(compiled):
     """(flops, bytes_accessed) from a compiled executable's XLA cost
-    analysis; (None, None) when the backend doesn't expose it. jax
-    returns either a per-computation list of dicts or one dict."""
-    try:
-        ca = compiled.cost_analysis()
-    except Exception:
+    analysis — one flat dict on this jax; (None, None) for an object
+    that is not a compiled executable (tests seed entries with one)."""
+    analyze = getattr(compiled, "cost_analysis", None)
+    if analyze is None:
         return None, None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    if not isinstance(ca, dict):
-        return None, None
+    ca = analyze()
     flops = ca.get("flops")
     acc = ca.get("bytes accessed")
     return (float(flops) if flops is not None else None,
@@ -196,23 +239,22 @@ def _cost_numbers(compiled):
 
 
 def _memory_numbers(compiled):
-    """Memory footprint dict from ``memory_analysis()``; zeros when
-    unavailable. ``peak_hbm_bytes`` is the standard estimate
-    argument + output + temp + generated_code − alias (donated buffers
-    alias their inputs and must not be double-counted), clamped at 0."""
+    """Memory footprint dict from ``memory_analysis()``; zeros for an
+    object that is not a compiled executable. ``peak_hbm_bytes`` is the
+    standard estimate argument + output + temp + generated_code − alias
+    (donated buffers alias their inputs and must not be double-counted),
+    clamped at 0."""
     out = {"argument_bytes": 0, "output_bytes": 0, "temp_bytes": 0,
            "generated_code_bytes": 0, "peak_hbm_bytes": 0}
-    try:
-        ma = compiled.memory_analysis()
-    except Exception:
+    analyze = getattr(compiled, "memory_analysis", None)
+    if analyze is None:
         return out
-    if ma is None:
-        return out
-    arg = int(getattr(ma, "argument_size_in_bytes", 0) or 0)
-    outp = int(getattr(ma, "output_size_in_bytes", 0) or 0)
-    tmp = int(getattr(ma, "temp_size_in_bytes", 0) or 0)
-    gen = int(getattr(ma, "generated_code_size_in_bytes", 0) or 0)
-    alias = int(getattr(ma, "alias_size_in_bytes", 0) or 0)
+    ma = analyze()
+    arg = int(ma.argument_size_in_bytes)
+    outp = int(ma.output_size_in_bytes)
+    tmp = int(ma.temp_size_in_bytes)
+    gen = int(ma.generated_code_size_in_bytes)
+    alias = int(ma.alias_size_in_bytes)
     out.update(argument_bytes=arg, output_bytes=outp, temp_bytes=tmp,
                generated_code_bytes=gen,
                peak_hbm_bytes=max(0, arg + outp + tmp + gen - alias))
@@ -222,19 +264,13 @@ def _memory_numbers(compiled):
 def note_compile(label, fingerprint, compiled, compile_s, aot_hit=False):
     """Record one compile into the ledger (called from
     ``capture.aot_compile`` for every captured/serving executable).
-    ``compiled`` may be a lazily-jitted fallback without analysis
-    methods — the entry still lands with the wall compile time, so
-    `every executable has a ledger entry` holds even where XLA hides
-    its cost model. Returns the ledger key."""
+    Returns the ledger key."""
+    import jax
+
     key = ledger_key(label, fingerprint)
     flops, acc = _cost_numbers(compiled)
     mem = _memory_numbers(compiled)
-    try:
-        import jax
-
-        backend = jax.default_backend()
-    except Exception:
-        backend = "cpu"
+    backend = jax.default_backend()
     with _LOCK:
         entry = _LEDGER.get(key)
         if entry is None:
@@ -278,10 +314,10 @@ def note_execution(label, fingerprint, blocked_s, dispatch_s=0.0):
         entry["device_calls"] = n + 1
         dev_s = entry["device_ms"] / 1e3
         if dev_s > 0:
-            peak_flops, peak_bw = nominal_peaks(entry["backend"])
-            if entry["flops"]:
+            peak_flops, peak_bw = nominal_peaks()
+            if entry["flops"] and peak_flops:
                 entry["mfu"] = entry["flops"] / (dev_s * peak_flops)
-            if entry["bytes_accessed"]:
+            if entry["bytes_accessed"] and peak_bw:
                 entry["roofline_fraction"] = \
                     entry["bytes_accessed"] / (dev_s * peak_bw)
     _STATS["perf_device_timings"] += 1
@@ -342,6 +378,7 @@ def snapshot():
     constants they were judged against + the timing-mode flag."""
     peak_flops, peak_bw = nominal_peaks()
     return {"entries": ledger(),
+            # None for a device_kind outside DEVICE_PEAKS (XLA-CPU)
             "peaks": {"flops_per_s": peak_flops, "hbm_bytes_per_s": peak_bw},
             "device_time": _DEVICE_TIME}
 
@@ -374,11 +411,11 @@ _DEVICE_MS = _metrics.gauge(
     "(MXNET_TPU_OBS_DEVICE_TIME)", labels=("executable",))
 _MFU = _metrics.gauge(
     "mxnet_tpu_mfu",
-    "model flops utilization vs the backend's nominal peak",
+    "model flops utilization vs the device's published peak",
     labels=("executable",))
 _ROOFLINE = _metrics.gauge(
     "mxnet_tpu_roofline_fraction",
-    "achieved HBM bandwidth fraction vs the backend's nominal peak",
+    "achieved HBM bandwidth fraction vs the device's published peak",
     labels=("executable",))
 
 

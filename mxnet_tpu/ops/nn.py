@@ -631,33 +631,19 @@ def _sdpa(q, k, v, mask=None, causal=False, scale=None, impl="xla"):
     (ops/pallas_kernels.py): O(T) HBM instead of the O(T^2) score matrix.
     Trainable: the op routes through flash_attention_with_grad
     (custom_vjp, blockwise backward from the saved log-sum-exp), so
-    nd/sym/gluon models using impl='flash' get the kernel in BOTH passes
-    — round-5 fix; previously the op was forward-only and training
-    silently fell back to the dense path."""
+    nd/sym/gluon models using impl='flash' get the kernel in BOTH passes."""
     if impl == "flash":
-        import warnings
-
-        from .pallas_kernels import flash_attention_with_grad, \
-            pallas_available
+        from .pallas_kernels import flash_attention_with_grad
 
         if mask is not None:
             raise ValueError(
                 "impl='flash' does not support an explicit mask (only "
                 "causal=True); the dense path would defeat the O(T) memory "
                 "guarantee you opted into")
-        if pallas_available():
-            try:
-                # NOTE: inside a trace only the shape gate can fall back;
-                # a program compiled for a CPU device cannot lower the TPU
-                # kernel — eager NDArray callers get automatic placement
-                # via pallas_kernels.flash_attention instead.
-                return flash_attention_with_grad(q, k, v, causal=causal,
-                                                 scale=scale)
-            except ValueError as e:  # shape gate (trace-time)
-                warnings.warn(f"impl='flash': {e}; falling back to XLA")
-        else:
-            warnings.warn("impl='flash' requires a TPU backend; falling "
-                          "back to the XLA composition")
+        # the kernel or an error: an unsupported shape (ScheduleError) or
+        # a non-TPU device (Pallas refuses to lower) raises — never the
+        # dense composition under the name the caller asked for
+        return flash_attention_with_grad(q, k, v, causal=causal, scale=scale)
     d = q.shape[-1]
     s = scale if scale is not None else 1.0 / _np.sqrt(d)
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * s

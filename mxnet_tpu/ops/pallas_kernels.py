@@ -8,9 +8,10 @@ forward — K/V arrive in VMEM one (BLOCK_K, D) tile per grid step, running
 across the innermost grid dimension, and the O(T^2) score matrix never
 exists anywhere. Sequence length is bounded by HBM, not VMEM.
 
-Kernels run on real TPUs (platform + shape gated) with the jnp
-composition as the universal fallback; tests drive the same kernel in
-Pallas interpret mode on CPU so numerics are CI-checked everywhere.
+Kernels compile for the TPU or raise: no entry point here substitutes
+another implementation or flips to interpret mode on its own. Tests
+drive the same kernels in Pallas interpret mode on CPU by passing
+``interpret=True``, so numerics are CI-checked everywhere.
 """
 from __future__ import annotations
 
@@ -35,13 +36,11 @@ def _schedule():
 
 
 def pallas_available():
+    """Whether compiled (non-interpret) Pallas kernels can run here: jax's
+    default backend is the TPU. What ``impl='auto'`` selects on."""
     import jax
 
-    try:
-        return jax.default_backend() not in ("cpu",) and \
-            any(d.platform != "cpu" for d in jax.devices())
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _mha_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -157,19 +156,6 @@ def _build_flash(bh, t, d, dtype_str, scale, causal, interpret, bq, bk):
     )
 
 
-def _unwrap_nd(q, k, v, interpret):
-    """NDArray inputs -> TPU-placed jax arrays (interpret on CPU hosts)."""
-    import jax
-
-    tpu_devs = [d for d in jax.devices() if d.platform != "cpu"]
-    if tpu_devs:
-        raw = [jax.device_put(a._data, tpu_devs[0]) for a in (q, k, v)]
-    else:
-        raw = [a._data for a in (q, k, v)]
-        interpret = True
-    return raw, interpret
-
-
 def flash_attention(q, k, v, causal=False, scale=None, interpret=False,
                     return_lse=False, q_offset=0, k_offset=0,
                     block_q=None, block_k=None):
@@ -186,12 +172,11 @@ def flash_attention(q, k, v, causal=False, scale=None, interpret=False,
     else the measured schedule table, else the legalized default.
     Requirements: a legal block exists (T itself, or a multiple-of-8
     divisor of T up to the scheduled block), D <= 256, self-attention
-    shapes. Raises ValueError otherwise — callers fall back to the XLA
-    composition (ops/nn.py scaled_dot_product_attention).
+    shapes. Raises ValueError otherwise.
 
-    Accepts NDArrays or jax arrays. Eager NDArray calls are placed on the
-    TPU device automatically (or run in interpret mode on CPU-only hosts),
-    since a program compiled for a CPU device cannot lower the kernel.
+    Accepts NDArrays or jax arrays, computed where they live: inputs on a
+    CPU device need ``interpret=True`` (a program compiled for a CPU
+    device cannot lower the kernel, and says so).
     """
     import jax.numpy as jnp
 
@@ -199,8 +184,8 @@ def flash_attention(q, k, v, causal=False, scale=None, interpret=False,
         from ..ndarray.ndarray import NDArray
 
         ctx = getattr(q, "_ctx", None)
-        raw, interpret = _unwrap_nd(q, k, v, interpret)
-        out = flash_attention(*raw, causal=causal, scale=scale,
+        out = flash_attention(q._data, k._data, v._data, causal=causal,
+                              scale=scale,
                               interpret=interpret, return_lse=return_lse,
                               q_offset=q_offset, k_offset=k_offset,
                               block_q=block_q, block_k=block_k)
@@ -212,8 +197,6 @@ def flash_attention(q, k, v, causal=False, scale=None, interpret=False,
         raise ValueError(
             f"flash_attention: unsupported shape — q {q.shape} vs k "
             f"{k.shape} / v {v.shape} (self-attention only)")
-    # ScheduleError subclasses ValueError, so the no-legal-block case
-    # keeps the documented fall-back contract
     bq, bk = _schedule().flash_fwd_blocks(
         b * h, t, d, str(q.dtype), interpret=bool(interpret),
         block_q=block_q, block_k=block_k)
@@ -361,9 +344,9 @@ def flash_attention_with_grad(q, k, v, causal=False, scale=None,
         from ..ndarray.ndarray import NDArray
 
         ctx = getattr(q, "_ctx", None)
-        raw, interpret = _unwrap_nd(q, k, v, interpret)
         return NDArray(flash_attention_with_grad(
-            *raw, causal=causal, scale=scale, interpret=interpret,
+            q._data, k._data, v._data, causal=causal, scale=scale,
+            interpret=interpret,
             block_q=block_q, block_k=block_k,
             bwd_block_k=bwd_block_k), ctx)
 

@@ -446,15 +446,9 @@ def named_mesh(spec=None, devices=None):
 
 
 def shard_map(fn, mesh, in_specs, out_specs, check=True):
-    """Version-portable jax shard_map: jax >= 0.6 exposes `jax.shard_map`
-    with the replication check named check_vma; earlier releases ship it
-    as jax.experimental.shard_map with check_rep."""
+    """``jax.shard_map`` with the varying-mesh-axes check as one flag
+    (off around ``pallas_call`` bodies, whose outputs carry no vma)."""
     import jax
 
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _smap
-
-    return _smap(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                 check_rep=check)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)

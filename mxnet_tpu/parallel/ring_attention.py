@@ -70,7 +70,7 @@ def ring_attention_inner(q, k, v, axis_name="sp", causal=False, scale=None,
             m, w, o, kc, vc = carry
             # axis_index must be (re)taken INSIDE the loop body: a value
             # closed over from outside becomes a while-body constant, and
-            # under check_vma/check_rep=False jax re-materializes it as a
+            # under check_vma=False jax re-materializes it as a
             # PartitionId HLO, which SPMD partitioning rejects
             # ("UNIMPLEMENTED: PartitionId instruction is not supported").
             my = lax.axis_index(axis_name)
@@ -147,22 +147,18 @@ def _ring_fn(mesh, axis_name, causal, scale, impl, interpret,
                              out_specs=spec, check=(impl != "flash")))
 
 
-def _pick_impl(impl, t_local, d, ring=True):
-    from ..ops.pallas_kernels import pallas_available
+def _pick_impl(impl, t_local, d, on_tpu):
+    """An explicit ``impl`` is honoured as asked (the kernel raises on a
+    shape or device it cannot serve). ``'auto'`` selects from what it can
+    observe: the Pallas kernel when the computation runs on TPU devices
+    and the shape has a legal schedule, the dense composition otherwise."""
     from ..tune import schedule as _tune_schedule
 
     if impl != "auto":
-        return impl, False
-    if not _tune_schedule.flash_shape_supported(t_local, d):
-        return "dense", False
-    if pallas_available():
-        return "flash", False
-    # CPU hosts: Pallas interpret mode is emulation-slow; for ring hops
-    # it is still the only way past a huge per-hop dense block, but the
-    # single-device path should keep XLA's fast dense composition
-    if ring and t_local >= 4096:
-        return "flash", True
-    return "dense", False
+        return impl
+    if on_tpu and _tune_schedule.flash_shape_supported(t_local, d):
+        return "flash"
+    return "dense"
 
 
 def ring_attention(q, k, v, mesh=None, axis_name="sp", causal=False,
@@ -174,8 +170,9 @@ def ring_attention(q, k, v, mesh=None, axis_name="sp", causal=False,
     output has the same global shape/sharding.
 
     impl: 'dense' | 'flash' | 'auto'. 'flash' streams each hop through
-    the Pallas kernel (O(T_local·BLOCK_K) memory per device); 'auto'
-    picks flash on TPU when shapes allow, dense otherwise.
+    the Pallas kernel (O(T_local·BLOCK_K) memory per device) or raises;
+    'auto' picks flash on TPU when shapes allow, dense otherwise.
+    ``interpret=True`` (tests) runs the kernel in Pallas interpret mode.
     """
     import jax
     import jax.numpy as jnp
@@ -196,8 +193,8 @@ def ring_attention(q, k, v, mesh=None, axis_name="sp", causal=False,
     if t % n != 0:
         raise ValueError(f"sequence length {t} not divisible by "
                          f"{axis_name} size {n}")
-    chosen, auto_interp = _pick_impl(impl, t // n, raw[0].shape[3])
-    interpret = interpret or auto_interp
+    chosen = _pick_impl(impl, t // n, raw[0].shape[3],
+                        mesh.devices.flat[0].platform == "tpu")
     spec = P(None, None, axis_name, None)
     from ..tune import schedule as _tune_schedule
 
@@ -234,13 +231,12 @@ def attention(q, k, v, causal=False, scale=None, mesh=None,
                               interpret=interpret)
     raw_q = q._data if hasattr(q, "_data") else jnp.asarray(q)
     b, h, t, d = raw_q.shape
-    chosen, auto_interp = _pick_impl(impl, t, d, ring=False)
-    if chosen == "flash":
-        from ..ops.pallas_kernels import flash_attention_with_grad
+    from ..ops.pallas_kernels import flash_attention_with_grad, \
+        pallas_available
 
-        return flash_attention_with_grad(
-            q, k, v, causal=causal, scale=scale,
-            interpret=interpret or auto_interp)
+    if _pick_impl(impl, t, d, pallas_available()) == "flash":
+        return flash_attention_with_grad(q, k, v, causal=causal,
+                                         scale=scale, interpret=interpret)
     if hasattr(q, "_data"):
         from .. import ndarray as nd
 

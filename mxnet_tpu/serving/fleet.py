@@ -520,6 +520,20 @@ class _ProcessReplica(_ThreadReplica):
     def build(self):
         import multiprocessing as mp
 
+        from ..context import held_accelerator
+
+        held = held_accelerator()
+        if held:
+            # libtpu refuses the child within seconds when JAX_PLATFORMS
+            # names the tpu, and jax silently computes on the host CPU
+            # when it does not — neither is a replica on the chip
+            raise MXNetError(
+                f"fleet mode='process': this process already holds the "
+                f"{held} chip(s), and a chip belongs to one process, so a "
+                "spawned replica cannot open it. Use mode='thread' with "
+                "one replica per device (one process drives every local "
+                "chip), or build the fleet from a parent that has not "
+                "placed anything on the chip")
         ctx = mp.get_context(
             os.environ.get("MXNET_TPU_FLEET_MP_START", "spawn").strip()
             or "spawn")

@@ -93,8 +93,7 @@ _TABLE_CACHE: dict = {"stamp": None, "table": None}
 
 
 class ScheduleError(ValueError):
-    """No legal schedule for the requested shape (subclass of
-    ``ValueError`` so kernel callers' fallback paths keep working)."""
+    """No legal schedule for the requested shape."""
 
 
 # ----------------------------------------------------------- legalization
@@ -104,7 +103,8 @@ def legalize_block(t, want):
     either ``t`` itself (a single block covering the whole sequence,
     legal at any length), or a multiple of :data:`MIN_SUBLANE` that
     divides ``t``. Returns None when no legal block exists — callers
-    raise :class:`ScheduleError` or fall back to the XLA composition."""
+    raise :class:`ScheduleError` (``impl="auto"`` asks
+    :func:`flash_shape_supported` first)."""
     t = int(t)
     want = int(want)
     if t <= 0 or want <= 0:
@@ -245,12 +245,9 @@ def resolve_backend(interpret=False):
     the live jax backend."""
     if interpret:
         return "interpret"
-    try:
-        import jax
+    import jax
 
-        return jax.default_backend()
-    except Exception:
-        return "cpu"
+    return jax.default_backend()
 
 
 def lookup(kernel, shape_key, dtype, backend):

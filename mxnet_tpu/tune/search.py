@@ -155,13 +155,13 @@ def _flash_block_pairs(t, quick=False):
     return [{"block_q": bq, "block_k": bk} for bq in legal for bk in legal]
 
 
-def flash_fwd_workload(b=2, h=1, t=256, d=32, causal=True, interpret=None,
+def flash_fwd_workload(b=2, h=1, t=256, d=32, causal=True, interpret=False,
                        seed=11, quick=False, k_offset=0, label=None):
     """Flash-attention forward sweep at one shape. ``k_offset != 0``
     shapes the ring-attention per-hop case (rotated K/V block placed
-    later in the global sequence — same kernel, hop-shaped masking)."""
-    if interpret is None:
-        interpret = not _chip()
+    later in the global sequence — same kernel, hop-shaped masking).
+    ``interpret=True`` (tests, ``autotune.py --demo``) times the Pallas
+    interpreter and keys the table under ``interpret``."""
     q, k, v = _flash_qkv(b, h, t, d, seed)
 
     def build(sched):
@@ -185,10 +185,8 @@ def flash_fwd_workload(b=2, h=1, t=256, d=32, causal=True, interpret=None,
         reference=ref)
 
 
-def flash_bwd_workload(b=2, h=1, t=256, d=32, causal=True, interpret=None,
+def flash_bwd_workload(b=2, h=1, t=256, d=32, causal=True, interpret=False,
                        seed=11, quick=False, label=None):
-    if interpret is None:
-        interpret = not _chip()
     q, k, v = _flash_qkv(b, h, t, d, seed)
     legal = schedule.legal_flash_blocks(t)
     if quick:
@@ -218,7 +216,7 @@ def flash_bwd_workload(b=2, h=1, t=256, d=32, causal=True, interpret=None,
 
 
 def decode_attn_workload(b=4, pages=8, page_size=16, h=2, d=32, seed=9,
-                         quick=False, label=None):
+                         quick=False, label=None, interpret=False):
     """Paged decode attention sweep at one (batch, pages) shape — the
     block_pages width of the streaming-softmax gather loop
     (ops/decode_attention.py). Every width in [1, pages] is legal (the
@@ -228,7 +226,6 @@ def decode_attn_workload(b=4, pages=8, page_size=16, h=2, d=32, seed=9,
 
     import jax.numpy as jnp
 
-    interpret = not _chip()
     rs = np.random.RandomState(seed)
     q = jnp.asarray(rs.randn(b, h, d).astype(np.float32) * 0.3)
     k_pages, v_pages = [
@@ -263,12 +260,6 @@ def decode_attn_workload(b=4, pages=8, page_size=16, h=2, d=32, seed=9,
         [{"block_pages": bp} for bp in space],
         label=label or "decode_attn",
         reference={"block_pages": ref_bp})
-
-
-def _chip():
-    from ..ops.pallas_kernels import pallas_available
-
-    return pallas_available()
 
 
 # ---------------------------------------------------------- int8 workloads

@@ -140,17 +140,13 @@ def get_gpu_count():
 
 
 def get_gpu_memory(dev_id=0):
-    """Best-effort (PJRT does not expose per-device free/total uniformly)."""
-    import jax
+    """(free, total) HBM bytes of accelerator ``dev_id``, from PJRT's
+    allocator statistics."""
+    from .context import tpu
 
-    devs = [d for d in jax.devices() if d.platform != "cpu"]
-    if dev_id >= len(devs):
-        raise ValueError(f"no accelerator device {dev_id}")
-    stats = getattr(devs[dev_id], "memory_stats", lambda: None)()
-    if not stats:
-        return (0, 0)
-    free = stats.get("bytes_limit", 0) - stats.get("bytes_in_use", 0)
-    return (free, stats.get("bytes_limit", 0))
+    stats = tpu(dev_id).jax_device().memory_stats()
+    return (stats["bytes_limit"] - stats["bytes_in_use"],
+            stats["bytes_limit"])
 
 
 def get_cuda_compute_capability(ctx=None):

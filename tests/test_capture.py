@@ -103,10 +103,19 @@ def test_captured_step_bitwise_vs_eager_bulk(opt, opt_params, monkeypatch):
         x, y = _batch(k)
         losses.append(step(x, y, batch_size=BS).asnumpy())
 
+    # the bitwise contract is the training trajectory: weights and
+    # optimizer state
     _assert_bitwise(_params_np(ref_net), _params_np(net))
     assert trainer.get_states_bytes() == ref_trainer.get_states_bytes()
+    # the REPORTED loss is held to float32 ULPs, not bits: the captured
+    # program fuses the last Dense's bias add into the loss reduction
+    # (one XLA-CPU loop: reduce(((dot + b) - y)^2)), where eager rounds
+    # `out` to memory first — 31.992929 vs 31.992928, one ULP, with the
+    # float64 sum between them. No gradient flows through that
+    # reduction, which is why the state above stays exact
+    # (docs/capture.md).
     for lr_, lc in zip(ref_losses, losses):
-        assert np.array_equal(lr_, lc)
+        np.testing.assert_allclose(lc, lr_, rtol=4 * np.finfo(np.float32).eps)
     s = capture.stats()
     assert s["capture_steps"] == 5
     assert s["capture_misses"] == 1 and s["capture_hits"] == 4

@@ -185,7 +185,10 @@ def test_dump_and_prometheus_surface_the_ledger():
     step(x, y, batch_size=2)
     d = obs.dump()
     assert d["perf"]["entries"], "dump() must expose the perf ledger"
-    assert d["perf"]["peaks"]["flops_per_s"] > 0
+    # XLA-CPU's device_kind has no row in the peaks table: the roofs are
+    # reported as unknown, never borrowed from another device
+    assert d["perf"]["peaks"] == {"flops_per_s": None,
+                                  "hbm_bytes_per_s": None}
     json.dumps(d, default=str)  # JSON-able end to end
     text = metrics.render_prometheus()
     key = next(iter(perf.ledger()))
@@ -196,7 +199,11 @@ def test_dump_and_prometheus_surface_the_ledger():
 
 # ---------------------------------------------------------- device timing
 
-def test_device_timing_splits_and_derives_mfu():
+def test_device_timing_splits_and_derives_mfu(monkeypatch):
+    # the MFU / roofline gauges need a roof: XLA-CPU has none in the
+    # table, so this test supplies one through the env overrides
+    monkeypatch.setenv("MXNET_TPU_PERF_PEAK_FLOPS", "2e11")
+    monkeypatch.setenv("MXNET_TPU_PERF_PEAK_GBPS", "50")
     step, x, y = _captured_step()
     step(x, y, batch_size=2)  # compile outside the timed window
     trace.set_enabled(True)
@@ -237,8 +244,21 @@ def test_nominal_peaks_env_override(monkeypatch):
     flops, bw = perf.nominal_peaks("cpu")
     assert flops == 1e15 and bw == 2000e9
     monkeypatch.setenv("MXNET_TPU_PERF_PEAK_FLOPS", "not-a-number")
-    flops, _ = perf.nominal_peaks("cpu")
-    assert flops > 0  # malformed override falls back, never raises
+    flops, _ = perf.nominal_peaks("TPU v5 lite")
+    assert flops == 197e12  # malformed override falls back, never raises
+
+
+def test_unknown_device_kind_has_no_peaks(monkeypatch):
+    monkeypatch.delenv("MXNET_TPU_PERF_PEAK_FLOPS", raising=False)
+    monkeypatch.delenv("MXNET_TPU_PERF_PEAK_GBPS", raising=False)
+    assert perf.nominal_peaks("cpu") == (None, None)
+    step, x, y = _captured_step()
+    step(x, y, batch_size=2)
+    perf.set_device_time(True)
+    step(x, y, batch_size=2)
+    e = next(iter(perf.ledger().values()))
+    assert e["device_calls"] >= 1 and e["device_ms"] > 0
+    assert e["mfu"] is None and e["roofline_fraction"] is None
 
 
 # ------------------------------------------------------------- gate logic

@@ -10,14 +10,14 @@ schema-versioned schedule table that kernel builders read at trace
 time and the AOT compile-cache key folds in
 (``capture.AOTCache.key``).
 
-Backend detection gates the measurement path: on a TPU host
-(``pallas_available()``) the flash workloads compile real Mosaic
-kernels and key the table under the chip backend; on CPU they run in
-Pallas interpret mode and key under ``interpret`` — emulation timings
-must never steer a chip. ``--demo`` shrinks the candidate spaces so the
-whole loop (generate -> validate -> measure -> persist -> warm skip)
-runs in seconds on CPU CI; a second run does ZERO searches because the
-target table is warm (``--force`` re-tunes).
+The flash workloads compile real Mosaic kernels and key the table
+under jax's default backend, so a sweep needs the chip. ``--demo`` is
+the one exception, and says so in its output: it runs the kernels in
+Pallas interpret mode, keys them under ``interpret`` — emulation timings
+must never steer a chip — and shrinks the candidate spaces so the whole
+loop (generate -> validate -> measure -> persist -> warm skip) runs in
+seconds on CPU CI; a second run does ZERO searches because the target
+table is warm (``--force`` re-tunes).
 
 The target table is ``--table`` -> ``MXNET_TPU_SCHEDULE_TABLE`` -> the
 committed ``tools/schedule_table.json``.
@@ -52,7 +52,7 @@ def resolve_table(arg):
     return env or DEFAULT_TABLE
 
 
-def build_workloads(quick):
+def build_workloads(quick, interpret=False):
     """The shipped sweep: flash fwd (plain + ring-hop-shaped) and bwd,
     int8 FC / conv / requantize. Shapes are small and fixed-seed so the
     demo is cheap and reproducible; the full mode widens only the
@@ -60,29 +60,30 @@ def build_workloads(quick):
     production shapes."""
     from mxnet_tpu.tune import search
 
+    kw = {"quick": quick, "interpret": interpret}
     return [
         search.flash_fwd_workload(b=2, h=1, t=256, d=32, causal=True,
-                                  quick=quick, label="flash_fwd"),
+                                  **kw, label="flash_fwd"),
         # the ring-attention per-hop case: a rotated K/V block placed
         # one hop later in the global sequence (same kernel, keyed at
         # the hop's local shape)
         search.flash_fwd_workload(b=2, h=1, t=128, d=32, causal=True,
-                                  quick=quick, k_offset=128,
+                                  **kw, k_offset=128,
                                   label="ring_hop"),
         search.flash_bwd_workload(b=2, h=1, t=256, d=32, causal=True,
-                                  quick=quick, label="flash_bwd"),
+                                  **kw, label="flash_bwd"),
         # the model-zoo transformer's attention shape (gluon/model_zoo/
         # transformer.py head_dim=64): fwd+bwd, so bench.py
         # --model=transformer and the transformer_step@tuned gate key
         # resolve tuned blocks instead of falling back to defaults
         search.flash_fwd_workload(b=2, h=1, t=128, d=64, causal=True,
-                                  quick=quick, label="transformer_fwd"),
+                                  **kw, label="transformer_fwd"),
         search.flash_bwd_workload(b=2, h=1, t=128, d=64, causal=True,
-                                  quick=quick, label="transformer_bwd"),
+                                  **kw, label="transformer_bwd"),
         # the serving decode step's paged-attention gather width, keyed
         # at the DecodePredictor default geometry (serving/decode.py)
         search.decode_attn_workload(b=4, pages=8, page_size=16,
-                                    quick=quick),
+                                    **kw),
         search.int8_fc_workload(m=8, k=64, n=32),
         search.int8_conv_workload(n=2, c=8, hw=8, o=16),
         search.int8_requant_workload(rows=8, cols=32),
@@ -109,17 +110,17 @@ def main(argv=None):
                     help="iterations per timing round")
     args = ap.parse_args(argv)
 
-    from mxnet_tpu.ops.pallas_kernels import pallas_available
+    import jax
+
     from mxnet_tpu.tune import search, stats
 
     table = resolve_table(args.table)
     rounds = args.rounds or (2 if args.demo else 3)
     iters = args.iters or (3 if args.demo else 8)
-    chip = pallas_available()
 
     results, errors = [], 0
     skipped = rejected = searches = 0
-    for wl in build_workloads(quick=args.demo):
+    for wl in build_workloads(quick=args.demo, interpret=args.demo):
         try:
             res = search.run_search(wl, table, rounds=rounds,
                                     iters=iters, force=args.force)
@@ -143,7 +144,8 @@ def main(argv=None):
         "value": searches,
         "unit": "searches",
         "extra": {
-            "backend": "chip" if chip else "cpu/interpret",
+            "backend": "interpret (demo)" if args.demo
+                       else jax.default_backend(),
             "table": table,
             "demo": bool(args.demo),
             "results": results,

@@ -7,9 +7,15 @@ Two measurements, two gates (docs/capture.md):
    Gate: captured per-step wall time <= the eager-bulk time (the
    captured program replaces dozens of dispatches with one).
 2. **Cold start** — a fresh process builds + first-steps the same
-   captured program with `MXNET_TPU_COMPILE_CACHE` warm vs cold.
+   captured program cold (no program artifact, jax's persistent compile
+   cache off) vs warm (artifact present, executable in the one compile
+   cache the package configures — never a directory of this tool's).
    Gate: warm >= 5x faster (the artifact skips tracing/lowering, the
-   XLA subcache skips compilation).
+   persistent cache skips compilation).
+
+A chip belongs to one process, so the cold-start children run FIRST,
+while this parent has not touched jax; the parent measures steady state
+afterwards.
 
 Prints ONE JSON line (house convention, tools/dispatch_bench.py):
 
@@ -18,7 +24,8 @@ Prints ONE JSON line (house convention, tools/dispatch_bench.py):
 
 Exit code is non-zero when either gate fails.
 
-Run: JAX_PLATFORMS=cpu python tools/capture_bench.py [--steps N]
+Run: python tools/capture_bench.py [--steps N]   (the JSON line names the
+device; under JAX_PLATFORMS=cpu it is the capture-layer CI gate)
 """
 from __future__ import annotations
 
@@ -143,14 +150,18 @@ def _child_coldstart(cache_dir):
 
 
 def cold_start():
-    """Run the child twice against one cache dir: cold then warm."""
-    d = tempfile.mkdtemp(prefix="capbench_cache_")
+    """Run the child three times against one program-artifact dir: cold
+    (jax's persistent compile cache switched off), a second pass that
+    stores the executable in the persistent cache, then warm."""
+    d = tempfile.mkdtemp(prefix="capbench_programs_")
     env = dict(os.environ, PYTHONPATH=os.pathsep.join(
         [os.path.dirname(os.path.dirname(os.path.abspath(__file__)))]
         + os.environ.get("PYTHONPATH", "").split(os.pathsep)))
-    out = []
+    out = {}
     try:
-        for phase in ("cold", "warm"):
+        for phase, xla_cache in (("cold", "false"), ("store", "true"),
+                                 ("warm", "true")):
+            env["JAX_ENABLE_COMPILATION_CACHE"] = xla_cache
             proc = subprocess.run(
                 [sys.executable, os.path.abspath(__file__),
                  "--_coldstart", d],
@@ -158,10 +169,10 @@ def cold_start():
             if proc.returncode != 0:
                 raise RuntimeError(
                     f"{phase} child failed:\n{proc.stderr[-2000:]}")
-            out.append(json.loads(proc.stdout.strip().splitlines()[-1]))
+            out[phase] = json.loads(proc.stdout.strip().splitlines()[-1])
     finally:
         shutil.rmtree(d, ignore_errors=True)
-    return out[0], out[1]
+    return out["cold"], out["warm"]
 
 
 def main(argv=None):
@@ -176,18 +187,11 @@ def main(argv=None):
         _child_coldstart(args._coldstart)
         return 0
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    bulk, captured = steady_state(args.steps, args.trials)
-    step_ok = captured <= bulk
-    print(f"# eager-bulk {bulk * 1e3:.3f} ms/step, captured "
-          f"{captured * 1e3:.3f} ms/step ({bulk / captured:.2f}x)",
-          file=sys.stderr)
-
     warm_speedup = first_step_speedup = None
     cold_ok = True
     cold = warm = None
     if not args.skip_coldstart:
-        cold, warm = cold_start()
+        cold, warm = cold_start()  # children first: parent still off jax
         assert warm["stats"].get("aot_cache_hits", 0) >= 1, \
             f"warm child missed the AOT cache: {warm['stats']}"
         warm_speedup = cold["compile_s"] / warm["compile_s"]
@@ -199,10 +203,19 @@ def main(argv=None):
               f"{warm['first_step_s']:.2f}s ({first_step_speedup:.1f}x)",
               file=sys.stderr)
 
+    bulk, captured = steady_state(args.steps, args.trials)
+    step_ok = captured <= bulk
+    print(f"# eager-bulk {bulk * 1e3:.3f} ms/step, captured "
+          f"{captured * 1e3:.3f} ms/step ({bulk / captured:.2f}x)",
+          file=sys.stderr)
+
+    from mxnet_tpu.observability import perf
+
     print(json.dumps({
         "metric": "capture_step_speedup",
         "value": round(bulk / captured, 3),
         "unit": "x",
+        "device": perf.device_record(),
         "extra": {
             "eager_bulk_ms_per_step": round(bulk * 1e3, 3),
             "captured_ms_per_step": round(captured * 1e3, 3),

@@ -134,6 +134,15 @@ def launch_local(n, cmd, port=None, env_extra=None, kill_siblings=True,
             sys.stderr.write(
                 f"launch.py: worker rank {failed_rank} exited with code "
                 f"{rc}; stderr tail:\n{tail}\n")
+            if "libtpu multi-process lockfile" in tail:
+                # every rank inherits the same environment and so tries
+                # to open every local chip; libtpu gives them to the
+                # first and refuses the rest
+                sys.stderr.write(
+                    "launch.py: a chip belongs to one process, and one "
+                    "process drives all chips of a host — launch one "
+                    "rank per HOST (-n 1 here), or pin the ranks to the "
+                    "CPU (JAX_PLATFORMS=cpu) for a dry run\n")
         return rc
     finally:
         if prev_handler is not None:

@@ -22,10 +22,9 @@ Sections (details on stderr):
 - int8:    int8-vs-bf16 sweep (docs/quantization.md) — the SAME convnet
            served as a calibrated-int8 Predictor vs a bf16 one at batch
            128, plus a 2-variant Fleet ({model: {bf16, int8}}) proving
-           per-model dtype-variant routing end to end. Gate (chip only;
-           CPU has no int8 MXU path): int8 >= 1.25x bf16 model-level —
-           the ROADMAP item-1 serving gate, measured 1.45x on ResNet-18
-           by tools/bench_int8.py.
+           per-model dtype-variant routing end to end. Gate: int8 >=
+           1.25x bf16 model-level — the ROADMAP item-1 serving gate,
+           measured 1.45x on ResNet-18 by tools/bench_int8.py.
 
 - operate (``--operate``): the operator sweep — under continuous load
            the fleet scales 2 -> 4 (gates: scale-up-phase p99 <= 3x
@@ -42,7 +41,12 @@ Sections (details on stderr):
            gates ZERO retraces after warmup, full token budgets on
            every completed stream, and a clean page pool.
 
-Run: JAX_PLATFORMS=cpu python tools/serving_bench.py [--iters N]
+Needs an accelerator (exits non-zero without one — every number here is
+a device metric) and names it in the JSON line's ``device`` field.
+Predictors are built without a ``ctx`` and so live on the chip, the
+default context.
+
+Run: python tools/serving_bench.py [--iters N]
      [--skip-fleet] [--skip-int8] [--operate] [--decode]
 """
 from __future__ import annotations
@@ -497,10 +501,9 @@ def _int8_variant_factories(mx, serving, batch, hw=16):
     return bf16_factory, int8_factory, tail
 
 
-def bench_int8(mx, serving, batch=128, iters=30, on_tpu=False):
+def bench_int8(mx, serving, batch=128, iters=30):
     """int8-vs-bf16 Predictor throughput at batch 128 plus the
-    dtype-variant fleet routing proof. Returns the result dict; the
-    throughput gate applies on a chip only."""
+    dtype-variant fleet routing proof. Returns the result dict."""
     import numpy as np
 
     bf16_factory, int8_factory, tail = _int8_variant_factories(
@@ -539,15 +542,14 @@ def bench_int8(mx, serving, batch=128, iters=30, on_tpu=False):
             - np.asarray(r8[0], np.float32)).max() < 0.25 * scale)
     finally:
         fleet.close()
-    gate_ok = (not on_tpu) or ratio >= GATE_INT8_VS_BF16
+    gate_ok = ratio >= GATE_INT8_VS_BF16
     return {
         "batch": batch,
         "bf16_samples_per_s": round(bf16_sps, 1),
         "int8_samples_per_s": round(int8_sps, 1),
         "int8_vs_bf16": round(ratio, 3),
         "gate_int8_vs_bf16": GATE_INT8_VS_BF16,
-        "gate": ("ok" if ratio >= GATE_INT8_VS_BF16 else "FAIL")
-                if on_tpu else "skipped (no chip)",
+        "gate": "ok" if gate_ok else "FAIL",
         "fleet_variants": variants,
         "variant_outputs_close": variant_close,
         "int8_warmup_cache_hits": p8.warmup_cache_hits,
@@ -572,6 +574,10 @@ def main(argv=None):
 
     import mxnet_tpu as mx
     from mxnet_tpu import serving
+    from mxnet_tpu.observability import perf
+
+    dev = perf.require_chip()
+    print(f"device: {dev}", file=sys.stderr)
 
     pred = _build_predictor(mx, serving, buckets=(1, 16))
     print(f"warmup: {pred.warmup_ms:.0f} ms for buckets "
@@ -603,10 +609,7 @@ def main(argv=None):
     int8 = None
     int8_ok = True
     if not args.skip_int8:
-        import jax
-
-        on_tpu = any(d.platform != "cpu" for d in jax.devices())
-        int8 = bench_int8(mx, serving, on_tpu=on_tpu)
+        int8 = bench_int8(mx, serving)
         int8_ok = int8.pop("gate_ok") and int8["variant_outputs_close"]
         print(f"int8 (batch {int8['batch']}): bf16 "
               f"{int8['bf16_samples_per_s']:.0f} vs int8 "
@@ -666,6 +669,7 @@ def main(argv=None):
         "value": round(batched, 1),
         "unit": "samples/s",
         "vs_baseline": round(speedup, 2),  # batch16 vs single-request
+        "device": dev,
         "extra": {
             "single_samples_per_s": round(single, 1),
             "batch16_vs_single": round(speedup, 2),
