@@ -14,12 +14,6 @@ Analysis (stderr): per-config img/s and MFU against the device's
 published bf16 peak (observability.perf.DEVICE_PEAKS). ResNet-50 fwd
 ≈ 4.1 GFLOP/img at 224²; training ≈ 3×.
 
-``--data=stream`` switches to the streaming-ingestion overlap bench
-(tools/stream_bench.py, which owns its own device setup): a dp=8
-synthetic-decode training run gated on
-``mxnet_tpu_input_stall_fraction`` <= 0.05 with device prefetch on and
-> 0.2 with it off (docs/data.md).
-
 ``--model=transformer`` switches to the dp×fsdp×tp transformer
 pretraining bench (docs/parallel.md): a model-zoo decoder-only LM,
 SpecLayout-sharded, trained in bf16 through ONE donated captured
@@ -321,26 +315,13 @@ def main_dist():
     return 0 if ok else 1
 
 
-def main_stream():
-    """Delegate to the streaming-ingestion gate (tools/stream_bench.py
-    owns the workload; this entry point keeps the one-bench front door).
-    Must run before jax initializes: the dp=8 mesh needs the virtual
-    device count stream_bench forces at import."""
-    import os
-
-    sys.path.insert(0, os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), "tools"))
-    import stream_bench
-
-    return stream_bench.main([a for a in sys.argv[1:]
-                              if not a.startswith("--data=")])
-
-
 if __name__ == "__main__":
+    unknown = set(sys.argv[1:]) - {"--capture", "--model=transformer",
+                                   "--no-capture", "--dist"}
+    if unknown:
+        sys.exit(f"bench.py: unknown argument(s) {sorted(unknown)}")
     if "--dist" in sys.argv[1:]:
         sys.exit(main_dist())
-    if "--data=stream" in sys.argv[1:]:
-        sys.exit(main_stream())
     if "--model=transformer" in sys.argv[1:]:
         sys.exit(main_transformer(
             capture_mode="--no-capture" not in sys.argv[1:]))

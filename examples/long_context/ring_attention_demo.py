@@ -6,6 +6,8 @@ Run on any host:
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
     JAX_PLATFORMS=cpu python examples/long_context/ring_attention_demo.py
 On a TPU pod the same code runs over real chips (drop the env vars).
+``--impl flash`` compiles the Pallas kernel and so needs the chips; off
+chip add ``--interpret``.
 """
 import argparse
 import os
@@ -31,6 +33,9 @@ def main():
     ap.add_argument("--impl", choices=["dense", "flash"], default="dense",
                     help="per-hop kernel: flash streams each hop through "
                          "the Pallas kernel (O(T_local*BLOCK) memory)")
+    ap.add_argument("--interpret", action="store_true",
+                    help="run the flash kernel in the Pallas interpreter "
+                         "(off-chip demo of --impl flash)")
     args = ap.parse_args()
 
     n = len(jax.devices())
@@ -45,12 +50,11 @@ def main():
         rng.randn(1, args.heads, T, args.dim).astype(np.float32) * 0.1,
         NamedSharding(mesh, spec)) for _ in range(3)]
 
-    interpret = jax.default_backend() == "cpu"
-
     def loss(q, k, v):
         f = jax.shard_map(
             lambda a, b, c: parallel.ring.ring_attention_inner(
-                a, b, c, causal=True, impl=args.impl, interpret=interpret),
+                a, b, c, causal=True, impl=args.impl,
+                interpret=args.interpret),
             mesh=mesh, in_specs=(spec,) * 3, out_specs=spec,
             check_vma=(args.impl != "flash"))
         return jnp.mean(f(q, k, v) ** 2)

@@ -21,24 +21,38 @@ def _configure_jax():
 
     Compile cache: ONE persistent XLA-executable cache per checkout.
     ``JAX_COMPILATION_CACHE_DIR``, when set, is jax's own setting and
-    nothing here touches it; otherwise the cache lives at the fixed
-    ``<checkout>/.jax_cache`` (the directory is part of what a later
-    process must find again, so never a temporary name, a pid or a
-    time). No other code sets a cache directory. Every executable is
-    kept, not only those past jax's 1 s compile-time floor: eager mode
-    compiles hundreds of small per-op programs, and a second process in
-    the same checkout should compile none of them again."""
+    nothing here touches it (its size is then jax's business too:
+    ``JAX_COMPILATION_CACHE_MAX_SIZE``); otherwise the cache lives at
+    the fixed ``<checkout>/.jax_cache`` (the directory is part of what a
+    later process must find again, so never a temporary name, a pid or
+    a time), held under ``MXNET_TPU_COMPILE_CACHE_MAX_MB`` by one
+    oldest-first sweep when the process ends. No other code sets a
+    cache directory. On an accelerator every executable is kept, not
+    only those past jax's 1 s compile-time floor: eager mode compiles
+    hundreds of small per-op programs, and a second process in the same
+    checkout should compile none of them again. A process pinned to
+    XLA-CPU keeps jax's floor: its compiles are cheap, and jaxlib's CPU
+    loader logs a multi-KB "machine feature" error on every hit."""
+    import atexit
     import os
 
     import jax
+
+    from .base import compile_cache_limit_bytes, evict_oldest
 
     if os.environ.get("MXNET_TPU_ENABLE_X64") == "1":
         jax.config.update("jax_enable_x64", True)
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(checkout, ".jax_cache"))
-    if not os.environ.get("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"):
+        cache = os.path.join(checkout, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", cache)
+        # swept once per process, not by jax_compilation_cache_max_size:
+        # that rescans the directory on every write (a cold 337-compile
+        # chip_smoke took 203 s with it against 150 s without)
+        atexit.register(evict_oldest, cache, compile_cache_limit_bytes())
+    pinned_to_cpu = (jax.config.jax_platforms or "").startswith("cpu")
+    if not pinned_to_cpu and not os.environ.get(
+            "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"):
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 

@@ -57,6 +57,46 @@ def getenv(name, default):
     return type(default)(val)
 
 
+def compile_cache_limit_bytes():
+    """``MXNET_TPU_COMPILE_CACHE_MAX_MB`` in bytes: the one size cap of
+    both compile-cache layers (jax's persistent executable cache and
+    the program artifacts of capture.AOTCache)."""
+    try:
+        mb = float(os.environ.get("MXNET_TPU_COMPILE_CACHE_MAX_MB", "2048"))
+    except ValueError:
+        mb = 2048.0
+    return int(mb * 1e6)
+
+
+def evict_oldest(directory, limit_bytes):
+    """Delete oldest-mtime files of ``directory`` until it holds at most
+    ``limit_bytes``; returns how many went. One scan; a file another
+    process removed first is not an error."""
+    entries, total = [], 0
+    try:
+        with os.scandir(directory) as it:
+            for e in it:
+                try:
+                    st = e.stat()
+                except OSError:
+                    continue
+                entries.append((st.st_mtime, st.st_size, e.path))
+                total += st.st_size
+    except OSError:
+        return 0
+    evicted = 0
+    for _, size, path in sorted(entries):
+        if total <= limit_bytes:
+            break
+        try:
+            os.remove(path)
+        except OSError:
+            continue
+        total -= size
+        evicted += 1
+    return evicted
+
+
 class _Registry:
     """Minimal name->object registry with alias support."""
 

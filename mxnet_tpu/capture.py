@@ -65,6 +65,7 @@ import threading
 import time
 
 from . import profiler as _profiler
+from .base import compile_cache_limit_bytes, evict_oldest
 from .observability import flight as _obs_flight
 from .observability import numerics as _obs_numerics
 from .observability import perf as _obs_perf
@@ -135,14 +136,6 @@ def _integrity_enabled():
     from .resilience import integrity as _integrity
 
     return _integrity.fingerprint_enabled()
-
-
-def _cache_limit_bytes():
-    try:
-        mb = float(os.environ.get("MXNET_TPU_COMPILE_CACHE_MAX_MB", "2048"))
-    except ValueError:
-        mb = 2048.0
-    return int(mb * 1e6)
 
 
 def _cache_salt():
@@ -542,34 +535,10 @@ class CompileCache:
         """Size-cap eviction: while the cache exceeds
         ``MXNET_TPU_COMPILE_CACHE_MAX_MB``, delete the oldest-mtime
         program artifacts."""
-        limit = _cache_limit_bytes() if limit_bytes is None else limit_bytes
-        entries = []
-        total = 0
-        try:
-            names = os.listdir(self.programs)
-        except OSError:
-            names = []
-        for name in names:
-            p = os.path.join(self.programs, name)
-            try:
-                st = os.stat(p)
-            except OSError:
-                continue
-            entries.append((st.st_mtime, st.st_size, p))
-            total += st.st_size
-        if total <= limit:
-            return 0
-        evicted = 0
-        for _, size, p in sorted(entries):
-            if total <= limit:
-                break
-            try:
-                os.remove(p)
-            except OSError:
-                continue
-            total -= size
-            evicted += 1
-            _STATS["aot_cache_evictions"] += 1
+        limit = (compile_cache_limit_bytes() if limit_bytes is None
+                 else limit_bytes)
+        evicted = evict_oldest(self.programs, limit)
+        _STATS["aot_cache_evictions"] += evicted
         return evicted
 
 

@@ -100,9 +100,6 @@ class Context:
                 f"{self}: only {len(devs)} device(s) of this type are visible"
             )
         self._jax_device = devs[self.device_id]
-        if self._jax_device.platform != "cpu":
-            global _HELD_ACCELERATOR
-            _HELD_ACCELERATOR = self._jax_device.platform
         return self._jax_device
 
     def empty_cache(self):
@@ -111,19 +108,6 @@ class Context:
         import gc
 
         gc.collect()
-
-
-# Platform of the accelerator this process has opened through a Context
-# (None while it has opened none). A chip belongs to one process: once
-# set, no child of this process can open the chip.
-_HELD_ACCELERATOR = None
-
-
-def held_accelerator():
-    """'tpu' once this process has resolved an accelerator context (and so
-    holds the chip), else None — answered without touching jax, so a
-    parent that must stay off the chip can ask."""
-    return _HELD_ACCELERATOR
 
 
 def _accelerator_devices():

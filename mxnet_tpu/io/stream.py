@@ -42,9 +42,8 @@ were built for (PAPERS.md). Three layers:
 Resume tokens serialize into the CheckpointManager v2 manifest
 (``save(..., data_iter=...)`` / ``restore_latest(..., data_iter=...)``,
 docs/resilience.md) so elastic recovery and mesh-shrink replay never see
-a sample twice. ``tools/stream_bench.py`` (also ``bench.py
---data=stream``) gates the overlap: ``mxnet_tpu_input_stall_fraction``
-<= 0.05 at dp=8 with prefetch on.
+a sample twice. ``tools/stream_bench.py`` gates the overlap:
+``mxnet_tpu_input_stall_fraction`` <= 0.05 at dp=8 with prefetch on.
 """
 from __future__ import annotations
 

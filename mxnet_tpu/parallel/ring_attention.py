@@ -229,12 +229,20 @@ def attention(q, k, v, causal=False, scale=None, mesh=None,
         return ring_attention(q, k, v, mesh=mesh, axis_name=axis_name,
                               causal=causal, scale=scale, impl=impl,
                               interpret=interpret)
+    import jax
+
     raw_q = q._data if hasattr(q, "_data") else jnp.asarray(q)
     b, h, t, d = raw_q.shape
     from ..ops.pallas_kernels import flash_attention_with_grad, \
         pallas_available
 
-    if _pick_impl(impl, t, d, pallas_available()) == "flash":
+    # where q lives decides, as the mesh does for the ring; a tracer has
+    # no device, and its computation lands on jax's default backend
+    if isinstance(raw_q, jax.core.Tracer):
+        on_tpu = pallas_available()
+    else:
+        on_tpu = next(iter(raw_q.devices())).platform == "tpu"
+    if _pick_impl(impl, t, d, on_tpu) == "flash":
         return flash_attention_with_grad(q, k, v, causal=causal,
                                          scale=scale, interpret=interpret)
     if hasattr(q, "_data"):

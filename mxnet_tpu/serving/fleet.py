@@ -520,10 +520,14 @@ class _ProcessReplica(_ThreadReplica):
     def build(self):
         import multiprocessing as mp
 
-        from ..context import held_accelerator
+        import jax
+        from jax._src import xla_bridge
 
-        held = held_accelerator()
-        if held:
+        # asked without opening anything: a parent that is still off jax
+        # must stay off it, or it would take the chip from its children
+        if xla_bridge.backends_are_initialized() \
+                and jax.default_backend() != "cpu":
+            held = jax.default_backend()
             # libtpu refuses the child within seconds when JAX_PLATFORMS
             # names the tpu, and jax silently computes on the host CPU
             # when it does not — neither is a replica on the chip

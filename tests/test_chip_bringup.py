@@ -4,7 +4,8 @@ chip_smoke.py proves the training path on the v5e; these tests pin what
 must stay true here: measurement entry points fail without a chip
 instead of falling back, accelerator contexts never resolve to the CPU,
 the default context follows jax's default backend, kvstore('tpu') sums
-across devices, there is ONE placeable compile cache, and the peaks
+across devices, a process fleet is refused by a parent that holds the
+chip, there is ONE placeable and bounded compile cache, and the peaks
 table never lends one device another's number.
 """
 import os
@@ -19,13 +20,13 @@ import mxnet_tpu as mx
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _run(args, **env_over):
+def _run(args, cwd=REPO, **env_over):
     env = {k: v for k, v in os.environ.items()
            if k not in ("JAX_COMPILATION_CACHE_DIR",
                         "JAX_ENABLE_COMPILATION_CACHE", "XLA_FLAGS")}
     env["JAX_PLATFORMS"] = "cpu"
     env.update(env_over)
-    return subprocess.run([sys.executable, *args], cwd=REPO, env=env,
+    return subprocess.run([sys.executable, *args], cwd=cwd, env=env,
                           capture_output=True, text=True, timeout=300)
 
 
@@ -36,6 +37,26 @@ def test_measurement_entry_points_fail_without_a_chip(script):
     assert r.stdout.strip() == "", "no result may be printed without a chip"
     assert "no chip" in r.stderr or "no accelerator" in r.stderr, r.stderr
     assert "cpu" in r.stderr
+
+
+def test_every_bench_mode_requires_the_chip(monkeypatch):
+    """Each mode of bench.py and the tools benches asks for the chip
+    before any work (in-process: the refusal is the first thing each
+    does); a mode bench.py does not have is an error, not the default."""
+    import importlib
+
+    monkeypatch.syspath_prepend(os.path.join(REPO, "tools"))
+    monkeypatch.syspath_prepend(REPO)
+    bench = importlib.import_module("bench")
+    for mode in (bench.main, lambda: bench.main(capture_mode=True),
+                 bench.main_transformer, bench.main_dist,
+                 lambda: importlib.import_module("serving_bench").main([]),
+                 lambda: importlib.import_module("bench_int8").main([])):
+        with pytest.raises(mx.MXNetError, match="no accelerator"):
+            mode()
+    r = _run(["bench.py", "--data=stream"])
+    assert r.returncode != 0 and r.stdout == ""
+    assert "unknown argument" in r.stderr
 
 
 def test_accelerator_context_never_resolves_to_the_cpu():
@@ -96,23 +117,65 @@ def test_kvstore_tpu_sums_values_committed_to_four_devices():
         assert o.data_.devices() == {c.jax_device()}
 
 
-_CACHE_PROBE = ("import jax, mxnet_tpu; "
-                "print(jax.config.jax_compilation_cache_dir)")
+def test_process_fleet_refuses_a_parent_that_holds_the_chip(monkeypatch):
+    """However the parent opened the chip (a Context or jax directly),
+    a process replica is refused at once, not spawned to fail."""
+    import jax
+
+    from mxnet_tpu.serving import fleet
+
+    jax.devices()  # this process has initialised its backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    replica = fleet._ProcessReplica("m", 0, lambda: None, {}, None)
+    with pytest.raises(mx.MXNetError, match="already holds the tpu"):
+        replica.build()
+    assert replica._proc is None
+
+
+_CACHE_PROBE = ("import jax, mxnet_tpu; c = jax.config; "
+                "print(c.jax_compilation_cache_dir, "
+                "c.jax_persistent_cache_min_compile_time_secs)")
+_FEW_OPS = ("; import mxnet_tpu as mx; x = mx.nd.ones((3,)); "
+            "[f(x).asnumpy() for f in (mx.nd.exp, mx.nd.sqrt, mx.nd.tanh, "
+            "mx.nd.sigmoid, mx.nd.relu, mx.nd.abs)]")
 
 
 def test_compile_cache_is_checkout_local_by_default():
     r = _run(["-c", _CACHE_PROBE])
     assert r.returncode == 0, r.stderr
-    assert r.stdout.strip() == os.path.join(REPO, ".jax_cache")
+    # pinned to XLA-CPU: jax's own compile-time floor stays (its loader
+    # logs an error per hit, and CPU compiles are cheap)
+    assert r.stdout.split() == [os.path.join(REPO, ".jax_cache"), "1.0"]
+    # not pinned (what a chip host sees): every executable is kept
+    r = _run(["-c", _CACHE_PROBE], JAX_PLATFORMS="")
+    assert r.returncode == 0, r.stderr
+    assert r.stdout.split()[1] == "0.0"
+
+
+def test_default_compile_cache_is_held_under_the_size_cap(tmp_path):
+    """<checkout>/.jax_cache is swept to MXNET_TPU_COMPILE_CACHE_MAX_MB
+    when the process ends (seen through a symlinked checkout, so the
+    sweep never touches this checkout's own cache)."""
+    os.symlink(os.path.join(REPO, "mxnet_tpu"), tmp_path / "mxnet_tpu")
+    cache = tmp_path / ".jax_cache"
+
+    r = _run(["-c", _CACHE_PROBE + _FEW_OPS], cwd=tmp_path,
+             JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+             MXNET_TPU_COMPILE_CACHE_MAX_MB="0.005")
+    assert r.returncode == 0, r.stderr
+    assert r.stdout.split()[0] == str(cache)
+    # the eight-odd executables written are ~2.5 KB each
+    assert 0 < sum(f.stat().st_size for f in cache.iterdir()) <= 5000
 
 
 def test_compile_cache_honours_jax_compilation_cache_dir(tmp_path):
     placed = str(tmp_path / "placed")
     r = _run(["-c", _CACHE_PROBE + "; import mxnet_tpu as mx; "
               "(mx.nd.ones((3,)) + 1).asnumpy()"],
-             JAX_COMPILATION_CACHE_DIR=placed, MXNET_TPU_COMPILE_CACHE="")
+             JAX_COMPILATION_CACHE_DIR=placed, MXNET_TPU_COMPILE_CACHE="",
+             JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
     assert r.returncode == 0, r.stderr
-    assert r.stdout.strip() == placed
+    assert r.stdout.split()[0] == placed
     assert os.listdir(placed), "the placed cache received nothing"
     # ... and nothing else was configured or created beside it
     assert os.listdir(tmp_path) == ["placed"]

@@ -109,6 +109,21 @@ def test_sdpa_impl_flash_contract():
                 impl="flash").asnumpy()
 
 
+def test_attention_auto_selects_on_where_q_lives(monkeypatch):
+    """parallel.attention(impl='auto') on inputs placed on the host takes
+    the dense composition even when the process's default backend is the
+    TPU (JAX_PLATFORMS=tpu,cpu + mx.cpu() inputs): the kernel cannot
+    lower there, and 'auto' must not raise where dense is right."""
+    from mxnet_tpu import parallel
+    from mxnet_tpu.ops import pallas_kernels
+
+    monkeypatch.setattr(pallas_kernels, "pallas_available", lambda: True)
+    q, k, v = (mx.nd.array(a, ctx=mx.cpu(0)) for a in _qkv(T=128))
+    out = parallel.attention(q, k, v, causal=True, impl="auto")
+    ref = mx.nd.scaled_dot_product_attention(q, k, v, causal=True)
+    np.testing.assert_array_equal(out.asnumpy(), ref.asnumpy())
+
+
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_backward_matches_dense(causal):
     """custom_vjp blockwise backward vs autodiff through dense attention."""

@@ -400,7 +400,8 @@ def test_perf_gate_runs_clean_end_to_end():
     """Acceptance: the gate passes clean on the unmodified repo (same
     subprocess form an operator/CI runs)."""
     r = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "tools", "perf_gate.py")],
+        [sys.executable, os.path.join(ROOT, "tools", "perf_gate.py"),
+         "--interpret"],
         capture_output=True, text=True, timeout=600,
         env={**os.environ, "JAX_PLATFORMS": "cpu"}, cwd=ROOT)
     out = json.loads(r.stdout.strip().splitlines()[-1])

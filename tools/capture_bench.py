@@ -157,6 +157,9 @@ def cold_start():
     env = dict(os.environ, PYTHONPATH=os.pathsep.join(
         [os.path.dirname(os.path.dirname(os.path.abspath(__file__)))]
         + os.environ.get("PYTHONPATH", "").split(os.pathsep)))
+    # keep the step's executable whatever it took to compile (pinned to
+    # XLA-CPU the package leaves jax's 1 s floor in place)
+    env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
     out = {}
     try:
         for phase, xla_cache in (("cold", "false"), ("store", "true"),

@@ -42,7 +42,13 @@ Exit code is non-zero on any regression, an unreadable/invalid
 baseline, or a fully-orphaned baseline backend section. ``--update``
 (re)writes this backend's section from the current measurements.
 
-Run: JAX_PLATFORMS=cpu python tools/perf_gate.py [--update] [--baseline P]
+Run: JAX_PLATFORMS=cpu python tools/perf_gate.py --interpret [--update]
+     [--baseline P]
+
+The two flash keys time the compiled Pallas kernel, which needs the
+chip. ``--interpret`` (the CPU CI gate) times the Pallas interpreter
+instead, and says so in the JSON line; without it a backend that cannot
+compile the kernel fails the run rather than switching on its own.
 """
 from __future__ import annotations
 
@@ -82,7 +88,7 @@ def _loss_fn(out, y):
     return ((out - y) ** 2).sum()
 
 
-def collect(steps=30, trials=3, rounds=2):
+def collect(steps=30, trials=3, rounds=2, interpret=False):
     """Run the gate workload ``rounds`` times and return the per-metric
     **minimum** ``{key: {metric: value}}`` per perf-ledger key — wall
     compile time is one long uninterruptible section, so min-of-rounds
@@ -93,7 +99,7 @@ def collect(steps=30, trials=3, rounds=2):
     measures a real compile."""
     measured = None
     for _ in range(max(1, rounds)):
-        cur = _collect_once(steps, trials)
+        cur = _collect_once(steps, trials, interpret)
         if measured is None:
             measured = cur
             continue
@@ -107,7 +113,7 @@ def collect(steps=30, trials=3, rounds=2):
     return measured
 
 
-def _collect_once(steps, trials):
+def _collect_once(steps, trials, interpret=False):
     saved_cache = os.environ.pop("MXNET_TPU_COMPILE_CACHE", None)
     try:
         import numpy as np
@@ -209,9 +215,11 @@ def _collect_once(steps, trials):
         # ACROSS schedule changes (a tuned table that slows the kernel
         # fails here like any compute regression)
         measured["flash_attn_fwd@tuned"] = {
-            "step_ms": _measure_flash(trials, bwd=False)}
+            "step_ms": _measure_flash(trials, bwd=False,
+                                      interpret=interpret)}
         measured["flash_attn_bwd@tuned"] = {
-            "step_ms": _measure_flash(trials, bwd=True)}
+            "step_ms": _measure_flash(trials, bwd=True,
+                                      interpret=interpret)}
         # the dp×fsdp×tp pretraining workload (bench.py
         # --model=transformer) gates its per-step wall under a fixed key
         # for the same reason as the flash kernels: attention resolves
@@ -274,18 +282,18 @@ def _measure_stream_ingest(steps, trials):
         shutil.rmtree(sdir, ignore_errors=True)
 
 
-def _measure_flash(trials, bwd, steps=5):
+def _measure_flash(trials, bwd, steps=5, interpret=False):
     """Best-of-N wall ms for the schedule-resolved flash-attention
-    forward (or forward+backward) at a fixed shape — Pallas interpret
-    mode off-chip, the real kernel on a TPU host. Blocks resolve
-    through the schedule table exactly as production callers' do."""
+    forward (or forward+backward) at a fixed shape: the compiled kernel,
+    or the Pallas interpreter when the caller asks (``--interpret``).
+    Blocks resolve through the schedule table exactly as production
+    callers' do."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from mxnet_tpu.ops import pallas_kernels as pk
 
-    interpret = not pk.pallas_available()
     rs = np.random.RandomState(7)
     q, k, v = [jnp.asarray(rs.randn(1, 2, 256, 32).astype(np.float32) * 0.3)
                for _ in range(3)]
@@ -550,12 +558,15 @@ def main(argv=None):
                          "the current measurements instead of gating")
     ap.add_argument("--steps", type=int, default=30)
     ap.add_argument("--trials", type=int, default=3)
+    ap.add_argument("--interpret", action="store_true",
+                    help="time the flash keys in the Pallas interpreter "
+                         "(the CPU CI gate) instead of the compiled kernel")
     args = ap.parse_args(argv)
 
     import jax
 
     backend = jax.default_backend()
-    measured = collect(args.steps, args.trials)
+    measured = collect(args.steps, args.trials, interpret=args.interpret)
     if args.update:
         update_baseline(args.baseline, backend, measured)
         print(f"baseline[{backend}] <- {len(measured)} entr(ies) "
@@ -601,7 +612,8 @@ def main(argv=None):
         # retry can never eat an injected fault's one fire window.)
         print(f"perf_gate: {len(regressions)} regression(s) on first "
               "measure; re-measuring once", file=sys.stderr)
-        measured = collect(args.steps, args.trials)
+        measured = collect(args.steps, args.trials,
+                           interpret=args.interpret)
         regressions, rebaselined = compare(measured, section["entries"])
     checked = [k for k in measured if k in section["entries"]]
     orphaned = bool(section["entries"]) and not checked
@@ -625,6 +637,7 @@ def main(argv=None):
         "unit": "regressions",
         "extra": {
             "backend": backend,
+            "flash_interpret": args.interpret,
             "checked": sorted(checked),
             "rebaselined": sorted(rebaselined),
             "orphaned": orphaned,

@@ -15,11 +15,18 @@ Gates (acceptance, ISSUE 13): stall fraction <= 0.05 with prefetch ON,
 and > 0.2 with it OFF (proving the measurement actually sees the
 un-overlapped cost, not a trivially-fast decode).
 
+This is the CI gate of the overlap MECHANISM, run on 8 virtual host
+devices; the stall fraction is a ratio of host wait to wall time, not a
+device metric, and the JSON line names the devices it ran on. A mesh
+wider than the visible devices is an error, never a narrower mesh under
+the same metric name.
+
 Prints ONE JSON line (repo tool convention)::
 
     {"metric": "stream_input_stall_fraction", "value": <stall_on>,
-     "unit": "fraction", "extra": {"stall_prefetch_off": ...,
-     "gate_on": 0.05, "gate_off_min": 0.2, ...}}
+     "unit": "fraction", "device": {...}, "extra":
+     {"stall_prefetch_off": ..., "gate_on": 0.05, "gate_off_min": 0.2,
+      ...}}
 
 Exit code is non-zero when either gate is blown (one re-measure first —
 the obs_bench noise discipline). Run:
@@ -112,7 +119,11 @@ def run(steps=30, dp=8, batch_size=16, feat=32, n_records=256,
     from mxnet_tpu.io import stream
     from mxnet_tpu.parallel import ShardedTrainer, create_mesh
 
-    dp = min(dp, len(jax.devices()))
+    if dp > len(jax.devices()):
+        raise ValueError(
+            f"stream_bench: the dp={dp} mesh needs {dp} devices, jax "
+            f"reports {len(jax.devices())} {jax.default_backend()} "
+            "device(s); pass --dp")
     tmp = workdir or tempfile.mkdtemp(prefix="stream_bench_")
     try:
         paths = build_dataset(tmp, n_records, feat, num_shards)
@@ -235,10 +246,13 @@ def main(argv=None):
           f"{GATE_STALL_OFF}), step={res['step_ms']}ms, "
           f"decode={res['decode_ms_per_batch']}ms/batch, dp={res['dp']}",
           file=sys.stderr)
+    from mxnet_tpu.observability import perf
+
     print(json.dumps({
         "metric": "stream_input_stall_fraction",
         "value": round(res["stall_on"], 4),
         "unit": "fraction",
+        "device": perf.device_record(),
         "extra": {
             "stall_prefetch_off": round(res["stall_off"], 4),
             "gate_on": GATE_STALL_ON,
