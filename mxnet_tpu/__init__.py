@@ -12,6 +12,11 @@ Usage mirrors the reference:
 """
 from __future__ import annotations
 
+import time as _time
+
+_T_IMPORT_NS = _time.perf_counter_ns()     # for the setup.import span
+
+
 def _configure_jax():
     """Process-wide jax settings, resolved once at import.
 
@@ -101,3 +106,11 @@ def __getattr__(name):
         globals()[name] = mod
         return mod
     raise AttributeError(f"module 'mxnet_tpu' has no attribute {name!r}")
+
+
+# this import, from its first line to here (jax's too where this is what
+# loads jax; the lazy subpackages above come later, where they are used)
+from .observability import trace as _obs_trace  # noqa: E402
+
+_obs_trace.record("setup.import", _T_IMPORT_NS,
+                  _time.perf_counter_ns() - _T_IMPORT_NS)

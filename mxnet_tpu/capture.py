@@ -58,6 +58,7 @@ Env knobs (docs/env_vars.md): ``MXNET_TPU_CAPTURE``,
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 import json
 import os
@@ -287,16 +288,24 @@ def net_sig(net):
 
 # ------------------------------------------------------- sanctioned compile
 
-def _compile_jit(fn, jit_kwargs):
+def _compile_jit(fn, jit_kwargs, name):
     """THE sanctioned ``jax.jit`` site for captured programs (graftlint
     TS002): every capture/AOT executable — trainer steps, elastic
     grad/apply programs, serving bucket forwards, deserialized AOT
     artifacts — compiles here, so donation/sharding conventions and the
-    capture counters cannot be bypassed by a stray raw jit."""
+    capture counters cannot be bypassed by a stray raw jit. ``name``
+    (the compile site's label) names the jitted function, and so the XLA
+    module: a device trace reads ``jit_sharded_step``,
+    ``jit_decode_prefill64``, not ``jit_step`` / ``jit_fn`` for all."""
     import jax
 
-    return jax.jit(fn, **{k: v for k, v in jit_kwargs.items()
-                          if v is not None})
+    @functools.wraps(fn)        # keeps the argument names jax reads
+    def named(*args):
+        return fn(*args)
+
+    named.__name__ = named.__qualname__ = name
+    return jax.jit(named, **{k: v for k, v in jit_kwargs.items()
+                             if v is not None})
 
 
 # ----------------------------------------------------------- scalar sessions
@@ -562,11 +571,48 @@ def compile_cache():
     return cache
 
 
-def _precompile(jitted, example_args):
+_PERSISTENT_HITS = 0       # jax's cache_hits events, once listening
+_LISTENING = False
+
+
+def _on_jax_event(event, **_):
+    global _PERSISTENT_HITS
+    if event == "/jax/compilation_cache/cache_hits":
+        _PERSISTENT_HITS += 1
+
+
+def _listen_for_cache_hits():
+    """Count jax's persistent-cache hits from now on. Only a traced
+    process asks: an untraced one registers no listener."""
+    global _LISTENING
+    if not _LISTENING:
+        import jax
+
+        jax.monitoring.register_event_listener(_on_jax_event)
+        _LISTENING = True
+
+
+def _precompile(jitted, example_args, label, t0_ns, aot_hit=False):
     """Force trace + XLA compile now (build time), so first-step latency
     never lands inside an armed watchdog guard and a compile failure
-    (a kernel Mosaic refuses, an OOM at link) surfaces here, named."""
-    return jitted.lower(*example_args).compile()
+    (a kernel Mosaic refuses, an OOM at link) surfaces here, named.
+    Returns ``(compiled, seconds since t0_ns)``: the readings that time
+    the ledger's ``compile_ms`` are the ones the ``capture.trace_lower``
+    (from ``t0_ns``: tracing, lowering, an AOT export or load) and
+    ``capture.compile`` (XLA, or the persistent cache's answer) spans
+    are recorded from."""
+    if _obs_trace.enabled():
+        _listen_for_cache_hits()
+    lowered = jitted.lower(*example_args)
+    t1_ns = time.perf_counter_ns()
+    hits = _PERSISTENT_HITS
+    compiled = lowered.compile()
+    t2_ns = time.perf_counter_ns()
+    _obs_trace.record("capture.trace_lower", t0_ns, t1_ns - t0_ns,
+                      label=label, aot_hit=aot_hit)
+    _obs_trace.record("capture.compile", t1_ns, t2_ns - t1_ns, label=label,
+                      cache_hit=_PERSISTENT_HITS > hits)
+    return compiled, (t2_ns - t0_ns) / 1e9
 
 
 def aot_compile(fn, *, label, fingerprint, example_args, sig=None,
@@ -585,10 +631,10 @@ def aot_compile(fn, *, label, fingerprint, example_args, sig=None,
     jit_kwargs = {"in_shardings": in_shardings,
                   "out_shardings": out_shardings,
                   "donate_argnums": donate_argnums or None}
-    t0 = time.perf_counter()
+    t0_ns = time.perf_counter_ns()
     perf_fp = _perf_identity(fingerprint, example_args, sig)
 
-    def _ledger(compiled, aot_hit=False):
+    def _build(jitted, aot_hit=False):
         # static perf attribution (observability.perf): every compile
         # through this site — captured steps, sharded programs, serving
         # buckets — lands one ledger entry (cost/memory analysis + wall
@@ -596,14 +642,15 @@ def aot_compile(fn, *, label, fingerprint, example_args, sig=None,
         # identity that keys the AOT artifact, so the perf gate and the
         # compile cache agree on identity by construction and two
         # programs can never merge into one entry
-        _obs_perf.note_compile(label, perf_fp, compiled,
-                               time.perf_counter() - t0, aot_hit=aot_hit)
+        compiled, seconds = _precompile(jitted, example_args, label, t0_ns,
+                                        aot_hit=aot_hit)
+        _obs_perf.note_compile(label, perf_fp, compiled, seconds,
+                               aot_hit=aot_hit)
         return compiled
 
     cache = compile_cache()
     if cache is None or not enabled():
-        return _ledger(_precompile(_compile_jit(fn, jit_kwargs),
-                                   example_args))
+        return _build(_compile_jit(fn, jit_kwargs, name=label))
     key = cache.key(label, fingerprint, sig if sig is not None
                     else _avals_sig(example_args))
     # load() counts the outcome: absent -> misses, version/backend
@@ -612,7 +659,7 @@ def aot_compile(fn, *, label, fingerprint, example_args, sig=None,
     exported = cache.load(key)
     aot_hit = exported is not None
     if exported is None:
-        jitted = _compile_jit(fn, jit_kwargs)
+        jitted = _compile_jit(fn, jit_kwargs, name=label)
         try:
             from jax import export as _export
 
@@ -621,12 +668,13 @@ def aot_compile(fn, *, label, fingerprint, example_args, sig=None,
         except Exception:
             # program not exportable (callbacks, unsupported primitive):
             # serve the plain executable; persistence is best-effort
-            return _ledger(_precompile(jitted, example_args))
+            return _build(jitted)
     else:
         _STATS["aot_cache_hits"] += 1
     wrapped = _compile_jit(exported.call,
-                           {"donate_argnums": donate_argnums or None})
-    return _ledger(_precompile(wrapped, example_args), aot_hit=aot_hit)
+                           {"donate_argnums": donate_argnums or None},
+                           name=label)
+    return _build(wrapped, aot_hit=aot_hit)
 
 
 def _avals_sig(args):

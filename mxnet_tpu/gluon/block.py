@@ -22,6 +22,8 @@ from .. import ndarray as nd
 from .parameter import (Parameter, ParameterDict, DeferredInitializationError,
                         tensor_types)
 from .. import initializer
+from .. import jit as _jit
+from ..observability import trace as _obs_trace
 
 __all__ = ["Block", "HybridBlock", "SymbolBlock"]
 
@@ -205,7 +207,9 @@ class Block:
 
     def initialize(self, init=initializer.Uniform(), ctx=None, verbose=False,
                    force_reinit=False):
-        self.collect_params().initialize(init, ctx, verbose, force_reinit)
+        with _obs_trace.span("setup.initialize", block=self.name):
+            self.collect_params().initialize(init, ctx, verbose,
+                                             force_reinit)
 
     def hybridize(self, active=True, **kwargs):
         for cld in self._children.values():
@@ -269,7 +273,10 @@ class Block:
     def __call__(self, *args):
         for hook in self._forward_pre_hooks.values():
             hook(self, args)
-        out = self.forward(*args)
+        # inside a functional_call trace the ops carry this block's name
+        # (jit.scope); an eager call gets the shared no-op
+        with _jit.scope(self._name):
+            out = self.forward(*args)
         for hook in self._forward_hooks.values():
             hook(self, args, out)
         return out
@@ -376,20 +383,21 @@ class HybridBlock(Block):
         _deferred_infer_shape (reference gluon/block.py:791)."""
         from .. import symbol as sym
         try:
-            inputs = [sym.var(f"data{i}") for i in range(len(args))]
-            out = self(*inputs)
-            if isinstance(out, (list, tuple)):
-                out = sym.Group(list(out))
-            shapes = {f"data{i}": a.shape for i, a in enumerate(args)
-                      if isinstance(a, tensor_types)}
-            arg_shapes, _, aux_shapes = out.infer_shape_partial(**shapes)
-            sdict = dict(zip(out.list_arguments(), arg_shapes))
-            sdict.update(zip(out.list_auxiliary_states(), aux_shapes))
-            for p in self._all_params().values():
-                if p.name in sdict and sdict[p.name] is not None and \
-                        p._deferred_init:
-                    p.shape = sdict[p.name]
-                    p._finish_deferred_init()
+            with _obs_trace.span("setup.infer_shape", block=self.name):
+                inputs = [sym.var(f"data{i}") for i in range(len(args))]
+                out = self(*inputs)
+                if isinstance(out, (list, tuple)):
+                    out = sym.Group(list(out))
+                shapes = {f"data{i}": a.shape for i, a in enumerate(args)
+                          if isinstance(a, tensor_types)}
+                arg_shapes, _, aux_shapes = out.infer_shape_partial(**shapes)
+                sdict = dict(zip(out.list_arguments(), arg_shapes))
+                sdict.update(zip(out.list_auxiliary_states(), aux_shapes))
+                for p in self._all_params().values():
+                    if p.name in sdict and sdict[p.name] is not None and \
+                            p._deferred_init:
+                        p.shape = sdict[p.name]
+                        p._finish_deferred_init()
         except Exception as e:
             raise ValueError(
                 "Deferred initialization failed because shape cannot be "

@@ -9,6 +9,7 @@ from __future__ import annotations
 import warnings
 
 from .. import nn as _nn
+from ... import jit as _jit
 from ..block import Block, HybridBlock
 
 __all__ = ["Remat", "Concurrent", "HybridConcurrent", "Identity", "SparseEmbedding",
@@ -243,20 +244,24 @@ class MultiHeadAttention(HybridBlock):
                                  "for the cross-attention path")
             (q,) = self._split_heads(F, self.q_proj(x), 1)
             k, v = self._split_heads(F, self.kv_proj(key_value), 2)
-        if self._impl in ("dense", "flash"):
-            out = F.scaled_dot_product_attention(
-                q, k, v, causal=self._causal, impl=(
-                    "flash" if self._impl == "flash" else "xla"))
-        elif self._impl in ("ring", "auto"):
-            from ... import parallel
+        # scores, softmax, values -- not the projections -- under one
+        # name whatever implements them (a kernel, a scan, a ring, plain
+        # XLA), so a trace reads attention as the same work
+        with _jit.scope("attention"):
+            if self._impl in ("dense", "flash"):
+                out = F.scaled_dot_product_attention(
+                    q, k, v, causal=self._causal, impl=(
+                        "flash" if self._impl == "flash" else "xla"))
+            elif self._impl in ("ring", "auto"):
+                from ... import parallel
 
-            # per-hop kernel: 'auto' picks the Pallas flash kernel on TPU
-            # and the dense composition on CPU meshes (virtual-device CI)
-            out = parallel.attention(q, k, v, causal=self._causal,
-                                     mesh=self._mesh,
-                                     axis_name=self._sp_axis, impl="auto")
-        else:
-            raise ValueError(f"unknown impl {self._impl!r}")
+                # per-hop kernel: 'auto' picks the Pallas flash kernel on TPU
+                # and the dense composition on CPU meshes (virtual-device CI)
+                out = parallel.attention(
+                    q, k, v, causal=self._causal, mesh=self._mesh,
+                    axis_name=self._sp_axis, impl="auto")
+            else:
+                raise ValueError(f"unknown impl {self._impl!r}")
         b, h, l, d = out.shape
         out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
                         shape=(b, l, h * d))

@@ -24,6 +24,7 @@ hybridize imposes in the reference).
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 
 from .base import MXNetError
@@ -40,9 +41,15 @@ def _sessions():
 
 
 class TraceSession:
-    """Records cell reads/mutations during a discovery run."""
+    """Records cell reads/mutations during a discovery run.
 
-    def __init__(self):
+    ``name_blocks``: the session is a jax trace whose ops should carry
+    the name of the gluon block that asked for them (:func:`scope`);
+    ``parallel.functional_call`` sets it, the eager discovery passes do
+    not."""
+
+    def __init__(self, name_blocks=False):
+        self.name_blocks = name_blocks
         self.created = set()      # id() of cells born inside the session
         self.captured = []        # pre-existing cells read by ops (ordered)
         self._captured_ids = set()
@@ -99,6 +106,23 @@ def _notify_io(inputs, outputs):
             s.note_read(x)
         for o in outputs:
             s.note_created(o)
+
+
+_NO_SCOPE = contextlib.nullcontext()
+
+
+def scope(name):
+    """``jax.named_scope(name)`` while the innermost session names its
+    blocks, else a shared no-op: the name lands in the ``op_name`` of
+    every instruction traced inside (``observability.perf.op_names``
+    hands the compiled program's names out). The eager path pays one
+    call and one check, opens no scope and so retraces nothing."""
+    sess = _active()
+    if sess is None or not sess.name_blocks:
+        return _NO_SCOPE
+    import jax
+
+    return jax.named_scope(name)
 
 
 class no_trace:

@@ -52,13 +52,16 @@ already hold compiled executables). See docs/observability.md
 from __future__ import annotations
 
 import os
+import re
 import threading
 import time
+import weakref
 
 from . import _STATS
 from . import metrics as _metrics
 
 __all__ = ["LEDGER_FIELDS", "note_compile", "note_execution", "timed_call",
+           "op_names", "parse_op_names",
            "ledger", "device_timed_entries", "ledger_key",
            "combined_fingerprint", "snapshot", "clear", "update_gauges",
            "device_time_enabled", "set_device_time", "DEVICE_PEAKS",
@@ -67,6 +70,13 @@ __all__ = ["LEDGER_FIELDS", "note_compile", "note_execution", "timed_call",
 
 _LOCK = threading.Lock()
 _LEDGER: dict = {}
+# ledger key -> a callable that gives the compiled object of the entry's
+# latest build or None: a weak reference (its owner is the CapturedExec
+# or the captured step), a strong one while span tracing is on (a traced
+# run asks for the names after the trainer that owned the step is gone);
+# and the names parsed out of it the first time someone asked (op_names)
+_COMPILED: dict = {}
+_OP_NAMES: dict = {}
 
 # THE field registry of one ledger entry. Every entry carries exactly
 # these keys (closure-tested), and every field is documented in
@@ -271,7 +281,18 @@ def note_compile(label, fingerprint, compiled, compile_s, aot_hit=False):
     flops, acc = _cost_numbers(compiled)
     mem = _memory_numbers(compiled)
     backend = jax.default_backend()
+    from . import trace as _trace
+
+    if _trace.enabled():
+        ref = lambda: compiled  # noqa: E731  (held for a traced run)
+    else:
+        try:
+            ref = weakref.ref(compiled)
+        except TypeError:   # not a compiled executable (tests seed one)
+            ref = None
     with _LOCK:
+        _COMPILED[key] = ref
+        _OP_NAMES.pop(key, None)
         entry = _LEDGER.get(key)
         if entry is None:
             entry = dict.fromkeys(LEDGER_FIELDS)
@@ -386,6 +407,91 @@ def snapshot():
 def clear():
     with _LOCK:
         _LEDGER.clear()
+        _COMPILED.clear()
+        _OP_NAMES.clear()
+
+
+# ------------------------------------------------- the program's own names
+
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
+_HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
+_HLO_OP_NAME = re.compile(r'\bop_name="((?:[^"\\]|\\.)*)"')
+_HLO_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+_PALLAS_TARGET = 'custom_call_target="tpu_custom_call"'
+
+
+def parse_op_names(hlo_text):
+    """Optimised HLO text -> ``{instruction name: {"op_name", "kernel",
+    "called"}}`` for every instruction of every computation.
+
+    ``op_name`` is the instruction's ``metadata={op_name=...}``: jax's
+    name stack at the point the op was traced, e.g.
+    ``jit(sharded_step)/transpose(jvp(net0))/net0_dense1/dot_general``
+    -- the executable's label, ``jvp(`` (forward) or ``transpose(jvp(``
+    (backward) from ``value_and_grad``, the ``optimizer`` / ``attention``
+    scopes, and the gluon blocks' names (``jit.scope``). "" where XLA
+    made the instruction itself (copies, layout changes, async pairs).
+    ``kernel`` is the Pallas kernel's ``name=`` for a ``tpu_custom_call``
+    (the scope the call sits in), else "". ``called`` lists the distinct
+    ``op_name`` values of the instructions inside a fusion's computation,
+    in program order, so a reader can tell a fusion that mixes blocks or
+    directions from one that does not."""
+    inside, current, out = {}, None, {}
+    for line in hlo_text.splitlines():
+        if not line.startswith(" "):
+            m = _HLO_COMPUTATION.match(line)
+            current = inside.setdefault(m.group(1), {}) if m else None
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if m is None or current is None:
+            continue
+        # operands and attributes come first, then the metadata, then a
+        # Pallas call's body (megabytes of base64 on the same line)
+        at = line.find("metadata={")
+        head = line[:at] if at >= 0 else line
+        found = _HLO_OP_NAME.search(line, at) if at >= 0 else None
+        op_name = found.group(1).replace("\\'", "'") if found else ""
+        kernel = ""
+        if _PALLAS_TARGET in head:
+            scopes = op_name.split("/")
+            kernel = scopes[-2] if len(scopes) > 1 \
+                and scopes[-1] == "pallas_call" else m.group(1)
+        calls = _HLO_CALLS.search(head)
+        if op_name:
+            current[op_name] = None     # a dict keeps them in order, once
+        out[m.group(1)] = {"op_name": op_name, "kernel": kernel,
+                           "called": list(inside.get(calls.group(1), ()))
+                           if calls else []}
+    return out
+
+
+def op_names(key):
+    """The names the program gave the instructions of one ledgered
+    executable (:func:`parse_op_names` of the optimised HLO of the
+    executable that runs, ``compiled.as_text()``), or None when the key
+    is unknown or its executable is gone: the ledger holds the compiled
+    object weakly, and strongly only for a compile made while span
+    tracing was on (a diagnosis run may ask after the owner is gone; an
+    untraced process never keeps a dead step's executable loaded).
+    Parsed when first asked for and kept until the key compiles again:
+    nothing is read or parsed at compile time, so a process that never
+    asks pays nothing. A trace's
+    ``XLA Ops`` events are named after these instructions, which is how
+    a reader puts device time down to forward, backward, optimizer, a
+    block or a kernel (benchmarks/attribution.py)."""
+    with _LOCK:
+        names = _OP_NAMES.get(key)
+        ref = _COMPILED.get(key)
+    if names is not None:
+        return names
+    compiled = ref() if ref is not None else None
+    if compiled is None:
+        return None
+    names = parse_op_names(compiled.as_text())
+    with _LOCK:
+        if _COMPILED.get(key) is ref:
+            _OP_NAMES[key] = names
+    return names
 
 
 # ------------------------------------------------------------ derived gauges

@@ -8,6 +8,13 @@ forward — K/V arrive in VMEM one (BLOCK_K, D) tile per grid step, running
 across the innermost grid dimension, and the O(T^2) score matrix never
 exists anywhere. Sequence length is bounded by HBM, not VMEM.
 
+Every ``pl.pallas_call`` carries a ``name=`` (``flash_attention_fwd``,
+``conv3x3_bn_stats``): it becomes the instruction's name and the last
+scope of its ``op_name`` in the compiled program, which is how a device
+trace and ``observability.perf.op_names`` find the kernel. A kernel added
+here is named the same way (``tests/test_kernel_names_tpu.py`` holds
+every call site in the package to it).
+
 Kernels compile for the TPU or raise: no entry point here substitutes
 another implementation or flips to interpret mode on its own. Tests
 drive the same kernels in Pallas interpret mode on CPU by passing
@@ -153,6 +160,7 @@ def _build_flash(bh, t, d, dtype_str, scale, causal, interpret, bq, bk):
             jax.ShapeDtypeStruct((bh, t, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )
 
 
@@ -444,6 +452,7 @@ def conv3x3_bn_stats(x, w, interpret=False):
             jax.ShapeDtypeStruct((cout,), jnp.float32),
         ],
         interpret=interpret,
+        name="conv3x3_bn_stats",
     )(x, w)
 
 

@@ -69,7 +69,7 @@ def functional_call(net, train=False):
             if RNG_KEY in avals:
                 key_cell._data = avals[RNG_KEY]
             in_nds = [NDArray(x) for x in inputs]
-            with TraceSession() as sess:
+            with TraceSession(name_blocks=True) as sess:
                 for a in in_nds:
                     sess.note_created(a)
                 with autograd.pause(train_mode=train):

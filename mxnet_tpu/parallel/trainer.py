@@ -65,6 +65,9 @@ class ShardedTrainer:
         if remat:
             self._fwd = jax.checkpoint(
                 self._fwd, policy=resolve_policy(remat))
+        # the optimizer state's creation and the placement of parameters,
+        # aux and state on the mesh: set-up a trace can account for
+        setup = _obs_trace.start_span("setup.trainer")
         self.params = param_arrays(net)
         self.aux = aux_arrays(net)
         self._compute_dtype = dtype
@@ -90,6 +93,7 @@ class ShardedTrainer:
         self._pod = None
         self._bind_mesh(mesh if mesh is not None else create_mesh())
         self._place()
+        setup.end(params=len(self.params))
         # elastic execution state (resilience.elastic): current sticky
         # accumulation count and a monotonically increasing step counter
         # for crash reports (the executables live in _bind_mesh state)
@@ -270,7 +274,7 @@ class ShardedTrainer:
                                if jnp.issubdtype(aux[k].dtype, jnp.floating)
                                else v)
                            for k, v in new_aux.items()}
-            with TraceSession() as sess:
+            with TraceSession(name_blocks=True) as sess:
                 out_nd, y_nd = NDArray(out), NDArray(y)
                 sess.note_created(out_nd)
                 sess.note_created(y_nd)
@@ -288,6 +292,22 @@ class ShardedTrainer:
             return loss.data_.mean(), new_aux
 
         return compute_loss
+
+    def _named_update(self):
+        """The optimizer's update under the ``optimizer`` scope, for
+        every step program (fused, masked, elastic apply, shadow replay):
+        its ops carry the name in the compiled program, where a trace
+        tells them from forward (``jvp``) and backward
+        (``transpose(jvp)``) ops."""
+        import jax
+
+        update = self._update
+
+        def named(params, grads, opt_state):
+            with jax.named_scope("optimizer"):
+                return update(params, grads, opt_state)
+
+        return named
 
     def _capture_fingerprint(self):
         """Structural identity of this trainer's step programs for the
@@ -359,7 +379,7 @@ class ShardedTrainer:
 
         from ..resilience import integrity as _integrity
 
-        update = self._update
+        update = self._named_update()
         compute_loss = self._make_compute_loss()
         # in-graph step fingerprint (resilience.integrity): one extra
         # uint32 output of the SAME program — zero extra executables.
@@ -403,7 +423,7 @@ class ShardedTrainer:
         import jax
         import jax.numpy as jnp
 
-        update = self._update
+        update = self._named_update()
         compute_loss = self._make_compute_loss()
 
         def masked_loss(params, aux, x, y, length):
@@ -998,7 +1018,7 @@ class ShardedTrainer:
         accumulation never reshards."""
         import jax
 
-        update = self._update
+        update = self._named_update()
         compute_loss = self._make_compute_loss()
 
         def grads_fn(params, aux, x, y):
@@ -1237,7 +1257,7 @@ class ShardedTrainer:
             opt_sh = self._opt_sharding(mesh=mesh,
                                         param_sharding=param_sh)
             shards = (param_sh, aux_sh, batch_sh, opt_sh)
-            update = self._update
+            update = self._named_update()
             compute_loss = self._make_compute_loss()
             if length is not None:
                 def masked_loss(p, a, xx, yy, ll):

@@ -1,0 +1,79 @@
+"""Every Pallas kernel carries a ``name=``: lowered for a described TPU
+(``v5e:2x2``, nothing attached, nothing run) the custom call and its
+``op_name`` hold it, which is what a device trace and
+``observability.perf.op_names`` then show. The one file that describes
+the topology: the call is made inside a fixture, never at import, and
+the compile happens in the test's own process (only one process at a
+time may load the TPU's library)."""
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no libtpu here: say why, do not fail
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _lowered(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    return jax.jit(fn).lower(*args).as_text(debug_info=True)
+
+
+def test_flash_forward_is_named_in_the_lowered_program(one_chip):
+    from mxnet_tpu.ops import pallas_kernels
+
+    qkv = ((4, 2, 256, 64), jnp.bfloat16)
+    text = _lowered(
+        lambda q, k, v: pallas_kernels.flash_attention(q, k, v, causal=True),
+        one_chip, qkv, qkv, qkv)
+    assert "tpu_custom_call" in text
+    assert 'kernel_name = "flash_attention_fwd"' in text
+    assert "flash_attention_fwd/pallas_call" in text
+
+
+def test_conv3x3_bn_stats_is_named_in_the_lowered_program(one_chip):
+    from mxnet_tpu.ops import pallas_kernels
+
+    text = _lowered(pallas_kernels.conv3x3_bn_stats, one_chip,
+                    ((2, 16, 16, 128), jnp.bfloat16),
+                    ((3, 3, 128, 128), jnp.bfloat16))
+    assert "tpu_custom_call" in text
+    assert 'kernel_name = "conv3x3_bn_stats"' in text
+    assert "conv3x3_bn_stats/pallas_call" in text
+
+
+def test_no_pallas_call_in_the_package_is_left_unnamed():
+    """A kernel added later is named the same way (docs/observability.md,
+    "The program's own names")."""
+    import ast
+    import os
+
+    import mxnet_tpu
+
+    root = os.path.dirname(mxnet_tpu.__file__)
+    unnamed = []
+    for folder, _, files in os.walk(root):
+        for fname in files:
+            if not fname.endswith(".py"):
+                continue
+            path = os.path.join(folder, fname)
+            with open(path, encoding="utf-8") as f:
+                tree = ast.parse(f.read())
+            for node in ast.walk(tree):
+                if isinstance(node, ast.Call) and isinstance(
+                        node.func, ast.Attribute) \
+                        and node.func.attr == "pallas_call" \
+                        and "name" not in {k.arg for k in node.keywords}:
+                    unnamed.append(f"{os.path.relpath(path, root)}:"
+                                   f"{node.lineno}")
+    assert not unnamed, unnamed
