@@ -3,10 +3,28 @@
 The custom-kernel layer the blueprint reserves for "where fusion matters"
 (SURVEY.md §7): hand-placed VMEM tiling for operations whose fused form
 XLA cannot synthesize. First resident: a streaming flash-attention
-forward — K/V arrive in VMEM one (BLOCK_K, D) tile per grid step, running
-(m, l, acc) online-softmax statistics live in VMEM scratch that persists
-across the innermost grid dimension, and the O(T^2) score matrix never
-exists anywhere. Sequence length is bounded by HBM, not VMEM.
+forward — K/V arrive in VMEM one (HEADS, BLOCK_K, D) tile per grid
+step, running (m, l, acc) online-softmax statistics live in VMEM scratch
+that persists across the innermost grid dimension, and the O(T^2) score
+matrix never exists anywhere. Sequence length is bounded by HBM, not
+VMEM.
+
+The forward's tile program (docs/autotune.md, "The flash forward's tile
+program"): the tile is a schedule (candidate axes up to 1024, block_q
+and block_k independent; default 512 x 512 legalized to T, so it
+depends on (T, D, dtype) and not on a model), and a grid step takes as
+many heads, unrolled, as fill it up to one 512 x 512 tile's worth and
+fit VMEM (``tune.schedule.flash_fwd_heads``). Both matmuls take their operands in
+the input's dtype and accumulate in float32 (bf16 inputs: bf16
+operands, the probabilities cast to the value dtype for P.V; float32
+inputs: float32 operands); the scale is applied to the float32 scores;
+max, sum, exp, correction and accumulator are float32. Under ``causal``
+a K block in the q block's future does no arithmetic and no DMA, and
+only a tile that crosses the diagonal is masked. Inside the kernel the
+row statistics are (rows, 128) with every lane holding the row's value;
+the log-sum-exp leaves the kernel with the sequence along lanes,
+(BH, T / BLOCK_Q, 1, BLOCK_Q), and ``flash_attention(return_lse=True)``
+hands its callers (B, H, T, 1).
 
 Every ``pl.pallas_call`` carries a ``name=`` (``flash_attention_fwd``,
 ``conv3x3_bn_stats``): it becomes the instruction's name and the last
@@ -50,11 +68,25 @@ def pallas_available():
     return jax.default_backend() == "tpu"
 
 
+def _lanes(x, n):
+    """A lane-replicated (rows, 128) statistic widened (or narrowed) to
+    ``n`` lanes: whole lane tiles repeat the vregs that are there, so the
+    subtraction from a (rows, n) score tile needs no lane broadcast."""
+    import jax.numpy as jnp
+
+    lanes = x.shape[-1]
+    if n % lanes == 0:
+        return jnp.tile(x, (1, n // lanes))
+    if n < lanes:
+        return x[:, :n]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
 def _mha_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_ref, l_ref, acc_ref, *, scale, causal, n_kb):
-    """Grid = (BH, n_q_blocks, n_k_blocks); the k dimension is innermost,
-    so the VMEM scratch (m, l, acc) carries across K blocks of one
-    (batch*head, q-block) pair and the output writes on the last step.
+    """Grid = (BH / HB, n_q_blocks, n_k_blocks); the k dimension is
+    innermost, so the VMEM scratch (m, l, acc) carries across K blocks of
+    one (heads, q-block) pair and the outputs write on the last step.
 
     qoff_ref/koff_ref: scalar-prefetch global position offsets — ring
     attention runs the kernel on (local Q, rotated K/V) block pairs whose
@@ -62,7 +94,11 @@ def _mha_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     sequence, and the offsets are traced values (lax.axis_index), so they
     arrive in SMEM rather than being baked into the compiled kernel.
 
-    q_ref (1, BQ, D) / k_ref, v_ref (1, BK, D) / o_ref (1, BQ, D).
+    q_ref, o_ref (HB, BQ, D) / k_ref, v_ref (HB, BK, D) in the caller's
+    dtype: both matmuls take their operands as they arrive and
+    accumulate in float32. m_ref, l_ref (HB, BQ, 128) float32, every
+    lane of a row holding the row's statistic; acc_ref (HB, BQ, D)
+    float32; lse_ref (HB, 1, 1, BQ) float32, the sequence along lanes.
     """
     import jax
     import jax.numpy as jnp
@@ -70,60 +106,89 @@ def _mha_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     kb = pl.program_id(2)
     qi = pl.program_id(1)
-    bq = q_ref.shape[1]
+    hb, bq, d = q_ref.shape
     bk = k_ref.shape[1]
 
     @pl.when(kb == 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # under causal masking, K blocks strictly in this q block's future are
-    # all-masked: skip their HBM reads and MXU work entirely (~2x on long
-    # sequences)
-    if causal:
-        live = (koff_ref[0] + kb * bk <=
-                qoff_ref[0] + (qi + 1) * bq - 1)
-    else:
-        live = kb >= 0
-
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32) * scale
-        k_blk = k_ref[0].astype(jnp.float32)
-        v_blk = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if causal:
-            qpos = qoff_ref[0] + qi * bq + \
-                jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = koff_ref[0] + kb * bk + \
+    def _tile(masked):
+        if masked:
+            # qpos >= kpos, as row - col >= (first kpos) - (first qpos)
+            rel = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) - \
                 jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(qpos >= kpos, s, _NEG)
-        m_prev = m_ref[:]
-        blk_max = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, blk_max)
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        m_ref[:] = m_new
-        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            keep = rel >= (koff_ref[0] + kb * bk) - (qoff_ref[0] + qi * bq)
+        # unrolled on purpose: what several heads a step gain is the
+        # compiler interleaving their code (rolled into a fori_loop,
+        # eight heads of 128 x 128 ran 2.5 x slower; PERF.md, PR 28)
+        for h in range(hb):
+            s = jax.lax.dot_general(
+                q_ref[h], k_ref[h], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            if masked:
+                s = jnp.where(keep, s, _NEG)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - _lanes(m_new, bk))
+            corr = jnp.exp(m_prev - m_new)
+            m_ref[h] = m_new
+            l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=1, keepdims=True)
+            v_blk = v_ref[h]
+            acc_ref[h] = acc_ref[h] * _lanes(corr, d) + jax.lax.dot_general(
+                p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+    if causal:
+        # K blocks strictly in this q block's future are all-masked: no
+        # arithmetic here, and no DMA either (the index maps of
+        # _build_flash name the last live block again). Only a tile that
+        # crosses the diagonal pays for the mask.
+        q_first = qoff_ref[0] + qi * bq
+        k_first = koff_ref[0] + kb * bk
+        live = k_first <= q_first + bq - 1
+        crosses = k_first + bk - 1 > q_first
+        pl.when(live & crosses)(lambda: _tile(True))
+        pl.when(live & jnp.logical_not(crosses))(lambda: _tile(False))
+    else:
+        _tile(False)
 
     @pl.when(kb == n_kb - 1)
     def _finish():
-        o_ref[0] = (acc_ref[:] /
-                    jnp.maximum(l_ref[:], 1e-20)).astype(o_ref.dtype)
-        # row log-sum-exp, already held in scratch — emit it so the
-        # custom_vjp backward doesn't need a recomputation sweep
-        lse_ref[0] = m_ref[:] + jnp.log(jnp.maximum(l_ref[:], 1e-20))
+        for h in range(hb):
+            l_fin = jnp.maximum(l_ref[h], 1e-20)
+            o_ref[h] = (acc_ref[h] / _lanes(l_fin, d)).astype(o_ref.dtype)
+            # row log-sum-exp, already held in scratch — emit it so the
+            # custom_vjp backward doesn't need a recomputation sweep;
+            # transposed, so that the sequence runs along lanes
+            lse = m_ref[h] + jnp.log(l_fin)
+            lse_ref[h, 0] = _row_of(lse)
+
+
+def _row_of(x):
+    """(rows, 128) lane-replicated statistic -> (1, rows): the rows laid
+    along lanes. A one-hot row contracted against the statistic's lanes
+    (the same A.B^T form as Q.K^T, so it lowers wherever the kernel
+    does, aligned to the lane tile or not); at HIGHEST precision a
+    float32 times 1.0 summed with zeros is the float32 itself."""
+    import jax
+    import jax.numpy as jnp
+
+    pick = (jax.lax.broadcasted_iota(
+        jnp.int32, (_schedule().MIN_SUBLANE, x.shape[-1]), 1) == 0
+    ).astype(jnp.float32)
+    rows = jax.lax.dot_general(
+        pick, x, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    return rows[:1]
 
 
 @functools.lru_cache(maxsize=32)
-def _build_flash(bh, t, d, dtype_str, scale, causal, interpret, bq, bk):
-    """One pallas_call per (shape, dtype, config, SCHEDULE): bq/bk are
+def _build_flash(bh, t, d, dtype_str, scale, causal, interpret, bq, bk, hb):
+    """One pallas_call per (shape, dtype, config, SCHEDULE): bq/bk/hb are
     part of the cache key, so a schedule-table change re-builds instead
     of serving the old tiling."""
     import jax
@@ -134,22 +199,38 @@ def _build_flash(bh, t, d, dtype_str, scale, causal, interpret, bq, bk):
     n_kb = t // bk
     kernel = functools.partial(_mha_kernel, scale=scale, causal=causal,
                                n_kb=n_kb)
+
+    def q_map(b, i, kb, *_):
+        return (b, i, 0)
+
+    def kv_map(b, i, kb, qoff_ref, koff_ref):
+        if causal:
+            # a step in the q block's future names the last live block
+            # again: the block is resident, so no DMA is issued for it.
+            # A hop wholly in the future has no live block; it names
+            # block 0 throughout and computes nothing.
+            last = (qoff_ref[0] + (i + 1) * bq - 1 - koff_ref[0]) // bk
+            kb = jnp.minimum(kb, jnp.clip(last, 0, n_kb - 1))
+        return (b, kb, 0)
+
+    sched = _schedule()
+    lanes = sched.LANES
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # q_offset, k_offset (SMEM)
-        grid=(bh, t // bq, n_kb),
+        grid=(bh // hb, t // bq, n_kb),
         in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, kb, *_: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, kb, *_: (b, kb, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, kb, *_: (b, kb, 0)),
+            pl.BlockSpec((hb, bq, d), q_map),
+            pl.BlockSpec((hb, bk, d), kv_map),
+            pl.BlockSpec((hb, bk, d), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, kb, *_: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, kb, *_: (b, i, 0)),
+            pl.BlockSpec((hb, bq, d), q_map),
+            pl.BlockSpec((hb, 1, 1, bq), lambda b, i, kb, *_: (b, i, 0, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),   # running max m
-            pltpu.VMEM((bq, 1), jnp.float32),   # running sum l
-            pltpu.VMEM((bq, d), jnp.float32),   # output accumulator
+            pltpu.VMEM((hb, bq, lanes), jnp.float32),   # running max m
+            pltpu.VMEM((hb, bq, lanes), jnp.float32),   # running sum l
+            pltpu.VMEM((hb, bq, d), jnp.float32),       # output accumulator
         ],
     )
     return pl.pallas_call(
@@ -157,8 +238,12 @@ def _build_flash(bh, t, d, dtype_str, scale, causal, interpret, bq, bk):
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((bh, t, d), jnp.dtype(dtype_str)),
-            jax.ShapeDtypeStruct((bh, t, 1), jnp.float32),
+            jax.ShapeDtypeStruct((bh, t // bq, 1, bq), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=sched.flash_fwd_vmem_limit(
+                hb, bq, bk, d, jnp.dtype(dtype_str).itemsize)),
         interpret=interpret,
         name="flash_attention_fwd",
     )
@@ -205,12 +290,14 @@ def flash_attention(q, k, v, causal=False, scale=None, interpret=False,
         raise ValueError(
             f"flash_attention: unsupported shape — q {q.shape} vs k "
             f"{k.shape} / v {v.shape} (self-attention only)")
-    bq, bk = _schedule().flash_fwd_blocks(
+    sched = _schedule()
+    bq, bk = sched.flash_fwd_blocks(
         b * h, t, d, str(q.dtype), interpret=bool(interpret),
         block_q=block_q, block_k=block_k)
+    hb = sched.flash_fwd_heads(b * h, bq, bk, d, q.dtype.itemsize)
     s = scale if scale is not None else 1.0 / _np.sqrt(d)
     fn = _build_flash(b * h, t, d, str(q.dtype), float(s), bool(causal),
-                      bool(interpret), bq, bk)
+                      bool(interpret), bq, bk, hb)
     qf = q.reshape(b * h, t, d)
     kf = k.reshape(b * h, t, d)
     vf = v.reshape(b * h, t, d)

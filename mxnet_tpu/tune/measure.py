@@ -28,6 +28,10 @@ __all__ = ["time_min_ms", "outputs_match", "FLOAT_RTOL", "FLOAT_ATOL"]
 # but anything beyond a few ULP at these magnitudes is a wrong kernel
 FLOAT_RTOL = 2e-5
 FLOAT_ATOL = 2e-5
+# the same bar for 16-bit float outputs (bf16 keeps 8 bits of mantissa:
+# two tilings may round the same float32 accumulator one ulp apart)
+HALF_RTOL = 2e-2
+HALF_ATOL = 2e-2
 
 
 def _leaves(out):
@@ -63,8 +67,9 @@ def time_min_ms(fn, args, rounds=3, iters=5):
 
 def outputs_match(ref, got, rtol=FLOAT_RTOL, atol=FLOAT_ATOL):
     """-> (ok, max_abs_err). Integer outputs must be exactly equal;
-    float outputs must agree within (rtol, atol) elementwise. Structure
-    (leaf count/shape/dtype) must match exactly."""
+    float outputs must agree within (rtol, atol) elementwise (16-bit
+    floats within the wider :data:`HALF_RTOL` / :data:`HALF_ATOL`).
+    Structure (leaf count/shape/dtype) must match exactly."""
     import numpy as np
 
     ref_l, got_l = _leaves(ref), _leaves(got)
@@ -81,11 +86,16 @@ def outputs_match(ref, got, rtol=FLOAT_RTOL, atol=FLOAT_ATOL):
                 return False, float(
                     np.max(np.abs(r.astype(np.int64) - g.astype(np.int64))))
             continue
+        leaf_rtol, leaf_atol = rtol, atol
+        if r.dtype.itemsize == 2:
+            leaf_rtol = max(rtol, HALF_RTOL)
+            leaf_atol = max(atol, HALF_ATOL)
         r64 = r.astype(np.float64)
         g64 = g.astype(np.float64)
         err = np.abs(r64 - g64)
         worst = max(worst, float(err.max()) if err.size else 0.0)
-        if not np.allclose(r64, g64, rtol=rtol, atol=atol, equal_nan=True):
+        if not np.allclose(r64, g64, rtol=leaf_rtol, atol=leaf_atol,
+                           equal_nan=True):
             return False, worst
     return True, worst
 

@@ -51,7 +51,26 @@ MIN_SUBLANE = 8
 # The declared candidate axes per kernel — what the search driver sweeps
 # and what validate_table() accepts. Block axes are legal-subset-filtered
 # per shape at candidate-generation time.
-FLASH_BLOCK_CANDIDATES = (256, 128, 64, 32, 16, 8)
+FLASH_BLOCK_CANDIDATES = (1024, 512, 256, 128, 64, 32, 16, 8)
+# Heads of the flattened batch*head axis a flash-forward grid step may
+# take together, and the score elements (heads x block_q x block_k) a
+# step is filled up to: one 512 x 512 tile's worth. Measured on a v5e at
+# (128, 1024, 64) bf16 (PERF.md, PR 28): a grid step costs about a third
+# of a microsecond whatever it holds, and the compiler interleaves the
+# unrolled heads' code, so eight heads of 128 x 128 run at 0.29 of one
+# and four of 256 x 256 at 0.61. Past one 512 x 512 tile the gain is
+# 10 % of the kernel and the unrolled code costs the step 6 s of compile
+# time (four heads of 512 x 512: 59.8 s against 53.3 s), so no further.
+FLASH_HEAD_CANDIDATES = (8, 4, 2, 1)
+FLASH_STEP_SCORES = 512 * 512
+# The TPU's lane width: the flash forward keeps its row statistics
+# replicated over one lane tile and lays the emitted lse along lanes.
+LANES = 128
+# VMEM a flash-forward step may plan for under the compiler's scoped
+# default (16 MiB on a v5e), and the most the kernel ever asks for
+# (a v5e core has 128 MiB).
+FLASH_VMEM_BUDGET = 12 * 2 ** 20
+FLASH_VMEM_CEILING = 48 * 2 ** 20
 DECODE_PAGE_BLOCK_CANDIDATES = (16, 8, 4, 2, 1)
 SEARCH_SPACE = {
     # Pallas streaming flash-attention forward (ops/pallas_kernels.py);
@@ -77,10 +96,12 @@ SEARCH_SPACE = {
     "decode_attn": {"block_pages": DECODE_PAGE_BLOCK_CANDIDATES},
 }
 
-# What a kernel runs when the table has no entry — the hand-written
-# pre-autotune constants, so an empty table is bitwise the old behavior.
+# What a kernel runs when the table has no entry (block sizes are
+# legalized down to the shape). The flash forward's is chip-measured
+# (PERF.md, PR 28); the others are the hand-written pre-autotune
+# constants.
 DEFAULT_SCHEDULES = {
-    "flash_fwd": {"block_q": 128, "block_k": 128},
+    "flash_fwd": {"block_q": 512, "block_k": 512},
     "flash_bwd": {"block_k": 128},
     "int8_fc": {"operand_width": "int8"},
     "int8_conv": {"operand_width": "int8"},
@@ -100,16 +121,17 @@ class ScheduleError(ValueError):
 
 def legalize_block(t, want):
     """The largest legal block ``<= want`` for sequence length ``t``:
-    either ``t`` itself (a single block covering the whole sequence,
-    legal at any length), or a multiple of :data:`MIN_SUBLANE` that
-    divides ``t``. Returns None when no legal block exists — callers
-    raise :class:`ScheduleError` (``impl="auto"`` asks
-    :func:`flash_shape_supported` first)."""
+    either ``t`` itself (a single block covering the whole sequence:
+    legal on the sublane grid, and off it up to one lane tile, the
+    envelope the kernels have always had), or a multiple of
+    :data:`MIN_SUBLANE` that divides ``t``. Returns None when no legal
+    block exists — callers raise :class:`ScheduleError`
+    (``impl="auto"`` asks :func:`flash_shape_supported` first)."""
     t = int(t)
     want = int(want)
     if t <= 0 or want <= 0:
         return None
-    if want >= t:
+    if want >= t and (t % MIN_SUBLANE == 0 or t <= LANES):
         return t
     b = (min(want, t) // MIN_SUBLANE) * MIN_SUBLANE
     while b >= MIN_SUBLANE:
@@ -304,13 +326,59 @@ def decode_shape_key(batch, pages):
 
 # ----------------------------------------------- flash-kernel resolution
 
+def _pad(n, to):
+    return -(-int(n) // to) * to
+
+
+def flash_fwd_vmem_bytes(hb, bq, bk, d, itemsize):
+    """What one grid step of the flash forward holds in VMEM, from its
+    shapes: the q/o and k/v blocks (double-buffered, D padded to the lane
+    tile), the float32 statistics and accumulator, the lse row, and the
+    (bq, bk) float32 score tile with the copies the softmax makes of it
+    (masked scores, probabilities, probabilities in the operand dtype)."""
+    dl = _pad(d, LANES)
+    blocks = 2 * hb * (2 * bq + 2 * bk) * dl * itemsize
+    stats = hb * bq * (2 * LANES + dl) * 4
+    lse = 2 * hb * MIN_SUBLANE * _pad(bq, LANES) * 4
+    scores = 4 * _pad(bq, MIN_SUBLANE) * _pad(bk, LANES) * 4
+    return blocks + stats + lse + scores
+
+
+def flash_fwd_vmem_limit(hb, bq, bk, d, itemsize):
+    """The kernel's ``vmem_limit_bytes``: None (the compiler's own
+    scoped default) while the step fits it, else the estimate with half
+    again for what the compiler adds, up to
+    :data:`FLASH_VMEM_CEILING`."""
+    need = flash_fwd_vmem_bytes(hb, bq, bk, d, itemsize)
+    if need <= FLASH_VMEM_BUDGET:
+        return None
+    return min(need + need // 2, FLASH_VMEM_CEILING)
+
+
+def flash_fwd_heads(bh, bq, bk, d, itemsize):
+    """Heads (rows of the flattened batch*head axis) one grid step takes:
+    as many as bring the step's score tiles up to
+    :data:`FLASH_STEP_SCORES` and still fit the VMEM budget, from
+    :data:`FLASH_HEAD_CANDIDATES`, dividing ``bh``. A function of the
+    tile, not a schedule axis of its own: small tiles (short sequences,
+    many heads) get the grid step's fixed cost spread over several
+    heads, large ones run one head a step."""
+    for hb in FLASH_HEAD_CANDIDATES:
+        if int(bh) % hb == 0 and hb * bq * bk <= FLASH_STEP_SCORES and \
+                flash_fwd_vmem_bytes(hb, bq, bk, d, itemsize) \
+                <= FLASH_VMEM_BUDGET:
+            return hb
+    return 1
+
 
 def flash_fwd_blocks(bh, t, d, dtype, interpret=False, block_q=None,
                      block_k=None):
     """Resolved + legalized (block_q, block_k) for the flash forward.
     Explicit overrides must already be legal (the search driver's
-    contract); table/default blocks are legalized down. Raises
-    :class:`ScheduleError` when the shape has no legal schedule."""
+    contract); table/default blocks are legalized down. How many heads
+    share a grid step follows from the tile (:func:`flash_fwd_heads`).
+    Raises :class:`ScheduleError` when the shape has no legal
+    schedule."""
     t = int(t)
     if int(d) > 256:
         raise ScheduleError(f"flash schedule: unsupported D={d} (> 256)")

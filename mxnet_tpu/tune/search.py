@@ -138,31 +138,39 @@ def run_search(workload, table_path, rounds=3, iters=5, force=False):
 
 # --------------------------------------------------------- flash workloads
 
-def _flash_qkv(b, h, t, d, seed):
+def _flash_qkv(b, h, t, d, seed, dtype="float32"):
     import numpy as np
 
     import jax.numpy as jnp
 
     rs = np.random.RandomState(seed)
-    return [jnp.asarray(rs.randn(b, h, t, d).astype(np.float32) * 0.3)
+    return [jnp.asarray(rs.randn(b, h, t, d).astype(np.float32) * 0.3,
+                        dtype)
             for _ in range(3)]
 
 
-def _flash_block_pairs(t, quick=False):
+def _flash_block_pairs(t, quick=False, min_block=None):
     legal = schedule.legal_flash_blocks(t)
+    if min_block is not None:
+        legal = [b for b in legal if b >= min_block] or legal[:1]
     if quick:
         legal = [b for b in legal if b in (128, 64)] or legal[:2]
     return [{"block_q": bq, "block_k": bk} for bq in legal for bk in legal]
 
 
 def flash_fwd_workload(b=2, h=1, t=256, d=32, causal=True, interpret=False,
-                       seed=11, quick=False, k_offset=0, label=None):
-    """Flash-attention forward sweep at one shape. ``k_offset != 0``
-    shapes the ring-attention per-hop case (rotated K/V block placed
-    later in the global sequence — same kernel, hop-shaped masking).
-    ``interpret=True`` (tests, ``autotune.py --demo``) times the Pallas
-    interpreter and keys the table under ``interpret``."""
-    q, k, v = _flash_qkv(b, h, t, d, seed)
+                       seed=11, quick=False, k_offset=0, label=None,
+                       dtype="float32", min_block=None):
+    """Flash-attention forward sweep at one shape and dtype (the entry
+    is keyed by both: the kernel feeds the MXU the input's dtype).
+    ``min_block`` leaves the narrow tiles out of a production shape's
+    sweep (an 8 x 8 tile at T = 1024 is two million grid steps a call).
+    ``k_offset != 0`` shapes the ring-attention per-hop case (rotated
+    K/V block placed later in the global sequence — same kernel,
+    hop-shaped masking). ``interpret=True`` (tests, ``autotune.py
+    --demo``) times the Pallas interpreter and keys the table under
+    ``interpret``."""
+    q, k, v = _flash_qkv(b, h, t, d, seed, dtype)
 
     def build(sched):
         import jax
@@ -179,10 +187,10 @@ def flash_fwd_workload(b=2, h=1, t=256, d=32, causal=True, interpret=False,
     ref = {"block_q": schedule.legalize_block(t, default["block_q"]),
            "block_k": schedule.legalize_block(t, default["block_k"])}
     return Workload(
-        "flash_fwd", schedule.flash_shape_key(b * h, t, d), "float32",
+        "flash_fwd", schedule.flash_shape_key(b * h, t, d), str(dtype),
         schedule.resolve_backend(interpret), build,
-        _flash_block_pairs(t, quick=quick), label=label or "flash_fwd",
-        reference=ref)
+        _flash_block_pairs(t, quick=quick, min_block=min_block),
+        label=label or "flash_fwd", reference=ref)
 
 
 def flash_bwd_workload(b=2, h=1, t=256, d=32, causal=True, interpret=False,

@@ -79,6 +79,121 @@ def test_explicit_override_must_divide():
         block_q=64, block_k=32) == (64, 32)
 
 
+def test_widened_flash_axes_keep_the_short_sequences():
+    """The candidate axes reach 1024; what is legal at T <= 256 is what
+    it was, and a wide tile validates as a table value."""
+    assert schedule.legal_flash_blocks(1024)[:4] == [1024, 512, 256, 128]
+    assert schedule.legal_flash_blocks(512)[:2] == [512, 256]
+    assert schedule.legal_flash_blocks(256) == [256, 128, 64, 32, 16, 8]
+    assert set(schedule.SEARCH_SPACE["flash_fwd"]) == {"block_q", "block_k"}
+    assert schedule.SEARCH_SPACE["flash_bwd"]["block_k"][0] == 1024
+    wide = {"schema_version": 1, "entries": {
+        "flash_fwd|tpu|bfloat16|bh128-t1024-d64": {
+            "schedule": {"block_q": 1024, "block_k": 512}}}}
+    assert schedule.validate_table(wide) == []
+
+
+@pytest.mark.parametrize("t,d,dtype,itemsize", [
+    (65, 64, "float32", 4), (96, 32, "float32", 4), (200, 32, "bfloat16", 2),
+    (1024, 64, "bfloat16", 2), (1024, 256, "float32", 4),
+    (1024, 256, "bfloat16", 2), (4096, 128, "bfloat16", 2)])
+def test_default_flash_schedule_is_legal_by_shape(t, d, dtype, itemsize,
+                                                  monkeypatch):
+    """A shape without a table entry gets a legal tile that is worth a
+    grid step and fits VMEM at its D and dtype: it depends on what the
+    builder sees, not on a model's name."""
+    monkeypatch.setenv("MXNET_TPU_AUTOTUNE", "0")   # no table: the default
+    bq, bk = schedule.flash_fwd_blocks(16, t, d, dtype, interpret=True)
+    for b in (bq, bk):
+        assert t % b == 0 and (b == t or b % schedule.MIN_SUBLANE == 0)
+    assert (bq, bk) == (min(t, 512), min(t, 512))
+    # a step is filled up to one 512 x 512 tile: one head of it, more of less
+    assert (schedule.flash_fwd_heads(16, bq, bk, d, itemsize) == 1) == (
+        2 * bq * bk > schedule.FLASH_STEP_SCORES)
+    hb = schedule.flash_fwd_heads(16, bq, bk, d, itemsize)
+    assert hb in schedule.FLASH_HEAD_CANDIDATES and 16 % hb == 0
+    assert hb * bq * bk <= schedule.FLASH_STEP_SCORES or hb == 1
+    assert schedule.flash_fwd_vmem_bytes(hb, bq, bk, d, itemsize) \
+        <= schedule.FLASH_VMEM_BUDGET
+    assert schedule.flash_fwd_vmem_limit(hb, bq, bk, d, itemsize) is None
+    # an odd head count still gets a divisor
+    assert 6 % schedule.flash_fwd_heads(6, bq, bk, d, itemsize) == 0
+
+
+def test_flash_vmem_limit_rises_with_the_tile():
+    """A tile over the compiler's scoped default asks for its own
+    limit, under the ceiling (1024 x 1024 in float32 at D = 256)."""
+    need = schedule.flash_fwd_vmem_bytes(1, 1024, 1024, 256, 4)
+    assert need > schedule.FLASH_VMEM_BUDGET
+    limit = schedule.flash_fwd_vmem_limit(1, 1024, 1024, 256, 4)
+    assert need < limit <= schedule.FLASH_VMEM_CEILING
+    assert schedule.flash_fwd_heads(16, 1024, 1024, 256, 4) == 1
+
+
+def test_committed_tpu_entry_resolves_for_the_gpt2_cell(monkeypatch):
+    """tools/schedule_table.json carries the chip-measured entry of the
+    GPT-2 medium training cell's attention (ROADMAP A5): on a TPU
+    backend the builder's lookup hits it."""
+    monkeypatch.delenv("MXNET_TPU_SCHEDULE_TABLE", raising=False)
+    monkeypatch.setenv("MXNET_TPU_AUTOTUNE", "1")
+    key = schedule.entry_key("flash_fwd",
+                             schedule.flash_shape_key(8 * 16, 1024, 64),
+                             "bfloat16", "tpu")
+    assert key == "flash_fwd|tpu|bfloat16|bh128-t1024-d64"
+    entry = schedule.load_single_table(schedule.default_table_path())[key]
+    assert schedule.validate_table(
+        {"schema_version": 1, "entries": {key: entry}}) == []
+    # its paired measurements: the winner against the reference schedule
+    assert 0 < entry["measured_ms"] <= entry["ref_ms"]
+    assert entry["candidates"] >= 16 and entry["tuned_at"]
+    monkeypatch.setattr(schedule, "resolve_backend",
+                        lambda interpret=False: "tpu")
+    tune.reset_stats()
+    sched = entry["schedule"]
+    assert schedule.flash_fwd_blocks(128, 1024, 64, "bfloat16") == (
+        sched["block_q"], sched["block_k"])
+    assert tune.stats()["autotune_table_hits"] == 1
+    assert tune.stats()["autotune_table_misses"] == 0
+    # float32 inputs at the same shape have no entry: the default
+    assert schedule.flash_fwd_blocks(128, 1024, 64, "float32") == (512, 512)
+    assert tune.stats()["autotune_table_misses"] == 1
+
+
+def test_flash_fwd_workload_is_keyed_by_its_dtype(tmp_path):
+    """The workload builds inputs of the dtype it is given and keys the
+    entry by it; bf16 candidates pass the gate at bf16's rounding."""
+    import jax.numpy as jnp
+
+    wl = search.flash_fwd_workload(b=1, h=2, t=128, d=16, interpret=True,
+                                   quick=True, dtype="bfloat16")
+    assert wl.dtype == "bfloat16"
+    fn, args = wl.build(wl.reference())
+    assert all(a.dtype == jnp.bfloat16 for a in args)
+    tbl = str(tmp_path / "t.json")
+    res = search.run_search(wl, tbl, rounds=1, iters=1)
+    assert res["key"] == "flash_fwd|interpret|bfloat16|bh2-t128-d16"
+    assert res["rejected"] == 0 and res["candidates"] == 4
+    assert res["key"] in schedule.load_single_table(tbl)
+    # the production sweep leaves the narrow tiles out
+    wide = search.flash_fwd_workload(b=1, h=1, t=1024, d=16,
+                                     interpret=True, min_block=128)
+    assert len(wide.candidates()) == 16
+    assert min(c["block_k"] for c in wide.candidates()) == 128
+
+
+def test_outputs_match_half_precision_bar():
+    import jax.numpy as jnp
+
+    ref = jnp.asarray([1.0, -0.5, 0.25], jnp.bfloat16)
+    ulp = jnp.asarray([1.0078125, -0.5, 0.25], jnp.bfloat16)
+    assert measure.outputs_match(ref, ulp)[0]
+    assert not measure.outputs_match(
+        ref, jnp.asarray([1.1, -0.5, 0.25], jnp.bfloat16))[0]
+    # float32 outputs keep the tight bar
+    assert not measure.outputs_match(
+        ref.astype(jnp.float32), ulp.astype(jnp.float32))[0]
+
+
 # ----------------------------------------------- candidate numerics parity
 
 def _qkv(b, h, t, d, seed=0):

@@ -41,6 +41,38 @@ def test_flash_forward_is_named_in_the_lowered_program(one_chip):
     assert "flash_attention_fwd/pallas_call" in text
 
 
+# (B, H, T, D), dtype: the GPT-2 medium training cell's attention, the
+# widest head in float32, sequences off the lane tile, a tile narrower
+# than a lane tile by explicit blocks
+_FLASH_SHAPES = [((8, 16, 1024, 64), jnp.bfloat16, {}),
+                 ((1, 2, 1024, 256), jnp.float32, {}),
+                 ((1, 2, 2048, 128), jnp.bfloat16, {}),
+                 ((1, 2, 65, 64), jnp.bfloat16, {}),
+                 ((1, 2, 96, 32), jnp.float32, {}),
+                 ((1, 1, 200, 32), jnp.float32, {}),
+                 ((1, 1, 200, 32), jnp.bfloat16,
+                  {"block_q": 40, "block_k": 40}),
+                 ((1, 2, 256, 64), jnp.bfloat16,
+                  {"block_q": 64, "block_k": 128})]
+
+
+@pytest.mark.parametrize("shape,dtype,blocks", _FLASH_SHAPES)
+def test_flash_forward_compiles_at_real_widths(one_chip, shape, dtype,
+                                               blocks):
+    """What interpret mode cannot show: Mosaic takes the tile program
+    (block shapes, the lane-dense lse rows, VMEM) at these widths, with
+    traced hop offsets, under the default schedule by shape."""
+    from mxnet_tpu.ops import pallas_kernels
+
+    qkv = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    off = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda q, k, v, qo, ko: pallas_kernels.flash_attention(
+            q, k, v, causal=True, return_lse=True, q_offset=qo,
+            k_offset=ko, **blocks)).lower(qkv, qkv, qkv, off, off).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_conv3x3_bn_stats_is_named_in_the_lowered_program(one_chip):
     from mxnet_tpu.ops import pallas_kernels
 

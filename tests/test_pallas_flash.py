@@ -159,6 +159,142 @@ def test_flash_backward_matches_dense(causal):
                                    err_msg=f"grad {name}")
 
 
+def _dense_ref(q, k, v, causal, q_offset=0, k_offset=0):
+    """float32 (out, lse, seen) with the causal mask placed by global
+    offsets; ``seen`` marks the rows that see a key at all."""
+    q, k, v = (np.asarray(x, np.float32) for x in (q, k, v))
+    s = np.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    keep = np.ones(s.shape[-2:], bool)
+    if causal:
+        qpos = q_offset + np.arange(q.shape[2])
+        kpos = k_offset + np.arange(k.shape[2])
+        keep = qpos[:, None] >= kpos[None, :]
+        s = np.where(keep, s, -1e30)
+    m = s.max(-1, keepdims=True)
+    e = np.exp(s - m)
+    l = e.sum(-1, keepdims=True)
+    return (np.einsum("bhqk,bhkd->bhqd", e / l, v), m + np.log(l),
+            keep.any(-1))
+
+
+# (T, D, block_q, block_k): block_q != block_k both ways, a block of 512
+# at T = 1024, and 64-wide tiles at T = 256, where a q block meets tiles
+# wholly below, on and wholly above the diagonal
+_TILINGS = [(256, 32, 64, 64), (256, 32, 128, 32), (256, 32, 32, 128),
+            (1024, 16, 512, 512), (1024, 16, 512, 256), (200, 32, 40, 200)]
+
+
+@pytest.mark.parametrize("dtype,atol", [("float32", 1e-5),
+                                        ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("t,d,bq,bk", _TILINGS)
+def test_tilings_match_float32_reference(t, d, bq, bk, causal, dtype, atol):
+    """Operands go to the MXU in the input's dtype and accumulate in
+    float32: float32 inputs keep their 1e-5 agreement with the dense
+    path, bf16 inputs stay within bf16's rounding of the float32
+    reference computed from the same (rounded) inputs."""
+    import jax.numpy as jnp
+
+    q, k, v = (jnp.asarray(a, dtype)
+               for a in _qkv(B=1, H=2, T=t, D=d, seed=t + bq))
+    out, lse = flash_attention(q, k, v, causal=causal, interpret=True,
+                               return_lse=True, block_q=bq, block_k=bk)
+    assert out.dtype == q.dtype and out.shape == q.shape
+    assert lse.dtype == jnp.float32 and lse.shape == (1, 2, t, 1)
+    ref, ref_lse, _ = _dense_ref(q, k, v, causal)
+    np.testing.assert_allclose(np.asarray(out, np.float32), ref, atol=atol)
+    np.testing.assert_allclose(np.asarray(lse), ref_lse,
+                               atol=1e-5 if dtype == "float32" else 1e-3)
+
+
+# ring-attention hops: (q_offset, k_offset) of a T = 256 block pair
+_HOPS = [(512, 256),    # K/V wholly in the past: no tile is masked
+         (256, 256),    # the diagonal hop
+         (256, 384),    # K/V half a block ahead: rows 0..127 see nothing
+         (0, 256),      # K/V wholly in the future: no live tile at all
+         (0, 1024)]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("bq,bk", [(256, 256), (64, 128), (128, 64)])
+@pytest.mark.parametrize("q_offset,k_offset", _HOPS)
+def test_hop_offsets_place_the_mask(q_offset, k_offset, bq, bk, dtype):
+    """The K/V index maps are clamped by the traced offsets, not by
+    q_offset == k_offset: rows that see a key match the reference; a hop
+    wholly in the future comes out as zeros with lse about -1e30."""
+    import jax
+    import jax.numpy as jnp
+
+    t = 256
+    q, k, v = (jnp.asarray(a, dtype)
+               for a in _qkv(B=1, H=2, T=t, D=32, seed=k_offset))
+    hop = jax.jit(lambda q, k, v, qo, ko: flash_attention(
+        q, k, v, causal=True, interpret=True, return_lse=True,
+        q_offset=qo, k_offset=ko, block_q=bq, block_k=bk))
+    out, lse = hop(q, k, v, jnp.int32(q_offset), jnp.int32(k_offset))
+    out, lse = np.asarray(out, np.float32), np.asarray(lse)
+    ref, ref_lse, seen = _dense_ref(q, k, v, True, q_offset, k_offset)
+    atol = 1e-5 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(out[:, :, seen], ref[:, :, seen], atol=atol)
+    np.testing.assert_allclose(lse[:, :, seen], ref_lse[:, :, seen],
+                               atol=atol)
+    # a row that sees nothing carries no weight into the ring's merge
+    assert (lse[:, :, ~seen] < -1e29).all()
+    if not seen.any():
+        assert (out == 0).all()
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("bq,bk", [(None, None), (64, 128), (128, 32)])
+def test_bf16_gradients_match_float32_reference(bq, bk, causal):
+    """flash_attention_with_grad on bf16 inputs against autodiff through
+    the float32 dense composition of the same inputs."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops.pallas_kernels import flash_attention_with_grad
+
+    q, k, v = (jnp.asarray(a, jnp.bfloat16)
+               for a in _qkv(B=1, H=2, T=256, D=32, seed=9))
+
+    def loss_flash(q_, k_, v_):
+        out = flash_attention_with_grad(q_, k_, v_, causal=causal,
+                                        interpret=True, block_q=bq,
+                                        block_k=bk)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    def loss_dense(q_, k_, v_):
+        s = jnp.einsum("bhqd,bhkd->bhqk", q_, k_) / np.sqrt(q_.shape[-1])
+        if causal:
+            s = jnp.where(jnp.tril(jnp.ones(s.shape[-2:], bool)), s, -1e30)
+        w = jax.nn.softmax(s, -1)
+        return jnp.sum(jnp.einsum("bhqk,bhkd->bhqd", w, v_) ** 2)
+
+    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    gd = jax.grad(loss_dense, argnums=(0, 1, 2))(
+        *(x.astype(jnp.float32) for x in (q, k, v)))
+    for a, b, name in zip(gf, gd, "qkv"):
+        assert a.dtype == jnp.bfloat16
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(np.asarray(a, np.float32) / scale,
+                                   np.asarray(b) / scale, atol=2e-2,
+                                   err_msg=f"grad {name}")
+
+
+def test_several_heads_share_a_grid_step():
+    """Short sequences take several heads a grid step
+    (schedule.flash_fwd_heads); every head still gets its own K/V."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.tune import schedule
+
+    q, k, v = (jnp.asarray(a) for a in _qkv(B=2, H=4, T=64, D=32, seed=2))
+    assert schedule.flash_fwd_heads(8, 64, 64, 32, 4) > 1
+    out = flash_attention(q, k, v, causal=True, interpret=True)
+    np.testing.assert_allclose(np.asarray(out), _dense(q, k, v, True),
+                               atol=1e-5)
+
+
 def test_conv3x3_bn_stats_interpret():
     """Fused conv+BN-stats kernel: exact vs the XLA composition."""
     import jax

@@ -55,13 +55,23 @@ def resolve_table(arg):
 def build_workloads(quick, interpret=False):
     """The shipped sweep: flash fwd (plain + ring-hop-shaped) and bwd,
     int8 FC / conv / requantize. Shapes are small and fixed-seed so the
-    demo is cheap and reproducible; the full mode widens only the
-    candidate spaces, not the shapes — re-run with a bespoke driver for
-    production shapes."""
+    demo is cheap and reproducible; the full mode widens the candidate
+    spaces and adds the benchmark cells' own shapes (which need the
+    chip)."""
     from mxnet_tpu.tune import search
 
     kw = {"quick": quick, "interpret": interpret}
-    return [
+    # the GPT-2 medium training cell's attention (benchmarks/configs/
+    # gpt2-medium.json under traffic/train_seq1024.json: batch 8 x 16
+    # heads, 1024 positions, head 64, bf16 under the training policy) —
+    # a production shape, so only outside the demo: how the committed
+    # ``flash_fwd|tpu|bfloat16|bh128-t1024-d64`` entry was made
+    production = [] if quick else [
+        search.flash_fwd_workload(b=8, h=16, t=1024, d=64, causal=True,
+                                  dtype="bfloat16", min_block=128,
+                                  interpret=interpret,
+                                  label="gpt2m_train_fwd")]
+    return production + [
         search.flash_fwd_workload(b=2, h=1, t=256, d=32, causal=True,
                                   **kw, label="flash_fwd"),
         # the ring-attention per-hop case: a rotated K/V block placed
