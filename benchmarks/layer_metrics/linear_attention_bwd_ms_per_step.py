@@ -1,0 +1,9 @@
+"""Device time of linear attention's backward pass per training step,
+chip 0: backward ops under the ``linear_attention`` scope, the
+recomputed forward of a ``Remat`` layer among them
+(``benchmarks/scopes.py``). Layer: kernels."""
+from benchmarks import scopes
+
+
+def read(run):
+    return scopes.scope_ms(run, "linear_attention", "backward")

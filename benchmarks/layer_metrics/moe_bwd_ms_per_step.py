@@ -1,0 +1,8 @@
+"""Device time of the expert layers' backward pass per training step,
+chip 0: backward ops under the ``moe`` scope, the recomputed forward of
+a ``Remat`` layer among them (``benchmarks/scopes.py``). Layer: moe."""
+from benchmarks import scopes
+
+
+def read(run):
+    return scopes.scope_ms(run, "moe", "backward")

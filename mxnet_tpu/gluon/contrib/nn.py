@@ -2,18 +2,24 @@
 
 Capability parity with python/mxnet/gluon/contrib/nn/basic_layers.py:
 Concurrent/HybridConcurrent (parallel branches, concatenated),
-Identity, SparseEmbedding, SyncBatchNorm.
+Identity, SparseEmbedding, SyncBatchNorm. Beyond the reference: Remat,
+MultiHeadAttention, and the blocks of today's decoder layers --
+GatedAttention (grouped K/V heads, rotary on part of a head, an output
+gate), GatedDeltaNet (linear attention by the gated delta rule),
+GatedMLP and SparseMoE (an expert layer told which experts it holds).
 """
 from __future__ import annotations
 
 import warnings
 
 from .. import nn as _nn
+from ... import initializer as _init
 from ... import jit as _jit
 from ..block import Block, HybridBlock
 
 __all__ = ["Remat", "Concurrent", "HybridConcurrent", "Identity", "SparseEmbedding",
-           "SyncBatchNorm"]
+           "SyncBatchNorm", "GatedAttention",
+           "GatedDeltaNet", "GatedMLP", "SparseMoE"]
 
 
 class Concurrent(_nn.Sequential):
@@ -266,3 +272,244 @@ class MultiHeadAttention(HybridBlock):
         out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
                         shape=(b, l, h * d))
         return self.out_proj(out)
+
+
+def _dense(units, in_units, prefix):
+    return _nn.Dense(units, use_bias=False, flatten=False, in_units=in_units,
+                     prefix=prefix)
+
+
+class _Own(_init.Initializer):
+    """``fn(shape) -> values``, whatever the parameter's name ends in
+    (the base class picks zeros or ones from a name's suffix)."""
+
+    def __init__(self, fn):
+        super().__init__()
+        self._fn = fn
+
+    def __call__(self, desc, arr):
+        arr[:] = self._fn(arr.shape)
+
+
+class GatedAttention(HybridBlock):
+    """Causal grouped-query self-attention with an output gate, as
+    Qwen3-Next's full-attention layers have it: ``q_proj`` gives, per
+    head, ``head_dim`` of query then ``head_dim`` of gate; zero-centred
+    RMSNorm over the head on q and on k; rotary positions (half-split
+    pairing) on the first ``rotary_dim`` channels of each head; each of
+    the ``num_kv_heads`` K/V heads serves ``num_heads / num_kv_heads``
+    query heads; the result times ``sigmoid(gate)``; ``out_proj``. No
+    biases; ``head_dim`` is free of ``units / num_heads``. ``impl`` as
+    MultiHeadAttention's 'dense' / 'flash'. x (B, T, units)."""
+
+    def __init__(self, units, num_heads, num_kv_heads, head_dim,
+                 rotary_dim=None, rope_theta=10000.0, epsilon=1e-6,
+                 impl="dense", **kwargs):
+        super().__init__(**kwargs)
+        if num_heads % num_kv_heads:
+            raise ValueError(f"num_heads {num_heads} not divisible by "
+                             f"num_kv_heads {num_kv_heads}")
+        if impl not in ("dense", "flash"):
+            raise ValueError(f"unknown impl {impl!r}")
+        self._heads, self._kv, self._dim = num_heads, num_kv_heads, head_dim
+        self._rotary = {"rotary_dim": head_dim if rotary_dim is None
+                        else int(rotary_dim), "theta": float(rope_theta)}
+        self._impl = impl
+        with self.name_scope():
+            self.q_proj = _dense(num_heads * 2 * head_dim, units, "q_")
+            self.k_proj = _dense(num_kv_heads * head_dim, units, "k_")
+            self.v_proj = _dense(num_kv_heads * head_dim, units, "v_")
+            self.q_norm = _nn.RMSNorm(head_dim, epsilon, zero_centered=True,
+                                      prefix="qnorm_")
+            self.k_norm = _nn.RMSNorm(head_dim, epsilon, zero_centered=True,
+                                      prefix="knorm_")
+            self.out_proj = _dense(units, num_heads * head_dim, "out_")
+
+    def hybrid_forward(self, F, x):
+        b, t = x.shape[0], x.shape[1]
+        h, kv, d = self._heads, self._kv, self._dim
+        qg = F.reshape(self.q_proj(x), shape=(b, t, h, 2 * d))
+        q = F.slice_axis(qg, axis=-1, begin=0, end=d)
+        gate = F.reshape(F.slice_axis(qg, axis=-1, begin=d, end=2 * d),
+                         shape=(b, t, h * d))
+        k = F.reshape(self.k_proj(x), shape=(b, t, kv, d))
+        v = F.reshape(self.v_proj(x), shape=(b, t, kv, d))
+        pos = F.arange(0, t, dtype="int32")
+        q = F.rotary_embedding(F.transpose(self.q_norm(q), axes=(0, 2, 1, 3)),
+                               pos, **self._rotary)
+        k = F.rotary_embedding(F.transpose(self.k_norm(k), axes=(0, 2, 1, 3)),
+                               pos, **self._rotary)
+        v = F.transpose(v, axes=(0, 2, 1, 3))
+        if h != kv:
+            k = F.repeat(k, repeats=h // kv, axis=1)
+            v = F.repeat(v, repeats=h // kv, axis=1)
+        with _jit.scope("attention"):
+            out = F.scaled_dot_product_attention(
+                q, k, v, causal=True,
+                impl="flash" if self._impl == "flash" else "xla")
+        out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
+                        shape=(b, t, h * d))
+        return self.out_proj(out * F.sigmoid(gate))
+
+
+class GatedDeltaNet(HybridBlock):
+    """Linear attention by the gated delta rule (Yang et al.,
+    arXiv:2412.06464), as Qwen3-Next's linear layers have it.
+    ``qkvz_proj`` gives q, k (``num_k_heads`` of ``head_k_dim``), v and
+    the output gate z (``num_v_heads`` of ``head_v_dim``), laid
+    [q | k | v | z]; ``ba_proj`` gives b then a (``num_v_heads`` each).
+    [q, k, v] pass a causal depthwise convolution of ``conv_kernel``
+    taps without bias, then SiLU; ``beta = sigmoid(b)``,
+    ``g = -exp(A_log) * softplus(a + dt_bias)`` in float32; the chunked
+    delta rule (``ops/linear_attention.py``); a gated RMSNorm per value
+    head with z; ``out_proj``. All but the four projections sits under
+    the scope ``linear_attention``. x (B, T, units)."""
+
+    def __init__(self, units, num_k_heads, num_v_heads, head_k_dim,
+                 head_v_dim, conv_kernel=4, epsilon=1e-6, chunk=64,
+                 **kwargs):
+        super().__init__(**kwargs)
+        if num_v_heads % num_k_heads:
+            raise ValueError(f"num_v_heads {num_v_heads} not divisible by "
+                             f"num_k_heads {num_k_heads}")
+        self._hk, self._hv = num_k_heads, num_v_heads
+        self._dk, self._dv = head_k_dim, head_v_dim
+        self._chunk = chunk
+        key_dim, value_dim = num_k_heads * head_k_dim, num_v_heads * head_v_dim
+        from ... import ndarray as nd
+
+        with self.name_scope():
+            self.qkvz_proj = _dense(2 * key_dim + 2 * value_dim, units,
+                                    "qkvz_")
+            self.ba_proj = _dense(2 * num_v_heads, units, "ba_")
+            self.conv_weight = self.params.get(
+                "conv_weight", shape=(2 * key_dim + value_dim, conv_kernel),
+                init=_init.Uniform(conv_kernel ** -0.5))
+            self.A_log = self.params.get(
+                "A_log", shape=(num_v_heads,), init=_Own(
+                    lambda shape: nd.log(nd.random.uniform(1e-6, 16,
+                                                           shape))))
+            self.dt_bias = self.params.get("dt_bias", shape=(num_v_heads,),
+                                           init=_Own(nd.ones))
+            self.norm = _nn.RMSNorm(head_v_dim, epsilon, prefix="norm_")
+            self.out_proj = _dense(units, value_dim, "out_")
+
+    def hybrid_forward(self, F, x, conv_weight, A_log, dt_bias):
+        b, t = x.shape[0], x.shape[1]
+        hk, hv, dk, dv = self._hk, self._hv, self._dk, self._dv
+        key_dim, value_dim = hk * dk, hv * dv
+        qkvz, ba = self.qkvz_proj(x), self.ba_proj(x)
+
+        def cut(src, begin, size, *heads):
+            out = F.slice_axis(src, axis=-1, begin=begin, end=begin + size)
+            return F.reshape(out, shape=(b, t) + heads) if heads else out
+
+        with _jit.scope("linear_attention"):
+            mixed = F.Activation(F.causal_conv1d(
+                cut(qkvz, 0, 2 * key_dim + value_dim), conv_weight),
+                act_type="silu")
+            q = cut(mixed, 0, key_dim, hk, dk)
+            k = cut(mixed, key_dim, key_dim, hk, dk)
+            v = cut(mixed, 2 * key_dim, value_dim, hv, dv)
+            z = cut(qkvz, 2 * key_dim + value_dim, value_dim, hv, dv)
+            beta = F.sigmoid(F.cast(cut(ba, 0, hv), dtype="float32"))
+            a = F.cast(cut(ba, hv, hv), dtype="float32")
+            g = -F.exp(F.cast(A_log, dtype="float32")) * F.Activation(
+                a + F.cast(dt_bias, dtype="float32"), act_type="softrelu")
+            out = self.norm(F.gated_delta_rule(q, k, v, g, beta,
+                                               chunk=self._chunk), z)
+        return self.out_proj(F.reshape(out, shape=(b, t, value_dim)))
+
+
+class GatedMLP(HybridBlock):
+    """SiLU-gated MLP without biases:
+    ``down(silu(gate x) * up x)``, gate and up in one projection
+    (``gate_up_``: gate rows first)."""
+
+    def __init__(self, units, hidden, **kwargs):
+        super().__init__(**kwargs)
+        self._hidden = hidden
+        with self.name_scope():
+            self.gate_up = _dense(2 * hidden, units, "gate_up_")
+            self.down = _dense(units, hidden, "down_")
+
+    def hybrid_forward(self, F, x):
+        h = self.gate_up(x)
+        gate = F.slice_axis(h, axis=-1, begin=0, end=self._hidden)
+        up = F.slice_axis(h, axis=-1, begin=self._hidden,
+                          end=2 * self._hidden)
+        return self.down(F.Activation(gate, act_type="silu") * up)
+
+
+class SparseMoE(HybridBlock):
+    """A sparse expert layer that is told which experts it holds.
+
+    The router scores ALL ``num_experts_total`` experts in float32,
+    takes the ``top_k`` largest of the softmax and (``renormalize``)
+    renormalises their weights over themselves. Of those assignments,
+    the ones whose expert is in ``experts_held`` -- ``(first, count)``
+    or a ``range``; default all -- are computed here:
+    ``sum w_e * down_e(silu(gate_e x) * up_e x)``, as grouped matrix
+    products over the sorted assignments (``ops/moe.py``): no capacity
+    factor, no token dropped for any routing. What the other experts
+    would add is left out: that is the part of the result one device of
+    an expert-parallel group gives. ``shared_hidden`` adds a shared
+    expert behind a sigmoid gate, ``sigmoid(shared_gate x) * shared(x)``.
+
+    The auxiliary state ``expert_tokens`` (count + 1, moved by every
+    call as BatchNorm moves its statistics) holds the last call's
+    assignments to each held expert and the tokens that chose no held
+    expert. Scopes: ``moe``, with
+    ``moe_router`` and ``moe_experts`` inside."""
+
+    def __init__(self, units, hidden, num_experts_total, top_k,
+                 experts_held=None, shared_hidden=0, renormalize=True,
+                 **kwargs):
+        super().__init__(**kwargs)
+        if experts_held is None:
+            experts_held = (0, num_experts_total)
+        if isinstance(experts_held, range):
+            experts_held = (experts_held.start, len(experts_held))
+        first, count = (int(n) for n in experts_held)
+        if not 0 <= first < first + count <= num_experts_total:
+            raise ValueError(f"experts_held {experts_held} is not a range "
+                             f"of the {num_experts_total} experts")
+        self._route = {"top_k": int(top_k), "renormalize": bool(renormalize)}
+        self._first = first
+
+        def per_expert(fan_in, fan_out):    # Xavier of one expert's matrix
+            return _init.Uniform((6.0 / (fan_in + fan_out)) ** 0.5)
+
+        with self.name_scope():
+            self.router_weight = self.params.get(
+                "router_weight", shape=(num_experts_total, units))
+            self.experts_gate_up_weight = self.params.get(
+                "experts_gate_up_weight", shape=(count, units, 2 * hidden),
+                init=per_expert(units, hidden))
+            self.experts_down_weight = self.params.get(
+                "experts_down_weight", shape=(count, hidden, units),
+                init=per_expert(hidden, units))
+            self.expert_tokens = self.params.get(
+                "expert_tokens", shape=(count + 1,), grad_req="null",
+                init="zeros", differentiable=False)
+            if shared_hidden:
+                self.shared = GatedMLP(units, shared_hidden,
+                                       prefix="shared_")
+                self.shared_gate = _dense(1, units, "shared_gate_")
+            else:
+                self.shared = self.shared_gate = None
+
+    def hybrid_forward(self, F, x, router_weight, experts_gate_up_weight,
+                       experts_down_weight, expert_tokens):
+        with _jit.scope("moe"):
+            with _jit.scope("moe_router"):
+                weights, experts = F.moe_router(x, router_weight,
+                                                **self._route)
+            with _jit.scope("moe_experts"):
+                out = F.moe_experts(x, weights, experts,
+                                    experts_gate_up_weight,
+                                    experts_down_weight, expert_tokens,
+                                    first_expert=self._first)
+            if self.shared is not None:
+                out = out + F.sigmoid(self.shared_gate(x)) * self.shared(x)
+        return out

@@ -2,3 +2,5 @@
 from . import vision
 from . import transformer
 from .transformer import TransformerBlock, TransformerLM, transformer_lm
+from . import qwen3_next
+from .qwen3_next import Qwen3NextBlock, Qwen3NextLM, qwen3_next_lm

@@ -14,7 +14,8 @@ from ..parameter import Parameter
 from ... import initializer
 
 __all__ = ["Sequential", "HybridSequential", "Dense", "Dropout", "BatchNorm",
-           "InstanceNorm", "LayerNorm", "GroupNorm", "Embedding", "Flatten",
+           "InstanceNorm", "LayerNorm", "RMSNorm", "GroupNorm", "Embedding",
+           "Flatten",
            "Lambda", "HybridLambda", "Activation", "LeakyReLU", "PReLU",
            "ELU", "SELU", "Swish", "GELU"]
 
@@ -313,6 +314,33 @@ class LayerNorm(HybridBlock):
         in_channels = self.gamma.shape[0]
         return "{name}({content}, in_channels={in_channels})".format(
             name=self.__class__.__name__, in_channels=in_channels,
+            content=", ".join(f"{k}={v}" for k, v in self._kwargs.items()))
+
+
+class RMSNorm(HybridBlock):
+    """Root-mean-square normalisation over the last axis, in float32:
+    ``x * rsqrt(mean(x^2) + eps) * w``. ``zero_centered`` takes
+    ``w = 1 + weight`` with the weight trained from zero (else from
+    one); called with a second input, ``norm(x, gate)``, it is the gated
+    form ``.. * silu(gate)``."""
+
+    def __init__(self, in_channels, epsilon=1e-6, zero_centered=False,
+                 prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        self._kwargs = {"eps": epsilon, "zero_centered": zero_centered}
+        with self.name_scope():
+            self.weight = self.params.get(
+                "weight", shape=(in_channels,),
+                init="zeros" if zero_centered else "ones")
+
+    def hybrid_forward(self, F, data, gate=None, weight=None):
+        if gate is None:
+            return F.RMSNorm(data, weight, **self._kwargs)
+        return F.RMSNorm(data, weight, gate, **self._kwargs)
+
+    def __repr__(self):
+        return "{name}({content}, in_channels={n})".format(
+            name=self.__class__.__name__, n=self.weight.shape[0],
             content=", ".join(f"{k}={v}" for k, v in self._kwargs.items()))
 
 

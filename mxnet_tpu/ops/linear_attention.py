@@ -1,0 +1,122 @@
+"""Linear attention by the gated delta rule (Gated DeltaNet, Yang et al.
+arXiv:2412.06464), and the short causal convolution in front of it.
+
+Per value head, with a state ``S`` (Dk x Dv, float32, from zero) and,
+for each token, a decay ``g_t <= 0`` and a write strength ``beta_t``:
+
+    S = exp(g_t) S
+    S = S + k_t (beta_t (v_t - S^T k_t))^T
+    o_t = S^T q_t
+
+``gated_delta_rule`` computes this in the chunked form (the upstream
+``torch_chunk_gated_delta_rule``): inside a chunk of ``chunk`` tokens
+the decays are a mask ``exp(cumsum g)``, the chunk's writes are the WY
+factors of a unit lower-triangular solve, and the state moves once a
+chunk, so a sequence of T tokens is T / chunk sequential steps of
+matrix products instead of T rank-one updates. Everything is
+``jax.numpy`` under one ``lax.scan``: differentiable as it stands, the
+backward pass is the scan's own.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from .registry import register
+
+
+@register("causal_conv1d")
+def causal_conv1d(data, weight):
+    """Causal depthwise convolution along time, no bias: ``data``
+    (B, T, C), ``weight`` (C, K);
+    ``y[t, c] = sum_j weight[c, j] * data[t - (K - 1) + j, c]`` with
+    zeros before the sequence."""
+    k = weight.shape[1]
+    t = data.shape[1]
+    padded = jnp.pad(data, ((0, 0), (k - 1, 0), (0, 0)))
+    return sum(padded[:, j:j + t, :] * weight[:, j] for j in range(k))
+
+
+def _l2norm(x, eps=1e-6):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+
+def _chunked(x, n, c, hk):
+    """(B, N * C, Hk * R, ...) -> (B, Hk, R, N, C, ...)."""
+    x = x.reshape((x.shape[0], n, c, hk, x.shape[2] // hk) + x.shape[3:])
+    return jnp.moveaxis(x, (3, 4), (1, 2))
+
+
+@register("gated_delta_rule")
+def gated_delta_rule(q, k, v, g, beta, chunk=64):
+    """``q``, ``k`` (B, T, Hk, Dk), ``v`` (B, T, Hv, Dv), ``g`` and
+    ``beta`` (B, T, Hv) -> (B, T, Hv, Dv) in ``v``'s dtype. ``q`` and
+    ``k`` are L2-normalised over the head here (eps 1e-6), ``q`` scaled
+    by Dk^-0.5; each q/k head serves Hv / Hk value heads (value head h
+    reads key head h // (Hv / Hk)). ``g`` is the log of the decay,
+    ``beta`` in (0, 1). T need not be a multiple of ``chunk``. Decays,
+    the solve, the state and every accumulation are float32; the
+    operands of the products that do not come out of the solve keep
+    ``v``'s dtype (under a bf16 policy that is what the MXU takes of
+    them anyway)."""
+    f32 = jnp.float32
+    b, t, hv, dv = v.shape
+    hk, dk = k.shape[2:]
+    rep = hv // hk
+    q = _l2norm(q.astype(f32)) * dk ** -0.5
+    k = _l2norm(k.astype(f32))
+    vf, g, beta = v.astype(f32), g.astype(f32), beta.astype(f32)
+    pad = (-t) % chunk
+    if pad:     # padded tokens write nothing (beta 0) and do not decay
+        q, k, vf, g, beta = (
+            jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+            for x in (q, k, vf, g, beta))
+    n = (t + pad) // chunk
+    # key-head tensors (B, Hk, 1, N, C, ..) against value-head tensors
+    # (B, Hk, Hv / Hk, N, C, ..): the key heads broadcast, never repeat
+    q, k, vf, g, beta = (_chunked(x, n, chunk, hk)
+                         for x in (q, k, vf, g, beta))  # g: (B, Hk, R, N, C)
+
+    gc = jnp.cumsum(g, axis=-1)
+    rows = jnp.arange(chunk)
+    lower = rows[:, None] >= rows[None, :]
+    # decay from token j to token i of one chunk, 0 above the diagonal
+    # (masked before the exp: the differences there are positive)
+    decay = jnp.exp(jnp.where(lower, gc[..., :, None] - gc[..., None, :],
+                              -jnp.inf))
+    mm = v.dtype
+    kk = jnp.einsum("bhrnid,bhrnjd->bhrnij", k.astype(mm), k.astype(mm),
+                    preferred_element_type=f32)
+    qk = jnp.einsum("bhrnid,bhrnjd->bhrnij", q.astype(mm), k.astype(mm),
+                    preferred_element_type=f32)
+    strict = rows[:, None] > rows[None, :]
+    system = jnp.where(strict, kk * beta[..., None] * decay, 0.0) \
+        + jnp.eye(chunk, dtype=f32)
+    # the WY factors: (I + A) [u, w] = [beta v, beta k exp(gc)]
+    rhs = jnp.concatenate(
+        [vf * beta[..., None], k * (beta * jnp.exp(gc))[..., None]], axis=-1)
+    solved = jax.scipy.linalg.solve_triangular(
+        system, rhs, lower=True, unit_diagonal=True)
+    u, w = solved[..., :dv], solved[..., dv:]
+    local = qk * decay
+    q_in = (q * jnp.exp(gc)[..., None]).astype(mm)    # reads entering state
+    k_out = (k * jnp.exp(gc[..., -1:] - gc)[..., None]).astype(mm)
+    g_end = jnp.exp(gc[..., -1])                      # (B, Hk, R, N)
+
+    def step(state, xs):
+        u_i, w_i, local_i, q_i, k_i, g_i = xs
+        v_new = u_i - jnp.einsum("bhrck,bhrkv->bhrcv", w_i, state)
+        out = jnp.einsum("bhrck,bhrkv->bhrcv", q_i, state,
+                         preferred_element_type=f32) \
+            + jnp.einsum("bhrij,bhrjv->bhriv", local_i, v_new)
+        state = state * g_i[..., None, None] \
+            + jnp.einsum("bhrck,bhrcv->bhrkv", k_i, v_new,
+                         preferred_element_type=f32)
+        return state, out
+
+    _, out = jax.lax.scan(
+        step, jnp.zeros((b, hk, rep, dk, dv), f32),
+        tuple(jnp.moveaxis(x, 3, 0)
+              for x in (u, w, local, q_in, k_out, g_end)))
+    out = jnp.moveaxis(out, 0, 3).reshape(b, hv, n * chunk, dv)
+    return out[:, :, :t].transpose(0, 2, 1, 3).astype(v.dtype)

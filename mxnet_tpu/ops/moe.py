@@ -1,0 +1,116 @@
+"""A sparse expert layer's two parts: the router over all experts, and
+the grouped matrix products over the experts this device holds.
+
+An expert-parallel group divides a layer's experts over its devices.
+Every device routes over ALL the experts (the router keeps its
+published width and its experts per token) and computes the part of the
+result that its own experts give, for the tokens routed to them; the
+exchange between devices adds the parts up. ``moe_experts`` is that
+part: told the first expert it holds (it holds as many as its weights
+have rows), it sorts the (token, expert) assignments that fall on its
+experts, runs ``jax.lax.ragged_dot`` over the sorted rows with the
+per-expert counts as group sizes (a grouped-matmul kernel on the TPU
+whose work follows the counts), and adds the weighted rows back to
+their tokens. No token is dropped for any routing: there is no capacity
+factor. Shapes are static, so the sorted rows are taken as many at a
+time as there are tokens: one such block serves while the assignments
+held fit it (the usual case: a ``lax.cond`` on the count), and a
+``lax.scan`` over the blocks of the worst case (every choice of every
+token held here), each recomputed in the backward pass and skipped
+when it holds no assignment, otherwise. Both are compiled, one runs,
+and neither keeps more than a block's intermediates.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from .registry import register
+
+
+@register("moe_router", num_outputs=2)
+def moe_router(data, weight, top_k=1, renormalize=True):
+    """``data`` (..., d), ``weight`` (experts, d) -> (weights (..., k)
+    float32, experts (..., k) int32): logits over all experts and their
+    softmax in float32, the ``top_k`` largest, their weights
+    renormalised to sum to one when ``renormalize``."""
+    logits = jnp.einsum("...d,ed->...e", data.astype(jnp.float32),
+                        weight.astype(jnp.float32))
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, experts = jax.lax.top_k(probs, int(top_k))
+    if renormalize:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights, experts.astype(jnp.int32)
+
+
+def _block_of_rows(x, flat_w, order, offsets, gate_up, down, top_k, block):
+    """What the sorted assignments [block * tokens, (block + 1) *
+    tokens) add to the result: the experts' gated MLPs over those rows,
+    weighted and added back per token, (tokens, d) float32."""
+    n_rows = x.shape[0]
+    lo = block * n_rows
+    take = jax.lax.dynamic_slice_in_dim(order, lo, n_rows)
+    token = take // top_k
+    # of each expert's run of sorted rows, the part inside this block
+    bounds = jnp.clip(offsets, lo, lo + n_rows)
+    sizes = bounds[1:] - bounds[:-1]
+    valid = (lo + jnp.arange(n_rows) < offsets[-1])[:, None]
+    # rows past the assignments held belong to no group: what the
+    # grouped product leaves there is not defined, so they are zeroed
+    # going in (which zeroes their gradient coming back) and going out
+    xs = jnp.where(valid, x[token], 0)
+    h = jax.lax.ragged_dot(xs, gate_up, sizes)
+    inner = down.shape[1]
+    act = (jax.nn.silu(h[:, :inner].astype(jnp.float32))
+           * h[:, inner:].astype(jnp.float32)).astype(x.dtype)
+    ys = jax.lax.ragged_dot(act, down, sizes)
+    ys = jnp.where(valid, ys.astype(jnp.float32) * flat_w[take][:, None], 0)
+    return jnp.zeros(x.shape, jnp.float32).at[token].add(ys)
+
+
+@register("moe_experts", mutate=(5,))
+def moe_experts(data, weights, experts, gate_up, down, counts,
+                first_expert=0):
+    """``data`` (..., d); ``weights`` / ``experts`` (..., k) from
+    ``moe_router``; ``gate_up`` (held, d, 2 x inner: gate then up) and
+    ``down`` (held, inner, d) of the experts
+    [first_expert, first_expert + held). Returns
+    ``sum_{e chosen and held} w_e * down_e(silu(gate_e x) * up_e x)``
+    and moves ``counts`` (held + 1, float32): the assignments to each
+    held expert in this call (every one of them is computed), and the
+    tokens that chose no held expert."""
+    held, d = gate_up.shape[0], data.shape[-1]
+    top_k = experts.shape[-1]
+    x = data.reshape(-1, d)
+    tokens = x.shape[0]
+    local = experts.reshape(tokens, top_k) - first_expert
+    here = (local >= 0) & (local < held)
+    key = jnp.where(here, local, held).reshape(-1)
+    order = jnp.argsort(key, stable=True)        # held first, by expert
+    sizes = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
+                    dtype=jnp.int32)
+    flat_w = weights.reshape(-1).astype(jnp.float32)
+    offsets = jnp.concatenate([jnp.zeros(1, jnp.int32), jnp.cumsum(sizes)])
+    n_held = offsets[-1]
+
+    def block_of_rows(block):
+        return _block_of_rows(x, flat_w, order, offsets, gate_up, down,
+                              top_k, block)
+
+    def every_block():
+        # every choice of every token held here is min(k, held) blocks
+        def one(total, block):
+            part = jax.lax.cond(
+                block * tokens < n_held, jax.checkpoint(block_of_rows),
+                lambda _: jnp.zeros(x.shape, jnp.float32), block)
+            return total + part, None
+
+        return jax.lax.scan(one, jnp.zeros(x.shape, jnp.float32),
+                            jnp.arange(min(top_k, held)))[0]
+
+    out = jax.lax.cond(n_held <= tokens, lambda: block_of_rows(0),
+                       every_block)
+    out = out.astype(x.dtype)
+    idle = jnp.sum(~jnp.any(here, axis=-1))
+    new_counts = jnp.concatenate([sizes, idle[None]]).astype(counts.dtype)
+    return out.reshape(data.shape), new_counts

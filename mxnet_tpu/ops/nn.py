@@ -293,6 +293,40 @@ def _layer_norm(data, gamma, beta, axis=-1, eps=1e-5, output_mean_var=False):
     return out
 
 
+@register("RMSNorm", aliases=("rms_norm",))
+def _rms_norm(data, gamma, gate=None, eps=1e-6, zero_centered=False):
+    """Root-mean-square normalisation over the last axis, in float32:
+    ``y = x * rsqrt(mean(x^2) + eps) * w``. ``zero_centered`` takes
+    ``w = 1 + gamma`` (gamma trained from zero); with ``gate`` it is the
+    gated form ``y * silu(gate)`` (a linear-attention layer's output
+    norm). The result has ``data``'s dtype."""
+    x = data.astype(jnp.float32)
+    w = gamma.astype(jnp.float32)
+    y = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+                          + eps) * (1.0 + w if zero_centered else w)
+    if gate is not None:
+        y = y * jax.nn.silu(gate.astype(jnp.float32))
+    return y.astype(data.dtype)
+
+
+@register("rotary_embedding")
+def _rotary_embedding(data, positions, rotary_dim=None, theta=10000.0):
+    """Rotary position embedding on the first ``rotary_dim`` channels of
+    each head, half-split pairing (channel i rotates with channel
+    i + rotary_dim / 2); the other channels pass. ``data`` is
+    (B, H, T, D), ``positions`` (T,) integers. Angles in float32."""
+    d = data.shape[-1]
+    r = d if rotary_dim is None else int(rotary_dim)
+    inv = 1.0 / (theta ** (jnp.arange(0, r, 2, dtype=jnp.float32) / r))
+    ang = positions.astype(jnp.float32)[:, None] * inv[None, :]  # (T, r/2)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x = data[..., :r].astype(jnp.float32)
+    x1, x2 = x[..., :r // 2], x[..., r // 2:]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                          axis=-1).astype(data.dtype)
+    return out if r == d else jnp.concatenate([out, data[..., r:]], axis=-1)
+
+
 @register("GroupNorm")
 def _group_norm(data, gamma, beta, num_groups=1, eps=1e-5, output_mean_var=False):
     n, c = data.shape[:2]

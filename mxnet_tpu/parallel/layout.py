@@ -16,8 +16,13 @@ in_units)`` with ``y = x @ W.T``:
 - embedding and LM-head tables shard their vocab rows over the whole
   non-data parameter surface ``(fsdp, tp)`` — the biggest tables get
   the most shards.
-- everything else (norm scales, small biases) stays replicated; the
-  column-parallel biases follow their weight's output split (``tp``).
+- the stacked matrices of an expert layer, (experts, in, out), split
+  their leading axis over ``ep`` (parameters only: on a mesh with an
+  ``ep`` axis the expert layer's exchange is still to come; on one
+  without, the axis drops and they replicate).
+- everything else (norm scales, small biases, a router) stays
+  replicated; the column-parallel biases follow their weight's output
+  split (``tp``).
 
 ShardedTrainer consumes this as ``param_rules`` — an ordered
 ``(regex, PartitionSpec)`` list, first match wins, unmatched params
@@ -39,19 +44,22 @@ class SpecLayout:
     parallelism without parameter sharding, and so on).
     """
 
-    def __init__(self, data_axis="dp", fsdp_axis="fsdp", tp_axis="tp"):
+    def __init__(self, data_axis="dp", fsdp_axis="fsdp", tp_axis="tp",
+                 ep_axis="ep"):
         self.data_axis = data_axis
         self.fsdp_axis = fsdp_axis
         self.tp_axis = tp_axis
+        self.ep_axis = ep_axis
 
     @classmethod
     def for_mesh(cls, mesh, data_axis="dp", fsdp_axis="fsdp",
-                 tp_axis="tp"):
+                 tp_axis="tp", ep_axis="ep"):
         """A SpecLayout with every axis the mesh lacks dropped to None."""
         names = set(mesh.axis_names)
         return cls(data_axis=data_axis if data_axis in names else None,
                    fsdp_axis=fsdp_axis if fsdp_axis in names else None,
-                   tp_axis=tp_axis if tp_axis in names else None)
+                   tp_axis=tp_axis if tp_axis in names else None,
+                   ep_axis=ep_axis if ep_axis in names else None)
 
     # ----------------------------------------------------------- specs
     def _spec(self, *dims):
@@ -93,6 +101,10 @@ class SpecLayout:
         """(vocab, units) — same table shape as the embedding."""
         return self._spec((self.fsdp_axis, self.tp_axis), None)
 
+    def experts(self):
+        """(experts, in, out) stacked expert matrices: experts over ep."""
+        return self._spec(self.ep_axis)
+
     def column_bias(self):
         """Bias of a column-parallel projection follows its out split."""
         return self._spec(self.tp_axis)
@@ -107,14 +119,23 @@ class SpecLayout:
         Written against the model_zoo transformer's stable param
         suffixes (gluon prefixes: ``attn_qkv_``/``attn_out_`` inside
         MultiHeadAttention, ``ff1_``/``ff2_`` for the MLP,
-        ``embed_``/``head_`` for the tables); first match wins and
-        anything unmatched — norms, positional table, small biases —
-        replicates, which is exactly the layout's intent.
+        ``embed_``/``head_`` for the tables) and qwen3_next's
+        (``attn_q_``/``attn_k_``/``attn_v_``, ``linattn_qkvz_``/
+        ``linattn_ba_``/``linattn_out_``, ``moe_experts_*``,
+        ``moe_shared_gate_up_``/``moe_shared_down_``); first match wins
+        and anything unmatched — norms, positional table, small biases,
+        a router, a depthwise convolution — replicates, which is exactly
+        the layout's intent.
         """
         return (
             (r".*attn_qkv_weight$", self.qkv_projection()),
             (r".*attn_qkv_bias$", self.column_bias()),
-            (r".*attn_out_weight$", self.attn_output()),
+            (r".*attn_[qkv]_weight$", self.qkv_projection()),
+            (r".*linattn_(qkvz|ba)_weight$", self.qkv_projection()),
+            (r".*(attn|linattn)_out_weight$", self.attn_output()),
+            (r".*moe_experts_(gate_up|down)_weight$", self.experts()),
+            (r".*moe_shared_gate_up_weight$", self.ffn_up()),
+            (r".*moe_shared_down_weight$", self.ffn_down()),
             (r".*ff1_weight$", self.ffn_up()),
             (r".*ff1_bias$", self.column_bias()),
             (r".*ff2_weight$", self.ffn_down()),
@@ -134,4 +155,5 @@ class SpecLayout:
 
     def __repr__(self):
         return (f"SpecLayout(data={self.data_axis!r}, "
-                f"fsdp={self.fsdp_axis!r}, tp={self.tp_axis!r})")
+                f"fsdp={self.fsdp_axis!r}, tp={self.tp_axis!r}, "
+                f"ep={self.ep_axis!r})")
