@@ -2,12 +2,12 @@
 
 The custom-kernel layer the blueprint reserves for "where fusion matters"
 (SURVEY.md §7): hand-placed VMEM tiling for operations whose fused form
-XLA cannot synthesize. First resident: a streaming flash-attention
-forward — K/V arrive in VMEM one (HEADS, BLOCK_K, D) tile per grid
-step, running (m, l, acc) online-softmax statistics live in VMEM scratch
-that persists across the innermost grid dimension, and the O(T^2) score
-matrix never exists anywhere. Sequence length is bounded by HBM, not
-VMEM.
+XLA cannot synthesize. First resident: streaming flash attention,
+forward and backward. In the forward K/V arrive in VMEM one
+(HEADS, BLOCK_K, D) tile per grid step, running (m, l, acc)
+online-softmax statistics live in VMEM scratch that persists across the
+innermost grid dimension, and the O(T^2) score matrix never exists
+anywhere, in either pass. Sequence length is bounded by HBM, not VMEM.
 
 The forward's tile program (docs/autotune.md, "The flash forward's tile
 program"): the tile is a schedule (candidate axes up to 1024, block_q
@@ -26,9 +26,43 @@ the log-sum-exp leaves the kernel with the sequence along lanes,
 (BH, T / BLOCK_Q, 1, BLOCK_Q), and ``flash_attention(return_lse=True)``
 hands its callers (B, H, T, 1).
 
+The backward's tile program (docs/autotune.md, "The flash backward's
+tile program"): one kernel, ``flash_attention_bwd``, gives dq, dk and dv. Its
+grid is (heads, q windows, K blocks, q blocks), the q blocks innermost:
+dk / dv of a K block gather in float32 VMEM scratch while the q blocks
+pass, dq of a whole window of the sequence gathers in float32 scratch
+while the K blocks pass, so a (q block, K block) pair costs five matrix
+products and one ``exp`` and nothing of size T x block ever passes
+through HBM (q, k, v, dout, ``lse`` and ``D = rowsum(dout * out) -
+dlse`` are read, dq, dk, dv written). Every operand has the SEQUENCE
+ALONG LANES, (BH, D, T), and the tile is computed transposed, s^T =
+K.Q^T (BLOCK_K, BLOCK_Q): ``lse`` and ``D`` arrive as the lane-dense
+rows the forward emits and spread over sublanes, and the three products
+that take the tile (dv^T = dout^T.p, dk^T = q^T.ds, dq^T = k^T.ds^T)
+take it as it lies. That layout pads nothing: (T, D) operands with D
+short of the 128 lanes are padded to them in HBM (twice the bytes at
+D = 64), and XLA holds what the backward kernel reads from the end of
+the forward pass on, so q, k and v wait transposed between the passes
+(``_seq_minor``). Operands as in the forward: the input's dtype into
+every product (p^T and ds^T cast to it), float32 scores, ``exp``, ``D``,
+ds and accumulators. A tile wholly above the causal diagonal does no
+arithmetic and no DMA (clamped index maps by the scalar-prefetched
+offsets, so a ring hop's traced offsets work); a tile that crosses it
+is masked after the ``exp``, to zero. The tile is the ``flash_bwd``
+schedule's (block_q, block_k), or the caller's ``bwd_block_k``,
+legalized to T as the forward's is but
+on the lane grid (a multiple of 128, or all of T; a long sequence off
+that grid runs padded to it with zeros, ``tune.schedule.
+flash_bwd_length``); heads a step and the number of q windows (one
+while dq of the whole sequence fits VMEM: T x D to about four million)
+follow from the tile and the shape (``flash_bwd_heads``,
+``flash_bwd_windows``). Every shape the forward takes, the backward
+takes: there is no other backward.
+
 Every ``pl.pallas_call`` carries a ``name=`` (``flash_attention_fwd``,
-``conv3x3_bn_stats``): it becomes the instruction's name and the last
-scope of its ``op_name`` in the compiled program, which is how a device
+``flash_attention_bwd``, ``conv3x3_bn_stats``): it becomes the
+instruction's name and the last scope of its ``op_name`` in the
+compiled program, which is how a device
 trace and ``observability.perf.op_names`` find the kernel. A kernel added
 here is named the same way (``tests/test_kernel_names_tpu.py`` holds
 every call site in the package to it).
@@ -249,6 +283,33 @@ def _build_flash(bh, t, d, dtype_str, scale, causal, interpret, bq, bk, hb):
     )
 
 
+def _flash_fwd(q, k, v, causal, scale, interpret, q_offset, k_offset,
+               block_q, block_k):
+    """The forward kernel on jax arrays: (out (B, H, T, D), the row
+    log-sum-exp as the kernel lays it, (BH, T / BLOCK_Q, 1, BLOCK_Q)
+    float32: row-major the flat sequence, which is how the backward
+    kernel takes it back)."""
+    import jax.numpy as jnp
+
+    b, h, t, d = q.shape
+    if k.shape != q.shape or v.shape != q.shape:
+        raise ValueError(
+            f"flash_attention: unsupported shape — q {q.shape} vs k "
+            f"{k.shape} / v {v.shape} (self-attention only)")
+    sched = _schedule()
+    bq, bk = sched.flash_fwd_blocks(
+        b * h, t, d, str(q.dtype), interpret=bool(interpret),
+        block_q=block_q, block_k=block_k)
+    hb = sched.flash_fwd_heads(b * h, bq, bk, d, q.dtype.itemsize)
+    fn = _build_flash(b * h, t, d, str(q.dtype), float(scale), bool(causal),
+                      bool(interpret), bq, bk, hb)
+    out, lse = fn(jnp.asarray(q_offset, jnp.int32).reshape(1),
+                  jnp.asarray(k_offset, jnp.int32).reshape(1),
+                  q.reshape(b * h, t, d), k.reshape(b * h, t, d),
+                  v.reshape(b * h, t, d))
+    return out.reshape(b, h, t, d), lse
+
+
 def flash_attention(q, k, v, causal=False, scale=None, interpret=False,
                     return_lse=False, q_offset=0, k_offset=0,
                     block_q=None, block_k=None):
@@ -271,8 +332,6 @@ def flash_attention(q, k, v, causal=False, scale=None, interpret=False,
     CPU device need ``interpret=True`` (a program compiled for a CPU
     device cannot lower the kernel, and says so).
     """
-    import jax.numpy as jnp
-
     if hasattr(q, "_data"):
         from ..ndarray.ndarray import NDArray
 
@@ -285,94 +344,266 @@ def flash_attention(q, k, v, causal=False, scale=None, interpret=False,
         if return_lse:
             return NDArray(out[0], ctx), NDArray(out[1], ctx)
         return NDArray(out, ctx)
-    b, h, t, d = q.shape
-    if k.shape != q.shape or v.shape != q.shape:
-        raise ValueError(
-            f"flash_attention: unsupported shape — q {q.shape} vs k "
-            f"{k.shape} / v {v.shape} (self-attention only)")
-    sched = _schedule()
-    bq, bk = sched.flash_fwd_blocks(
-        b * h, t, d, str(q.dtype), interpret=bool(interpret),
-        block_q=block_q, block_k=block_k)
-    hb = sched.flash_fwd_heads(b * h, bq, bk, d, q.dtype.itemsize)
-    s = scale if scale is not None else 1.0 / _np.sqrt(d)
-    fn = _build_flash(b * h, t, d, str(q.dtype), float(s), bool(causal),
-                      bool(interpret), bq, bk, hb)
-    qf = q.reshape(b * h, t, d)
-    kf = k.reshape(b * h, t, d)
-    vf = v.reshape(b * h, t, d)
-    qo = jnp.asarray(q_offset, jnp.int32).reshape(1)
-    ko = jnp.asarray(k_offset, jnp.int32).reshape(1)
-    out, lse = fn(qo, ko, qf, kf, vf)
-    out = out.reshape(b, h, t, d)
+    s = scale if scale is not None else 1.0 / _np.sqrt(q.shape[-1])
+    out, lse = _flash_fwd(q, k, v, causal, s, interpret, q_offset, k_offset,
+                          block_q, block_k)
     if return_lse:
-        return out, lse.reshape(b, h, t, 1)
+        return out, lse.reshape(q.shape[:3] + (1,))
     return out
 
 
 # ---------------------------------------------------------------------------
-# differentiable wrapper: custom_vjp with blockwise recomputation backward
-# (flash-attention backward, O(T * BLOCK_K) memory — the score matrix is
-# never materialized in either direction)
+# the backward kernel: probabilities recomputed tile by tile from the
+# forward's saved log-sum-exp; the score matrix exists in neither
+# direction, and nothing of size T x block passes through HBM
 # ---------------------------------------------------------------------------
 
-def _flash_bwd_blockwise(q, k, v, out, lse, dout, scale, causal, block_k,
-                         dlse=None, q_offset=0, k_offset=0):
-    """Standard flash-attention backward with recomputed probabilities,
-    scanned over K blocks; `lse` comes from the forward kernel's scratch
-    (no recomputation sweep). `dlse` carries the cotangent of the emitted
-    log-sum-exp (nonzero when the caller merges hop results by lse, as
-    ring attention does): d lse / d s = p folds in as ds += p * dlse.
+def _mha_bwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
+                    lse_ref, dd_ref, dq_ref, dk_ref, dv_ref,
+                    dq_acc, dk_acc, dv_acc, *, scale, causal, n_kb, n_qw):
+    """Grid = (BH / HB, q windows, n_k_blocks, q blocks a window), the q
+    blocks innermost: dk / dv of one K block gather over them in VMEM
+    scratch and leave on the last; dq of the whole window gathers in
+    scratch over the K blocks and leaves on the last of those. One tile
+    pair is five matrix products and one ``exp``.
 
-    ``block_k`` need not divide T: the trailing partial block is padded
-    and masked to probability zero (a schedule-table block must never
-    silently drop the sequence tail), and the padded dk/dv rows are
-    trimmed after the scan."""
+    Every operand has the SEQUENCE ALONG LANES, (D, block), and the tile
+    is computed transposed, s^T = K.Q^T (BK, BQ): ``lse`` and
+    ``D = rowsum(dout * out) - dlse`` arrive as lane-dense rows (1, BQ),
+    the layout the forward emits, and spread over sublanes; the two
+    products that make the tile contract the short (D, BK) blocks of K
+    and V over their rows, and dv^T = dout^T.p, dk^T = q^T.ds and
+    dq^T = k^T.ds^T take the (BK, BQ) tile as it lies.
+
+    q_ref, do_ref (HB, D, BQ) / k_ref, v_ref (HB, D, BK) in the caller's
+    dtype: every product takes its operands so (p^T and ds^T cast to
+    it) and accumulates in float32. lse_ref, dd_ref (HB, 1, 1, BQ)
+    float32. dq_ref / dq_acc (HB, window's q blocks, D, BQ); dk_ref,
+    dv_ref (1, HB, D, BK) of one window's part; dk_acc, dv_acc
+    (HB, D, BK) float32. Offsets as in the forward.
+    """
     import jax
     import jax.numpy as jnp
+    from jax.experimental import pallas as pl
 
-    b, h, t, d = q.shape
-    block_k = max(1, min(int(block_k), t))
-    pad = (-t) % block_k
-    n_kb = (t + pad) // block_k
-    q32, k32, v32 = (x.astype(jnp.float32) for x in (q, k, v))
-    o32, do32 = out.astype(jnp.float32), dout.astype(jnp.float32)
-    if pad:
-        widen = ((0, 0), (0, 0), (0, pad), (0, 0))
-        k32 = jnp.pad(k32, widen)
-        v32 = jnp.pad(v32, widen)
-    D = jnp.sum(do32 * o32, axis=-1, keepdims=True)  # (b,h,t,1)
-    if dlse is not None:
-        D = D - dlse.astype(jnp.float32)
-    qpos = q_offset + jnp.arange(t)
+    w, kb, qi = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    hb, d, bq = q_ref.shape
+    bk = k_ref.shape[2]
 
-    def body(dq, kb):
-        ks = jax.lax.dynamic_slice_in_dim(k32, kb * block_k, block_k, axis=2)
-        vs = jax.lax.dynamic_slice_in_dim(v32, kb * block_k, block_k, axis=2)
-        s = jnp.einsum("bhqd,bhkd->bhqk", q32, ks) * scale
-        kcol = kb * block_k + jnp.arange(block_k)
+    @pl.when(qi == 0)
+    def _init_dkv():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when(kb == 0)
+    def _init_dq():
+        for h in range(hb):
+            dq_acc[h, qi] = jnp.zeros((d, bq), jnp.float32)
+
+    q_first = qoff_ref[0] + (w * n_qw + qi) * bq
+    k_first = koff_ref[0] + kb * bk
+
+    def _tile(masked):
+        if masked:
+            # qpos >= kpos, as col - row >= (first kpos) - (first qpos)
+            rel = jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 1) - \
+                jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
+            keep = rel >= k_first - q_first
+        rows = (((0,), (0,)), ((), ()))     # (D, BK) x (D, BQ) -> (BK, BQ)
+        lanes = (((1,), (1,)), ((), ()))    # (D, BQ) x (BK, BQ) -> (D, BK)
+        # unrolled, as the forward's heads are (PERF.md, PR 28)
+        for h in range(hb):
+            k_blk, v_blk, q_blk, do_blk = k_ref[h], v_ref[h], q_ref[h], \
+                do_ref[h]
+            s_t = jax.lax.dot_general(
+                k_blk, q_blk, rows,
+                preferred_element_type=jnp.float32) * scale
+            p_t = jnp.exp(s_t - lse_ref[h, 0])
+            if masked:
+                # after the exp: a masked entry is 0 whatever the row's
+                # lse is (a row that sees no key at all has lse ~ -1e30)
+                p_t = jnp.where(keep, p_t, 0.0)
+            dp_t = jax.lax.dot_general(
+                v_blk, do_blk, rows, preferred_element_type=jnp.float32)
+            ds_t = (p_t * (dp_t - dd_ref[h, 0])).astype(q_blk.dtype)
+            dv_acc[h] += jax.lax.dot_general(
+                do_blk, p_t.astype(do_blk.dtype), lanes,
+                preferred_element_type=jnp.float32)
+            dk_acc[h] += jax.lax.dot_general(
+                q_blk, ds_t, lanes, preferred_element_type=jnp.float32)
+            dq_acc[h, qi] += jax.lax.dot_general(
+                k_blk, ds_t, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+    if causal:
+        # as in the forward: a tile wholly above the diagonal does no
+        # arithmetic and no DMA (the index maps of _build_flash_bwd name
+        # a resident block again); only one that crosses it is masked
+        live = k_first <= q_first + bq - 1
+        crosses = k_first + bk - 1 > q_first
+        pl.when(live & crosses)(lambda: _tile(True))
+        pl.when(live & jnp.logical_not(crosses))(lambda: _tile(False))
+    else:
+        _tile(False)
+
+    @pl.when(qi == n_qw - 1)
+    def _finish_dkv():
+        for h in range(hb):
+            dk_ref[0, h] = (dk_acc[h] * scale).astype(dk_ref.dtype)
+            dv_ref[0, h] = dv_acc[h].astype(dv_ref.dtype)
+
+    @pl.when(kb == n_kb - 1)
+    def _finish_dq():
+        for h in range(hb):
+            dq_ref[h, qi] = (dq_acc[h, qi] * scale).astype(dq_ref.dtype)
+
+
+@functools.lru_cache(maxsize=32)
+def _build_flash_bwd(bh, t, d, dtype_str, scale, causal, interpret, bq, bk,
+                     hb, n_win):
+    """The backward's pallas_call for one (shape, dtype, config,
+    SCHEDULE), on (BH, D, T) operands. ``n_win`` q windows share the K
+    blocks: each window's dk / dv part comes out on its own (float32
+    when there are several, for the caller to add up); dq comes out a q
+    block at a time, (BH, T / BLOCK_Q, D, BLOCK_Q)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n_kb, n_qb = t // bk, t // bq
+    n_qw = n_qb // n_win
+    dtype = jnp.dtype(dtype_str)
+    part = dtype if n_win == 1 else jnp.dtype(jnp.float32)
+    kernel = functools.partial(_mha_bwd_kernel, scale=scale, causal=causal,
+                               n_kb=n_kb, n_qw=n_qw)
+
+    def q_block(w, kb, qi, qoff_ref, koff_ref):
+        g = w * n_qw + qi
         if causal:
-            kpos = k_offset + kcol
-            s = jnp.where(qpos[:, None] >= kpos[None, :], s, _NEG)
-        if pad:
-            # padded K columns are outside the sequence: mask them to
-            # p = exp(_NEG - lse) = 0 so they contribute to nothing
-            s = jnp.where((kcol < t)[None, :], s, _NEG)
-        p = jnp.exp(s - lse)  # (b,h,t,bk)
-        dp = jnp.einsum("bhqd,bhkd->bhqk", do32, vs)
-        ds = p * (dp - D)
-        dq = dq + jnp.einsum("bhqk,bhkd->bhqd", ds, ks) * scale
-        dk_blk = jnp.einsum("bhqk,bhqd->bhkd", ds, q32) * scale
-        dv_blk = jnp.einsum("bhqk,bhqd->bhkd", p, do32)
-        return dq, (dk_blk, dv_blk)
+            # a q block in the K block's past names the first live one
+            # (or the window's last, where none is): resident when the
+            # sweep reaches it, so a dead step issues no DMA
+            first = (koff_ref[0] + kb * bk - qoff_ref[0]) // bq
+            g = jnp.minimum(jnp.maximum(g, first), (w + 1) * n_qw - 1)
+        return g
 
-    dq0 = jnp.zeros_like(q32)
-    dq, (dk_blks, dv_blks) = jax.lax.scan(body, dq0, jnp.arange(n_kb))
-    # scan stacks over the leading axis: (n_kb, b, h, bk, d) ->
-    # (b, h, t+pad, d), padded tail rows (exactly zero) trimmed off
-    dk = jnp.moveaxis(dk_blks, 0, 2).reshape(b, h, t + pad, d)[:, :, :t]
-    dv = jnp.moveaxis(dv_blks, 0, 2).reshape(b, h, t + pad, d)[:, :, :t]
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    def q_map(b, w, kb, qi, qoff_ref, koff_ref):
+        return (b, 0, q_block(w, kb, qi, qoff_ref, koff_ref))
+
+    def row_map(b, w, kb, qi, qoff_ref, koff_ref):
+        return (b, q_block(w, kb, qi, qoff_ref, koff_ref), 0, 0)
+
+    def kv_map(b, w, kb, qi, qoff_ref, koff_ref):
+        if causal:
+            # K blocks in the whole window's future: the last live one
+            last = (qoff_ref[0] + (w + 1) * n_qw * bq - 1
+                    - koff_ref[0]) // bk
+            kb = jnp.minimum(kb, jnp.clip(last, 0, n_kb - 1))
+        return (b, 0, kb)
+
+    sched = _schedule()
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,  # q_offset, k_offset (SMEM)
+        grid=(bh // hb, n_win, n_kb, n_qw),
+        in_specs=[
+            pl.BlockSpec((hb, d, bq), q_map),           # q^T
+            pl.BlockSpec((hb, d, bk), kv_map),          # k^T
+            pl.BlockSpec((hb, d, bk), kv_map),          # v^T
+            pl.BlockSpec((hb, d, bq), q_map),           # dout^T
+            pl.BlockSpec((hb, 1, 1, bq), row_map),      # lse
+            pl.BlockSpec((hb, 1, 1, bq), row_map),      # D
+        ],
+        out_specs=[
+            pl.BlockSpec((hb, n_qw, d, bq), lambda b, w, *_: (b, w, 0, 0)),
+            pl.BlockSpec((1, hb, d, bk), lambda b, w, kb, *_: (w, b, 0, kb)),
+            pl.BlockSpec((1, hb, d, bk), lambda b, w, kb, *_: (w, b, 0, kb)),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((hb, n_qw, d, bq), jnp.float32),     # dq^T
+            pltpu.VMEM((hb, d, bk), jnp.float32),           # dk^T
+            pltpu.VMEM((hb, d, bk), jnp.float32),           # dv^T
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, n_qb, d, bq), dtype),
+            jax.ShapeDtypeStruct((n_win, bh, d, t), part),
+            jax.ShapeDtypeStruct((n_win, bh, d, t), part),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=sched.flash_bwd_vmem_limit(
+                hb, bq, bk, n_qw * bq, d, dtype.itemsize)),
+        interpret=interpret,
+        name="flash_attention_bwd",
+    )
+
+
+def _seq_minor(x):
+    """(B, H, T, D) -> (B, H, D, T): the layout the backward kernel takes
+    and the one q, k and v wait in between the passes. With the sequence
+    along lanes nothing is padded; (T, D) operands with D short of the
+    128 lanes are, to twice the bytes at D = 64, and XLA would hold the
+    backward's padded copies from the end of the forward on (50 MB a
+    layer at (8, 16, 1024, 64))."""
+    import jax.numpy as jnp
+
+    return jnp.swapaxes(x, -1, -2)
+
+
+def _flash_bwd(qt, kt, vt, out, lse, dout, dlse, scale, causal, interpret,
+               q_offset, k_offset, block_k=None, block_q=None):
+    """dq, dk, dv of one flash-attention call through the backward
+    kernel. ``qt``, ``kt``, ``vt`` are (B, H, D, T) (:func:`_seq_minor`);
+    ``lse`` is the forward's, in any shape that is row-major the
+    (B, H, T) sequence; ``dlse`` (or None) is its cotangent, non-zero
+    when the caller merges results by log-sum-exp as ring attention
+    does: d lse / d s = p, so it only moves D. ``block_k`` / ``block_q``
+    override the schedule's tile (``bwd_block_k=``; the search driver
+    gives both) and are legalized as the schedule's are."""
+    import jax.numpy as jnp
+
+    b, h, t, d = out.shape
+    bh = b * h
+    sched = _schedule()
+    # a long sequence off the lane grid runs padded with zeros
+    tp = sched.flash_bwd_length(t)
+    bq, bk = sched.flash_bwd_block(bh, tp, d, str(out.dtype),
+                                   interpret=bool(interpret),
+                                   block_k=block_k, block_q=block_q)
+    itemsize = out.dtype.itemsize
+    n_win = sched.flash_bwd_windows(tp, bq, bk, d, itemsize)
+    hb = sched.flash_bwd_heads(bh, bq, bk, tp // n_win, d, itemsize)
+    fn = _build_flash_bwd(bh, tp, d, str(out.dtype), float(scale),
+                          bool(causal), bool(interpret), bq, bk, hb, n_win)
+    dd = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    if dlse is not None:
+        dd = dd - dlse.astype(jnp.float32).reshape(dd.shape)
+
+    def lanes(x, *lead):
+        x = x.reshape(bh, *lead, t)
+        if tp != t:
+            x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, tp - t)])
+        return x
+
+    rows = (bh, tp // bq, 1, bq)
+    dq, dk, dv = fn(jnp.asarray(q_offset, jnp.int32).reshape(1),
+                    jnp.asarray(k_offset, jnp.int32).reshape(1),
+                    lanes(qt, d), lanes(kt, d), lanes(vt, d),
+                    lanes(_seq_minor(dout), d),
+                    lanes(lse).reshape(rows), lanes(dd).reshape(rows))
+    if n_win == 1:
+        dk, dv = dk[0], dv[0]
+    else:
+        dk, dv = dk.sum(0).astype(out.dtype), dv.sum(0).astype(out.dtype)
+    dq = jnp.swapaxes(dq, -1, -2).reshape(bh, tp, d)[:, :t]
+    return (dq.reshape(out.shape),
+            _seq_minor(dk[..., :t]).reshape(out.shape),
+            _seq_minor(dv[..., :t]).reshape(out.shape))
 
 
 def flash_attention_with_lse(q, k, v, causal=False, scale=None,
@@ -380,41 +611,43 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None,
                              block_q=None, block_k=None, bwd_block_k=None):
     """Differentiable (out, lse) pair — the ring-attention building block:
     per-hop results merge by log-sum-exp, so the lse output needs a
-    gradient path too (folded into the blockwise backward as ds += p*dlse).
-    Offsets may be traced scalars (lax.axis_index inside shard_map);
-    custom_vjp cannot close over tracers, so they ride along as float
-    primals with zero cotangents. Block sizes resolve through the
-    schedule registry (docs/autotune.md); bwd_block_k overrides the
-    backward's K-scan width."""
-    import functools as _ft
-
+    gradient path too (its cotangent enters the backward kernel through
+    D = rowsum(dout * out) - dlse). Offsets may be traced scalars
+    (lax.axis_index inside shard_map); custom_vjp cannot close over
+    tracers, so they ride along as float primals with zero cotangents.
+    Both passes are Pallas kernels (``flash_attention_fwd``,
+    ``flash_attention_bwd``) whose tiles resolve through the schedule
+    registry (docs/autotune.md): block_q / block_k override the
+    forward's, bwd_block_k the width of the backward's K block
+    (legalized: any width the scan before it took still works)."""
     import jax
     import jax.numpy as jnp
 
-    b, h, t, d = q.shape
-    s = scale if scale is not None else 1.0 / _np.sqrt(d)
-    bk = _schedule().flash_bwd_block(b * h, t, d, str(q.dtype),
-                                     interpret=bool(interpret),
-                                     block_k=bwd_block_k)
+    s = scale if scale is not None else 1.0 / _np.sqrt(q.shape[-1])
 
-    @_ft.partial(jax.custom_vjp)
+    def fwd(q, k, v, qo, ko):
+        return _flash_fwd(q, k, v, causal, s, interpret,
+                          qo.astype(jnp.int32), ko.astype(jnp.int32),
+                          block_q, block_k)
+
+    @jax.custom_vjp
     def f(q, k, v, qo, ko):
-        return flash_attention(q, k, v, causal=causal, scale=s,
-                               interpret=interpret, return_lse=True,
-                               q_offset=qo.astype(jnp.int32),
-                               k_offset=ko.astype(jnp.int32),
-                               block_q=block_q, block_k=block_k)
+        out, lse = fwd(q, k, v, qo, ko)
+        return out, lse.reshape(q.shape[:3] + (1,))
 
     def f_fwd(q, k, v, qo, ko):
-        out, lse = f(q, k, v, qo, ko)
-        return (out, lse), (q, k, v, out, lse, qo, ko)
+        out, lse = fwd(q, k, v, qo, ko)
+        return ((out, lse.reshape(q.shape[:3] + (1,))),
+                (_seq_minor(q), _seq_minor(k), _seq_minor(v), out, lse,
+                 qo, ko))
 
     def f_bwd(res, cot):
         q, k, v, out, lse, qo, ko = res
         dout, dlse = cot
-        dq, dk, dv = _flash_bwd_blockwise(
-            q, k, v, out, lse, dout, s, causal, bk, dlse=dlse,
-            q_offset=qo.astype(jnp.int32), k_offset=ko.astype(jnp.int32))
+        dq, dk, dv = _flash_bwd(
+            q, k, v, out, lse, dout, dlse, s, causal, interpret,
+            qo.astype(jnp.int32), ko.astype(jnp.int32),
+            block_k=bwd_block_k)
         return dq, dk, dv, jnp.zeros_like(qo), jnp.zeros_like(ko)
 
     f.defvjp(f_fwd, f_bwd)
@@ -425,14 +658,14 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None,
 def flash_attention_with_grad(q, k, v, causal=False, scale=None,
                               interpret=False, block_q=None, block_k=None,
                               bwd_block_k=None):
-    """Differentiable flash attention: the Pallas kernel forward paired
-    with a blockwise backward via jax.custom_vjp (probabilities
-    recomputed from the forward's saved log-sum-exp — no extra Q.K^T
-    sweep). Same shape/placement/schedule rules as flash_attention,
-    NDArrays included; bwd_block_k overrides the backward's K-scan
-    width (any width — the backward pads non-dividing tails)."""
-    import functools as _ft
-
+    """Differentiable flash attention: the forward kernel paired by
+    jax.custom_vjp with the backward kernel (``flash_attention_bwd``:
+    probabilities recomputed tile by tile from the forward's saved
+    log-sum-exp, dq / dk / dv gathered in VMEM; operands in the input's
+    dtype, float32 accumulation, dead causal tiles skipped). Same
+    shape / placement / schedule rules as flash_attention, NDArrays
+    included; bwd_block_k overrides the width of the backward's K
+    block (any width: it is legalized onto the kernel's lane grid)."""
     import jax
 
     if hasattr(q, "_data"):
@@ -445,27 +678,24 @@ def flash_attention_with_grad(q, k, v, causal=False, scale=None,
             block_q=block_q, block_k=block_k,
             bwd_block_k=bwd_block_k), ctx)
 
-    b, h, t, d = q.shape
-    s = scale if scale is not None else 1.0 / _np.sqrt(d)
-    bk = _schedule().flash_bwd_block(b * h, t, d, str(q.dtype),
-                                     interpret=bool(interpret),
-                                     block_k=bwd_block_k)
+    s = scale if scale is not None else 1.0 / _np.sqrt(q.shape[-1])
 
-    @_ft.partial(jax.custom_vjp)
+    def fwd(q, k, v):
+        return _flash_fwd(q, k, v, causal, s, interpret, 0, 0,
+                          block_q, block_k)
+
+    @jax.custom_vjp
     def f(q, k, v):
-        return flash_attention(q, k, v, causal=causal, scale=s,
-                               interpret=interpret,
-                               block_q=block_q, block_k=block_k)
+        return fwd(q, k, v)[0]
 
     def f_fwd(q, k, v):
-        out, lse = flash_attention(q, k, v, causal=causal, scale=s,
-                                   interpret=interpret, return_lse=True,
-                                   block_q=block_q, block_k=block_k)
-        return out, (q, k, v, out, lse)
+        out, lse = fwd(q, k, v)
+        return out, (_seq_minor(q), _seq_minor(k), _seq_minor(v), out, lse)
 
     def f_bwd(res, dout):
         q, k, v, out, lse = res
-        return _flash_bwd_blockwise(q, k, v, out, lse, dout, s, causal, bk)
+        return _flash_bwd(q, k, v, out, lse, dout, None, s, causal,
+                          interpret, 0, 0, block_k=bwd_block_k)
 
     f.defvjp(f_fwd, f_bwd)
     return f(q, k, v)

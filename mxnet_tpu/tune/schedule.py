@@ -3,9 +3,9 @@
 THE one home for Pallas block constants and kernel schedule choices
 (graftlint TS004 flags hardcoded block sizes anywhere else): the flash-
 attention forward/backward block sizes, the ring-attention per-hop
-blocks (the hop kernel IS the flash forward, keyed at the hop's local
-shape), and the INT8 conv/FC/requantize arrangement choices all resolve
-here at trace time, in this order:
+blocks (the hop kernels ARE the flash forward and backward, keyed at
+the hop's local shape), and the INT8 conv/FC/requantize arrangement
+choices all resolve here at trace time, in this order:
 
 1. an explicit override from the caller (how the search driver times a
    candidate without touching the table),
@@ -78,8 +78,9 @@ SEARCH_SPACE = {
     # (bh, t, d) shape (parallel/ring_attention.py)
     "flash_fwd": {"block_q": FLASH_BLOCK_CANDIDATES,
                   "block_k": FLASH_BLOCK_CANDIDATES},
-    # blockwise-recomputation backward (K-block scan width)
-    "flash_bwd": {"block_k": FLASH_BLOCK_CANDIDATES},
+    # Pallas flash-attention backward (one kernel for dq, dk and dv)
+    "flash_bwd": {"block_q": FLASH_BLOCK_CANDIDATES,
+                  "block_k": FLASH_BLOCK_CANDIDATES},
     # INT8 GEMM / conv operand arrangement: feed the MXU int8 operands
     # directly, or widen to int32 first (exact same integer results;
     # which one the backend runs faster is a measured fact)
@@ -97,12 +98,12 @@ SEARCH_SPACE = {
 }
 
 # What a kernel runs when the table has no entry (block sizes are
-# legalized down to the shape). The flash forward's is chip-measured
-# (PERF.md, PR 28); the others are the hand-written pre-autotune
-# constants.
+# legalized down to the shape). The flash forward's and backward's are
+# chip-measured (PERF.md, PRs 28 and 31); the others are the
+# hand-written pre-autotune constants.
 DEFAULT_SCHEDULES = {
     "flash_fwd": {"block_q": 512, "block_k": 512},
-    "flash_bwd": {"block_k": 128},
+    "flash_bwd": {"block_q": 512, "block_k": 512},
     "int8_fc": {"operand_width": "int8"},
     "int8_conv": {"operand_width": "int8"},
     "int8_requant": {"path": "via_fp32"},
@@ -119,38 +120,41 @@ class ScheduleError(ValueError):
 
 # ----------------------------------------------------------- legalization
 
-def legalize_block(t, want):
+def legalize_block(t, want, grain=MIN_SUBLANE):
     """The largest legal block ``<= want`` for sequence length ``t``:
     either ``t`` itself (a single block covering the whole sequence:
     legal on the sublane grid, and off it up to one lane tile, the
-    envelope the kernels have always had), or a multiple of
-    :data:`MIN_SUBLANE` that divides ``t``. Returns None when no legal
-    block exists — callers raise :class:`ScheduleError`
-    (``impl="auto"`` asks :func:`flash_shape_supported` first)."""
+    envelope the kernels have always had), or a multiple of ``grain``
+    that divides ``t``: :data:`MIN_SUBLANE` where the sequence lies
+    along sublanes (the forward), :data:`LANES` where it lies along
+    lanes (the backward). Returns None when no legal block exists —
+    callers raise :class:`ScheduleError` (``impl="auto"`` asks
+    :func:`flash_shape_supported` first)."""
     t = int(t)
     want = int(want)
     if t <= 0 or want <= 0:
         return None
     if want >= t and (t % MIN_SUBLANE == 0 or t <= LANES):
         return t
-    b = (min(want, t) // MIN_SUBLANE) * MIN_SUBLANE
-    while b >= MIN_SUBLANE:
+    b = (min(want, t) // grain) * grain
+    while b >= grain:
         if t % b == 0:
             return b
-        b -= MIN_SUBLANE
+        b -= grain
     return None
 
 
-def legal_flash_blocks(t, cap=None):
+def legal_flash_blocks(t, cap=None, grain=MIN_SUBLANE):
     """The legal subset of :data:`FLASH_BLOCK_CANDIDATES` for length
     ``t`` (plus the single-block ``t`` itself), largest first — the
-    candidate axis the search driver sweeps."""
+    candidate axis the search driver sweeps: multiples of ``grain``
+    (:data:`LANES` for the backward, whose sequence lies along lanes)."""
     t = int(t)
     out = []
     for b in FLASH_BLOCK_CANDIDATES:
         if cap is not None and b > cap:
             continue
-        if b == t or (b < t and t % b == 0):
+        if b == t or (b < t and t % b == 0 and b % grain == 0):
             out.append(b)
     if t not in out and (cap is None or t <= cap):
         out.insert(0, t)
@@ -344,31 +348,40 @@ def flash_fwd_vmem_bytes(hb, bq, bk, d, itemsize):
     return blocks + stats + lse + scores
 
 
-def flash_fwd_vmem_limit(hb, bq, bk, d, itemsize):
-    """The kernel's ``vmem_limit_bytes``: None (the compiler's own
-    scoped default) while the step fits it, else the estimate with half
-    again for what the compiler adds, up to
+def _vmem_limit(need):
+    """A flash kernel's ``vmem_limit_bytes`` from its step's estimate:
+    None (the compiler's own scoped default) while the step fits it,
+    else the estimate with half again for what the compiler adds, up to
     :data:`FLASH_VMEM_CEILING`."""
-    need = flash_fwd_vmem_bytes(hb, bq, bk, d, itemsize)
     if need <= FLASH_VMEM_BUDGET:
         return None
     return min(need + need // 2, FLASH_VMEM_CEILING)
 
 
-def flash_fwd_heads(bh, bq, bk, d, itemsize):
+def _heads(bh, bq, bk, vmem_bytes):
     """Heads (rows of the flattened batch*head axis) one grid step takes:
     as many as bring the step's score tiles up to
-    :data:`FLASH_STEP_SCORES` and still fit the VMEM budget, from
-    :data:`FLASH_HEAD_CANDIDATES`, dividing ``bh``. A function of the
-    tile, not a schedule axis of its own: small tiles (short sequences,
-    many heads) get the grid step's fixed cost spread over several
-    heads, large ones run one head a step."""
+    :data:`FLASH_STEP_SCORES` and still fit the VMEM budget
+    (``vmem_bytes(hb)``), from :data:`FLASH_HEAD_CANDIDATES`, dividing
+    ``bh``. A function of the tile, not a schedule axis of its own: small
+    tiles (short sequences, many heads) get the grid step's fixed cost
+    spread over several heads, large ones run one head a step."""
     for hb in FLASH_HEAD_CANDIDATES:
         if int(bh) % hb == 0 and hb * bq * bk <= FLASH_STEP_SCORES and \
-                flash_fwd_vmem_bytes(hb, bq, bk, d, itemsize) \
-                <= FLASH_VMEM_BUDGET:
+                vmem_bytes(hb) <= FLASH_VMEM_BUDGET:
             return hb
     return 1
+
+
+def flash_fwd_vmem_limit(hb, bq, bk, d, itemsize):
+    """The forward's ``vmem_limit_bytes`` (:func:`_vmem_limit`)."""
+    return _vmem_limit(flash_fwd_vmem_bytes(hb, bq, bk, d, itemsize))
+
+
+def flash_fwd_heads(bh, bq, bk, d, itemsize):
+    """Heads one grid step of the forward takes (:func:`_heads`)."""
+    return _heads(bh, bq, bk, lambda hb: flash_fwd_vmem_bytes(
+        hb, bq, bk, d, itemsize))
 
 
 def flash_fwd_blocks(bh, t, d, dtype, interpret=False, block_q=None,
@@ -416,16 +429,89 @@ def flash_fwd_blocks(bh, t, d, dtype, interpret=False, block_q=None,
     return bq, bk
 
 
-def flash_bwd_block(bh, t, d, dtype, interpret=False, block_k=None):
-    """Resolved backward K-block width. Unlike the forward, any width in
-    [1, T] is legal — the blockwise backward pads the trailing partial
-    block and masks it (ops/pallas_kernels._flash_bwd_blockwise)."""
+def flash_bwd_length(t):
+    """The sequence length the flash backward runs at. Its operands have
+    the sequence along lanes, so a tile is a multiple of :data:`LANES`
+    or the whole sequence: ``t`` itself where a lane tile divides it or
+    it is short enough for one tile (the default's 512), else ``t``
+    rounded up to the lane tile, which the caller pads to with zeros (a
+    padded key or query gives nothing to any gradient that is kept)."""
     t = int(t)
-    if block_k is None:
+    if t % LANES == 0 or t <= DEFAULT_SCHEDULES["flash_bwd"]["block_q"]:
+        return t
+    return _pad(t, LANES)
+
+
+def flash_bwd_block(bh, t, d, dtype, interpret=False, block_k=None,
+                    block_q=None):
+    """The flash backward's tile, (block_q, block_k), at a length
+    :func:`flash_bwd_length` gives, so every shape the forward runs on
+    has one. Each axis is the caller's override (``bwd_block_k=``, the
+    search driver's candidates), else the table's, else the default's,
+    and every one of them is legalized onto the lane grid: the largest
+    multiple of :data:`LANES` that divides ``t`` and is no wider than
+    asked (a width under one lane tile runs at one), or ``t`` itself.
+    The scan this kernel replaced took any K width and padded the tail;
+    a width given for it still works. Heads a grid step and q windows
+    follow from the tile (:func:`flash_bwd_heads`,
+    :func:`flash_bwd_windows`)."""
+    t = int(t)
+    want = {"block_q": block_q, "block_k": block_k}
+    if block_q is None or block_k is None:
         sched = kernel_schedule("flash_bwd", flash_shape_key(bh, t, d),
                                 str(dtype), resolve_backend(interpret))
-        block_k = sched["block_k"]
-    return max(1, min(int(block_k), t))
+        want = {axis: sched[axis] if b is None else b
+                for axis, b in want.items()}
+    return tuple(
+        legalize_block(t, max(int(want[axis]), LANES), LANES) or t
+        for axis in ("block_q", "block_k"))
+
+
+def flash_bwd_vmem_bytes(hb, bq, bk, rows, d, itemsize):
+    """What one grid step of the flash backward holds in VMEM, from its
+    shapes (the sequence along lanes, D along sublanes): the q/dout and
+    k/v blocks and the dk/dv blocks going out (double-buffered), the lse
+    and D rows, the float32 dk/dv accumulators, dq of a whole window of
+    ``rows`` q rows (float32 accumulator and the double-buffered block
+    going out), and the (bk, bq) float32 score tile with the copies the
+    tile program makes of it (probabilities, dp, ds, and the two in the
+    operand dtype)."""
+    ds = _pad(d, 2 * MIN_SUBLANE)
+    bq, bk, rows = _pad(bq, LANES), _pad(bk, LANES), _pad(rows, LANES)
+    blocks = 2 * hb * (2 * bq + 4 * bk) * ds * itemsize
+    rows_in = 2 * 2 * hb * MIN_SUBLANE * bq * 4
+    dkv = 2 * hb * bk * ds * 4
+    dq = hb * rows * ds * (4 + 2 * itemsize)
+    scores = 6 * bk * bq * 4
+    return blocks + rows_in + dkv + dq + scores
+
+
+def flash_bwd_vmem_limit(hb, bq, bk, rows, d, itemsize):
+    """The backward's ``vmem_limit_bytes`` (:func:`_vmem_limit`)."""
+    return _vmem_limit(flash_bwd_vmem_bytes(hb, bq, bk, rows, d, itemsize))
+
+
+def flash_bwd_windows(t, bq, bk, d, itemsize):
+    """Into how many q windows the backward cuts the sequence: the
+    kernel holds dq of a whole window in VMEM while the K blocks pass,
+    so the fewest windows (a divisor of T / bq) whose step, with half
+    again for the compiler, stays under :data:`FLASH_VMEM_CEILING`. One
+    up to T x D of about four million (8192 x 256, 32 768 x 128 in
+    bf16); each further window reads K and V once more."""
+    n_qb = int(t) // int(bq)
+    for n_win in range(1, n_qb + 1):
+        if n_qb % n_win == 0 and 3 * flash_bwd_vmem_bytes(
+                1, bq, bk, int(t) // n_win, d, itemsize) \
+                <= 2 * FLASH_VMEM_CEILING:
+            return n_win
+    return n_qb
+
+
+def flash_bwd_heads(bh, bq, bk, rows, d, itemsize):
+    """Heads one grid step of the backward takes (:func:`_heads`), with
+    a window of ``rows`` q rows each."""
+    return _heads(bh, bq, bk, lambda hb: flash_bwd_vmem_bytes(
+        hb, bq, bk, rows, d, itemsize))
 
 
 def decode_attn_block_pages(batch, pages, dtype, interpret=False,

@@ -149,12 +149,17 @@ def _flash_qkv(b, h, t, d, seed, dtype="float32"):
             for _ in range(3)]
 
 
-def _flash_block_pairs(t, quick=False, min_block=None):
-    legal = schedule.legal_flash_blocks(t)
+def _flash_block_pairs(t, quick=False, min_block=None,
+                       grain=schedule.MIN_SUBLANE):
+    # a production sweep (min_block) also leaves out the one block of a
+    # long T: a tile wider than the widest candidate only fails to build
+    cap = None if min_block is None else schedule.FLASH_BLOCK_CANDIDATES[0]
+    legal = schedule.legal_flash_blocks(t, cap=cap, grain=grain)
     if min_block is not None:
         legal = [b for b in legal if b >= min_block] or legal[:1]
     if quick:
-        legal = [b for b in legal if b in (128, 64)] or legal[:2]
+        small = [b for b in legal if b in (128, 64)]
+        legal = small if len(small) > 1 else legal[:2]
     return [{"block_q": bq, "block_k": bk} for bq in legal for bk in legal]
 
 
@@ -194,33 +199,38 @@ def flash_fwd_workload(b=2, h=1, t=256, d=32, causal=True, interpret=False,
 
 
 def flash_bwd_workload(b=2, h=1, t=256, d=32, causal=True, interpret=False,
-                       seed=11, quick=False, label=None):
-    q, k, v = _flash_qkv(b, h, t, d, seed)
-    legal = schedule.legal_flash_blocks(t)
-    if quick:
-        legal = [bk for bk in legal if bk in (128, 64, 32)] or legal[:3]
+                       seed=11, quick=False, label=None, dtype="float32",
+                       min_block=None):
+    """Flash-attention backward sweep at one shape and dtype: the
+    backward kernel alone under the candidate tile, on what the forward
+    kernel (under its own schedule) leaves for it and the cotangent of a
+    sum of squares; keyed and cut down as :func:`flash_fwd_workload`
+    is."""
+    q, k, v = _flash_qkv(b, h, t, d, seed, dtype)
 
     def build(sched):
         import jax
-        import jax.numpy as jnp
 
-        from ..ops.pallas_kernels import flash_attention_with_grad
+        from ..ops import pallas_kernels as pk
 
-        def loss(q, k, v):
-            out = flash_attention_with_grad(
-                q, k, v, causal=causal, interpret=interpret,
-                bwd_block_k=sched["block_k"])
-            return jnp.sum(out.astype(jnp.float32) ** 2)
+        scale = d ** -0.5
+        out, lse = pk._flash_fwd(q, k, v, causal, scale, interpret, 0, 0,
+                                 None, None)
+        fn = jax.jit(lambda *args: pk._flash_bwd(
+            *args, None, scale, causal, interpret, 0, 0,
+            block_k=sched["block_k"], block_q=sched["block_q"]))
+        return fn, (pk._seq_minor(q), pk._seq_minor(k), pk._seq_minor(v),
+                    out, lse, 2 * out)
 
-        fn = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
-        return fn, (q, k, v)
-
-    default_bk = schedule.DEFAULT_SCHEDULES["flash_bwd"]["block_k"]
+    default = schedule.DEFAULT_SCHEDULES["flash_bwd"]
+    ref = {axis: schedule.legalize_block(t, default[axis], schedule.LANES)
+           or t for axis in ("block_q", "block_k")}
     return Workload(
-        "flash_bwd", schedule.flash_shape_key(b * h, t, d), "float32",
+        "flash_bwd", schedule.flash_shape_key(b * h, t, d), str(dtype),
         schedule.resolve_backend(interpret), build,
-        [{"block_k": bk} for bk in legal], label=label or "flash_bwd",
-        reference={"block_k": min(default_bk, t)})
+        _flash_block_pairs(t, quick=quick, min_block=min_block,
+                           grain=schedule.LANES),
+        label=label or "flash_bwd", reference=ref)
 
 
 def decode_attn_workload(b=4, pages=8, page_size=16, h=2, d=32, seed=9,

@@ -86,7 +86,8 @@ def test_widened_flash_axes_keep_the_short_sequences():
     assert schedule.legal_flash_blocks(512)[:2] == [512, 256]
     assert schedule.legal_flash_blocks(256) == [256, 128, 64, 32, 16, 8]
     assert set(schedule.SEARCH_SPACE["flash_fwd"]) == {"block_q", "block_k"}
-    assert schedule.SEARCH_SPACE["flash_bwd"]["block_k"][0] == 1024
+    assert schedule.SEARCH_SPACE["flash_bwd"] == \
+        schedule.SEARCH_SPACE["flash_fwd"]
     wide = {"schema_version": 1, "entries": {
         "flash_fwd|tpu|bfloat16|bh128-t1024-d64": {
             "schedule": {"block_q": 1024, "block_k": 512}}}}
@@ -130,32 +131,120 @@ def test_flash_vmem_limit_rises_with_the_tile():
     assert schedule.flash_fwd_heads(16, 1024, 1024, 256, 4) == 1
 
 
-def test_committed_tpu_entry_resolves_for_the_gpt2_cell(monkeypatch):
-    """tools/schedule_table.json carries the chip-measured entry of the
-    GPT-2 medium training cell's attention (ROADMAP A5): on a TPU
-    backend the builder's lookup hits it."""
+# (BH, T, D, dtype, itemsize, q windows): the two training cells'
+# attention, short and off-tile sequences, the widest head in float32,
+# and sequences whose dq no longer fits VMEM in one window
+@pytest.mark.parametrize("bh,t,d,dtype,itemsize,windows", [
+    (8, 65, 64, "float32", 4, 1), (8, 200, 32, "bfloat16", 2, 1),
+    (128, 1024, 64, "bfloat16", 2, 1), (16, 8192, 256, "bfloat16", 2, 1),
+    (16, 1024, 256, "float32", 4, 1), (16, 16384, 256, "bfloat16", 2, 2),
+    (16, 65536, 128, "bfloat16", 2, 4), (16, 65536, 128, "float32", 4, 8)])
+def test_default_flash_bwd_schedule_is_legal_by_shape(
+        bh, t, d, dtype, itemsize, windows, monkeypatch):
+    """The backward's tile, heads a step and q windows follow from what
+    the builder sees, (T, D, dtype), legalized as the forward's are but on
+    the lane grid: every shape the forward takes has a backward tile that
+    fits VMEM (a long sequence off the lane grid runs padded to it)."""
+    monkeypatch.setenv("MXNET_TPU_AUTOTUNE", "0")   # no table: the default
+    bq, bk = schedule.flash_bwd_block(bh, t, d, dtype, interpret=True)
+    assert (bq, bk) == schedule.flash_fwd_blocks(bh, t, d, dtype,
+                                                 interpret=True)
+    n_win = schedule.flash_bwd_windows(t, bq, bk, d, itemsize)
+    assert n_win == windows and (t // bq) % n_win == 0
+    rows = t // n_win
+    hb = schedule.flash_bwd_heads(bh, bq, bk, rows, d, itemsize)
+    assert bh % hb == 0 and (hb * bq * bk <= schedule.FLASH_STEP_SCORES
+                             or hb == 1)
+    need = schedule.flash_bwd_vmem_bytes(hb, bq, bk, rows, d, itemsize)
+    limit = schedule.flash_bwd_vmem_limit(hb, bq, bk, rows, d, itemsize)
+    if limit is None:
+        assert need <= schedule.FLASH_VMEM_BUDGET
+    else:
+        assert need < limit <= schedule.FLASH_VMEM_CEILING
+        assert 3 * need <= 2 * schedule.FLASH_VMEM_CEILING
+    assert schedule.flash_bwd_length(t) == t
+    assert all(b == t or b % schedule.LANES == 0 for b in (bq, bk))
+    # overrides are legalized onto the same grid: too wide is T, under
+    # a lane tile is one lane tile (or T, where that is all there is)
+    assert schedule.flash_bwd_block(bh, t, d, dtype, block_k=t + 8,
+                                    block_q=bq) == (bq, t)
+    assert schedule.flash_bwd_block(bh, t, d, dtype, block_k=64,
+                                    block_q=bq) == (
+        bq, schedule.LANES if t % schedule.LANES == 0 else t)
+    assert schedule.flash_bwd_length(2000) == 2048
+    assert schedule.flash_bwd_block(4, 2048, 64, dtype,
+                                    interpret=True) == (512, 512)
+
+
+def test_flash_bwd_workload_and_table_entry_steer_the_backward(
+        tmp_path, monkeypatch):
+    """``flash_bwd_workload`` sweeps (block_q, block_k) of the backward
+    kernel at its dtype and keys the entry by it; the entry it writes is
+    the one the builder's lookup hits."""
+    import jax.numpy as jnp
+
+    wl = search.flash_bwd_workload(b=1, h=2, t=256, d=16, interpret=True,
+                                   quick=True, dtype="bfloat16")
+    assert wl.dtype == "bfloat16" and wl.kernel == "flash_bwd"
+    fn, args = wl.build(wl.reference())
+    # q, k, v (sequence-minor), out and dout in the dtype; lse float32
+    assert [a.dtype for a in args] == [jnp.bfloat16] * 4 + [
+        jnp.float32, jnp.bfloat16]
+    tbl = str(tmp_path / "t.json")
+    res = search.run_search(wl, tbl, rounds=1, iters=1)
+    assert res["key"] == "flash_bwd|interpret|bfloat16|bh2-t256-d16"
+    # tiles on the lane grid: 128 and the whole of T
+    assert res["rejected"] == 0 and res["candidates"] == 4
+    won = schedule.load_single_table(tbl)[res["key"]]["schedule"]
+    assert set(won) == {"block_q", "block_k"}
+    monkeypatch.setenv("MXNET_TPU_SCHEDULE_TABLE", tbl)
+    tune.reset_stats()
+    assert schedule.flash_bwd_block(2, 256, 16, "bfloat16",
+                                    interpret=True) == (
+        won["block_q"], won["block_k"])
+    assert tune.stats()["autotune_table_hits"] == 1
+    # the production sweeps leave the narrow tiles out, and the one
+    # block of a long T
+    wide = search.flash_bwd_workload(b=1, h=1, t=1024, d=16,
+                                     interpret=True, min_block=256)
+    assert len(wide.candidates()) == 9
+    long = search.flash_bwd_workload(b=1, h=1, t=8192, d=16,
+                                     interpret=True, min_block=256)
+    assert len(long.candidates()) == 9
+
+
+@pytest.mark.parametrize("kernel,bh,t,d,candidates", [
+    ("flash_fwd", 8 * 16, 1024, 64, 16), ("flash_bwd", 8 * 16, 1024, 64, 9),
+    ("flash_bwd", 16, 8192, 256, 9)])
+def test_committed_tpu_entries_resolve_for_the_training_cells(
+        kernel, bh, t, d, candidates, monkeypatch):
+    """tools/schedule_table.json carries the chip-measured entries of
+    the training cells' attention (ROADMAP A5): GPT-2 medium's forward
+    and backward, Qwen3-Next's backward. On a TPU backend the builder's
+    lookup hits them."""
     monkeypatch.delenv("MXNET_TPU_SCHEDULE_TABLE", raising=False)
     monkeypatch.setenv("MXNET_TPU_AUTOTUNE", "1")
-    key = schedule.entry_key("flash_fwd",
-                             schedule.flash_shape_key(8 * 16, 1024, 64),
+    key = schedule.entry_key(kernel, schedule.flash_shape_key(bh, t, d),
                              "bfloat16", "tpu")
-    assert key == "flash_fwd|tpu|bfloat16|bh128-t1024-d64"
+    assert key == f"{kernel}|tpu|bfloat16|bh{bh}-t{t}-d{d}"
     entry = schedule.load_single_table(schedule.default_table_path())[key]
     assert schedule.validate_table(
         {"schema_version": 1, "entries": {key: entry}}) == []
     # its paired measurements: the winner against the reference schedule
     assert 0 < entry["measured_ms"] <= entry["ref_ms"]
-    assert entry["candidates"] >= 16 and entry["tuned_at"]
+    assert entry["candidates"] >= candidates and entry["tuned_at"]
     monkeypatch.setattr(schedule, "resolve_backend",
                         lambda interpret=False: "tpu")
     tune.reset_stats()
+    resolve = {"flash_fwd": schedule.flash_fwd_blocks,
+               "flash_bwd": schedule.flash_bwd_block}[kernel]
     sched = entry["schedule"]
-    assert schedule.flash_fwd_blocks(128, 1024, 64, "bfloat16") == (
+    assert resolve(bh, t, d, "bfloat16") == (
         sched["block_q"], sched["block_k"])
     assert tune.stats()["autotune_table_hits"] == 1
     assert tune.stats()["autotune_table_misses"] == 0
     # float32 inputs at the same shape have no entry: the default
-    assert schedule.flash_fwd_blocks(128, 1024, 64, "float32") == (512, 512)
+    assert resolve(bh, t, d, "float32") == (512, 512)
     assert tune.stats()["autotune_table_misses"] == 1
 
 
@@ -215,8 +304,11 @@ def test_flash_identical_across_schedules(causal):
     from mxnet_tpu.ops.pallas_kernels import (flash_attention,
                                               flash_attention_with_grad)
 
-    q, k, v = _qkv(1, 2, 128, 16, seed=3)
-    candidates = [(128, 128), (64, 128), (128, 64), (32, 32), (16, 64)]
+    q, k, v = _qkv(1, 2, 256, 16, seed=3)
+    # the backward's sequence lies along lanes: its tiles are multiples
+    # of 128 (or T), the forward's of 8
+    candidates = [(256, 256), (128, 256), (256, 128), (128, 128),
+                  (64, 32)]
 
     ref_out = None
     ref_g = None
@@ -244,10 +336,11 @@ def test_flash_identical_across_schedules(causal):
 
 
 def test_flash_bwd_nondivisible_block_pads_tail():
-    """Regression (ISSUE 15 satellite): `_flash_bwd_blockwise` used to
-    compute n_kb = t // block_k and silently DROP the tail for
-    non-dividing blocks. Odd T with a forced small block must match
-    dense autodiff exactly like the dividing case."""
+    """Regression (ISSUE 15 satellite): the scan backward once computed
+    n_kb = t // block_k and silently DROP the tail for non-dividing
+    blocks. The kernel that replaced it legalizes ``bwd_block_k``: odd T
+    with a forced small block (one tile of T) must match dense autodiff
+    exactly like the dividing case."""
     import jax
     import jax.numpy as jnp
 
@@ -268,7 +361,7 @@ def test_flash_bwd_nondivisible_block_pads_tail():
         return jnp.sum(jnp.einsum("bhqk,bhkd->bhqd", w, v_) ** 2)
 
     gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
-    for bk in (8, 4, t):  # 33 % 8 = 1, 33 % 4 = 1 — both padded paths
+    for bk in (8, 4, t):  # 33 % 8 = 1, 33 % 4 = 1: neither divides T
         gf = jax.grad(lambda a, b, c: loss_flash(a, b, c, bk=bk),
                       argnums=(0, 1, 2))(q, k, v)
         for a, b, name in zip(gf, gd, "qkv"):

@@ -73,6 +73,56 @@ def test_flash_forward_compiles_at_real_widths(one_chip, shape, dtype,
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _grad_through_flash(blocks):
+    """q, k, v, traced offsets -> (dq, dk, dv) under the ``attention``
+    scope, as ``MultiHeadAttention`` and the ring hop call it."""
+    from mxnet_tpu.ops import pallas_kernels
+
+    def loss(q, k, v, qo, ko):
+        with jax.named_scope("attention"):
+            out, lse = pallas_kernels.flash_attention_with_lse(
+                q, k, v, causal=True, q_offset=qo, k_offset=ko,
+                bwd_block_k=blocks.get("block_k"), **blocks)
+        return jnp.sum(out.astype(jnp.float32)) + jnp.sum(lse)
+
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+# the forward's shapes under the backward's default tile (its sequence lies
+# along lanes: a tile is a multiple of 128 or all of T), explicit tiles of
+# its own, a long sequence off the lane grid (padded to 2048), and the two
+# training cells' attention: GPT-2 medium (first) and Qwen3-Next's gated
+# attention after the K/V repeat
+_FLASH_BWD_SHAPES = [(shape, dtype, {}) for shape, dtype, blocks
+                     in _FLASH_SHAPES if not blocks] + [
+    ((1, 2, 256, 64), jnp.bfloat16, {"block_q": 128, "block_k": 256}),
+    ((1, 2, 2000, 64), jnp.bfloat16, {}),
+    ((1, 16, 8192, 256), jnp.bfloat16, {})]
+
+
+@pytest.mark.parametrize("shape,dtype,blocks", _FLASH_BWD_SHAPES)
+def test_flash_backward_is_named_and_compiles_at_real_widths(
+        one_chip, shape, dtype, blocks):
+    """``jax.grad`` through the custom_vjp: the backward is one kernel,
+    named, under the backward half of the ``attention`` scope (what
+    ``attention_bwd_ms_per_step`` joins on), and Mosaic takes its tile
+    program at these widths with traced hop offsets and a non-zero
+    ``dlse``."""
+    qkv = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    off = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    lowered = jax.jit(_grad_through_flash(blocks)).lower(
+        qkv, qkv, qkv, off, off)
+    text = lowered.as_text(debug_info=True)
+    assert 'kernel_name = "flash_attention_bwd"' in text
+    named = [line for line in text.splitlines()
+             if "flash_attention_bwd/pallas_call" in line]
+    assert named and all("transpose(jvp(attention))" in line
+                         for line in named), named[:2]
+    compiled = lowered.compile().as_text()
+    assert compiled.count("tpu_custom_call") >= 2
+    assert "while" not in compiled      # no scan is left in the backward
+
+
 def test_conv3x3_bn_stats_is_named_in_the_lowered_program(one_chip):
     from mxnet_tpu.ops import pallas_kernels
 

@@ -126,7 +126,7 @@ def test_attention_auto_selects_on_where_q_lives(monkeypatch):
 
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_backward_matches_dense(causal):
-    """custom_vjp blockwise backward vs autodiff through dense attention."""
+    """custom_vjp backward kernel vs autodiff through dense attention."""
     import jax
     import jax.numpy as jnp
 
@@ -279,6 +279,180 @@ def test_bf16_gradients_match_float32_reference(bq, bk, causal):
         np.testing.assert_allclose(np.asarray(a, np.float32) / scale,
                                    np.asarray(b) / scale, atol=2e-2,
                                    err_msg=f"grad {name}")
+
+
+# The backward kernel: (B, H, T, D, causal, tile, q_offset, k_offset).
+# ``tile`` is None (the default schedule), a (block_q, block_k) pair (a
+# table entry, as a tuned shape gets one) or an int (``bwd_block_k=``,
+# legalized). Its sequence lies along lanes, so a tile is a multiple of
+# 128 or all of T. T = 384 in tiles of 128 meets tiles wholly below, on
+# and wholly above the diagonal; T = 640 is a length the default's 512
+# does not divide (tiles of 128); T = 33 / 65 / 200 are off the lane
+# tile (one block of T); T = 520 is off it and too long for one block:
+# padded to 640, tiles of 128; (2, 4, 64, 32) takes eight heads a grid
+# step and (1, 2, 65, 64) two; the overrides are widths the scan before
+# the kernel took (one that divides T, one under the lane tile, one that
+# divides nothing); the last four are ring hops (K/V in the past, on
+# the diagonal, half a sequence ahead so that rows 0..127 see nothing,
+# wholly in the future)
+_BWD_CASES = [
+    (1, 2, 384, 32, True, (128, 128), 0, 0),
+    (1, 2, 256, 64, True, (128, 256), 0, 0),
+    (1, 2, 256, 64, True, (256, 128), 0, 0),
+    (1, 2, 256, 32, False, (128, 128), 0, 0),
+    (1, 2, 256, 32, False, (256, 128), 0, 0),
+    (1, 1, 640, 32, True, None, 0, 0),
+    (1, 1, 33, 32, True, None, 0, 0),
+    (1, 2, 65, 64, True, None, 0, 0),
+    (1, 1, 200, 32, True, None, 0, 0),
+    (1, 1, 520, 32, True, None, 0, 0),
+    (1, 1, 520, 32, False, None, 0, 0),
+    (2, 4, 64, 32, True, None, 0, 0),
+    (1, 1, 256, 128, True, (128, 128), 0, 0),
+    (1, 1, 256, 256, True, (128, 128), 0, 0),
+    (1, 1, 64, 256, False, None, 0, 0),
+    (1, 2, 384, 32, True, 128, 0, 0),
+    (1, 1, 256, 64, True, 64, 0, 0),
+    (1, 1, 33, 32, True, 8, 0, 0),
+    (1, 2, 256, 32, True, (128, 256), 512, 256),
+    (1, 2, 256, 32, True, (256, 128), 256, 256),
+    (1, 2, 256, 32, True, (128, 128), 256, 384),
+    (1, 2, 256, 32, True, (128, 256), 0, 256),
+]
+
+
+def _bwd_tile(t, tile):
+    """The tile the backward runs a case at, by the rules above."""
+    from mxnet_tpu.tune import schedule
+
+    tp = schedule.flash_bwd_length(t)
+    assert tp == (640 if t == 520 else t)
+    if isinstance(tile, tuple):
+        return tile
+    whole = 128 if t in (520, 640) else tp
+    if tile is None:
+        return whole, whole
+    return whole, {128: 128, 64: 128, 8: 33}[tile]
+
+
+def _dense_out_lse(q, k, v, causal, q_offset, k_offset):
+    import jax
+    import jax.numpy as jnp
+
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    if causal:
+        qpos = q_offset + jnp.arange(q.shape[2])
+        kpos = k_offset + jnp.arange(k.shape[2])
+        s = jnp.where(qpos[:, None] >= kpos[None, :], s, -1e30)
+    lse = jax.scipy.special.logsumexp(s, -1, keepdims=True)
+    return jnp.einsum("bhqk,bhkd->bhqd", jnp.exp(s - lse), v), lse
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("b,h,t,d,causal,tile,q_offset,k_offset",
+                         _BWD_CASES)
+def test_backward_kernel_matches_float32_dense_autodiff(
+        b, h, t, d, causal, tile, q_offset, k_offset, dtype, tol,
+        tmp_path, monkeypatch):
+    """dq, dk, dv of ``flash_attention_with_lse`` (the backward kernel,
+    offsets as traced values, a non-zero ``dlse``) against autodiff
+    through the float32 dense composition of the same inputs, each
+    relative to the gradient's largest entry. A row that sees no key
+    gives nothing to any gradient, whatever its cotangents are: the
+    reference gets them zeroed there, the kernel does not."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops.pallas_kernels import flash_attention_with_lse
+    from mxnet_tpu.tune import schedule
+
+    rng = np.random.RandomState(t + d + k_offset)
+    q, k, v = (jnp.asarray(rng.randn(b, h, t, d).astype(np.float32) * 0.3,
+                           dtype) for _ in range(3))
+    w_out = jnp.asarray(rng.randn(b, h, t, d).astype(np.float32))
+    w_lse = jnp.asarray(rng.randn(b, h, t, 1).astype(np.float32))
+    # a per-host table of its own: the case's entry, or none (and not
+    # the committed table's interpret entries either)
+    table = str(tmp_path / "table.json")
+    monkeypatch.setenv("MXNET_TPU_SCHEDULE_TABLE", table)
+    monkeypatch.setattr(schedule, "default_table_path", lambda: table)
+    override = None
+    if isinstance(tile, tuple):
+        schedule.put_entry(table, "flash_bwd",
+                           schedule.flash_shape_key(b * h, t, d), dtype,
+                           "interpret", {"block_q": tile[0],
+                                         "block_k": tile[1]})
+    elif tile is not None:
+        override = tile
+    want_tile = _bwd_tile(t, tile)
+    tp = schedule.flash_bwd_length(t)
+    assert schedule.flash_bwd_block(b * h, tp, d, dtype, interpret=True,
+                                    block_k=override) == want_tile
+    if tile is None and t <= 200:
+        # short sequences: every head in one grid step, unrolled
+        assert schedule.flash_bwd_heads(b * h, t, t, t, d, 4) == b * h
+
+    def loss_flash(q_, k_, v_, qo, ko):
+        out, lse = flash_attention_with_lse(
+            q_, k_, v_, causal=causal, interpret=True, q_offset=qo,
+            k_offset=ko, bwd_block_k=override)
+        return jnp.sum(out.astype(jnp.float32) * w_out) + \
+            jnp.sum(lse * w_lse)
+
+    seen = np.ones(t, bool)
+    if causal:
+        seen = q_offset + np.arange(t) >= k_offset
+    mask = jnp.asarray(seen, jnp.float32)[:, None]
+
+    def loss_dense(q_, k_, v_):
+        out, lse = _dense_out_lse(q_, k_, v_, causal, q_offset, k_offset)
+        return jnp.sum(out * w_out * mask) + jnp.sum(lse * w_lse * mask)
+
+    got = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(
+        q, k, v, jnp.int32(q_offset), jnp.int32(k_offset))
+    want = jax.grad(loss_dense, argnums=(0, 1, 2))(
+        *(x.astype(jnp.float32) for x in (q, k, v)))
+    for a, ref, name in zip(got, want, "qkv"):
+        assert a.dtype == q.dtype and a.shape == q.shape
+        a, ref = np.asarray(a, np.float32), np.asarray(ref)
+        if not seen.any():
+            assert (a == 0).all(), f"grad {name} of a hop in the future"
+            continue
+        scale = np.abs(ref).max()
+        np.testing.assert_allclose(a / scale, ref / scale, atol=tol,
+                                   err_msg=f"grad {name}")
+
+
+def test_backward_q_windows_add_up(monkeypatch):
+    """A sequence whose dq does not fit VMEM in one piece is cut into q
+    windows (a grid axis): each window's dk / dv part comes out in
+    float32 and the parts are added. Forced here by a small ceiling."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops.pallas_kernels import flash_attention_with_grad
+    from mxnet_tpu.tune import schedule
+
+    t, d, bq, bk = 256, 32, 128, 128
+    assert schedule.flash_bwd_windows(t, bq, bk, d, 4) == 1
+    assert schedule.flash_bwd_windows(65536, 512, 512, 128, 2) == 4
+    q, k, v = (jnp.asarray(a) for a in _qkv(B=1, H=2, T=t, D=d, seed=4))
+    monkeypatch.setitem(schedule.DEFAULT_SCHEDULES, "flash_bwd",
+                        {"block_q": bq, "block_k": bk})
+    monkeypatch.setenv("MXNET_TPU_AUTOTUNE", "0")
+
+    def grads():
+        return jax.grad(lambda *a: jnp.sum(flash_attention_with_grad(
+            *a, causal=True, interpret=True) ** 2),
+            argnums=(0, 1, 2))(q, k, v)
+
+    whole = grads()
+    monkeypatch.setattr(
+        schedule, "FLASH_VMEM_CEILING",
+        schedule.flash_bwd_vmem_bytes(1, bq, bk, t // 2, d, 4) * 3 // 2)
+    assert schedule.flash_bwd_windows(t, bq, bk, d, 4) == 2
+    for a, b in zip(grads(), whole):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
 
 
 def test_several_heads_share_a_grid_step():

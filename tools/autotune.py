@@ -66,11 +66,23 @@ def build_workloads(quick, interpret=False):
     # heads, 1024 positions, head 64, bf16 under the training policy) —
     # a production shape, so only outside the demo: how the committed
     # ``flash_fwd|tpu|bfloat16|bh128-t1024-d64`` entry was made
+    # The backward kernel at the same shape, and at the Qwen3-Next
+    # cell's (configs/qwen3-next-80b-a3b.json under train_seq8192_bs1:
+    # one row, 16 query heads after the K/V repeat, 8192 positions, head
+    # 256): the ``flash_bwd|tpu|bfloat16|...`` entries
     production = [] if quick else [
         search.flash_fwd_workload(b=8, h=16, t=1024, d=64, causal=True,
                                   dtype="bfloat16", min_block=128,
                                   interpret=interpret,
-                                  label="gpt2m_train_fwd")]
+                                  label="gpt2m_train_fwd"),
+        search.flash_bwd_workload(b=8, h=16, t=1024, d=64, causal=True,
+                                  dtype="bfloat16", min_block=256,
+                                  interpret=interpret,
+                                  label="gpt2m_train_bwd"),
+        search.flash_bwd_workload(b=1, h=16, t=8192, d=256, causal=True,
+                                  dtype="bfloat16", min_block=256,
+                                  interpret=interpret,
+                                  label="qwen3next_train_bwd")]
     return production + [
         search.flash_fwd_workload(b=2, h=1, t=256, d=32, causal=True,
                                   **kw, label="flash_fwd"),
