@@ -60,7 +60,7 @@ follow from the tile and the shape (``flash_bwd_heads``,
 takes: there is no other backward.
 
 Every ``pl.pallas_call`` carries a ``name=`` (``flash_attention_fwd``,
-``flash_attention_bwd``, ``conv3x3_bn_stats``): it becomes the
+``flash_attention_bwd``): it becomes the
 instruction's name and the last scope of its ``op_name`` in the
 compiled program, which is how a device
 trace and ``observability.perf.op_names`` find the kernel. A kernel added
@@ -699,156 +699,3 @@ def flash_attention_with_grad(q, k, v, causal=False, scale=None,
 
     f.defvjp(f_fwd, f_bwd)
     return f(q, k, v)
-
-
-def conv3x3_bn_stats(x, w, interpret=False):
-    """Fused 3x3 stride-1 SAME conv + BatchNorm statistics (round-5
-    PERF experiment, VERDICT r4 next #1b).
-
-    x (N, H, W, C_in) NHWC; w (3, 3, C_in, C_out). Returns
-    (y (N, H, W, C_out), sum_c (C_out,), sumsq_c (C_out,)) where the
-    per-channel sums are accumulated INSIDE the conv epilogue while the
-    output tile is still in VMEM — the one fusion XLA structurally cannot
-    do (a full-reduction consumer inside a conv producer), saving the
-    separate stats read pass over y that makes BN training HBM-bound
-    (PERF.md roofline). Grid over N; per-step compute is 9 shifted
-    (H*W, C_in) @ (C_in, C_out) MXU matmuls.
-    """
-    import functools
-
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    n, h, wd, cin = x.shape
-    cout = w.shape[-1]
-
-    def kernel(xr, wr, yr, sr, qr):
-        i = pl.program_id(0)
-        # SAME-pad halo built IN VMEM: the block already holds the whole
-        # image, so padding here is register/VMEM work — doing it outside
-        # the kernel (jnp.pad) materializes a padded copy in HBM and was
-        # measured to cost the C=128 case the win (PERF.md round 5)
-        xpad = jnp.pad(xr[0], ((1, 1), (1, 1), (0, 0)))
-        acc = jnp.zeros((h * wd, cout), jnp.float32)
-        for kh in range(3):
-            for kw in range(3):
-                tap = xpad[kh:kh + h, kw:kw + wd, :].reshape(h * wd, cin)
-                acc += jax.lax.dot(
-                    tap, wr[kh, kw],
-                    preferred_element_type=jnp.float32)
-        yr[0] = acc.reshape(h, wd, cout).astype(yr.dtype)
-        psum = jnp.sum(acc, axis=0)
-        psq = jnp.sum(acc * acc, axis=0)
-
-        @pl.when(i == 0)
-        def _init():
-            sr[...] = psum
-            qr[...] = psq
-
-        @pl.when(i != 0)
-        def _acc():
-            sr[...] += psum
-            qr[...] += psq
-
-    return pl.pallas_call(
-        kernel,
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec((1, h, wd, cin), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((3, 3, cin, cout), lambda i: (0, 0, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, h, wd, cout), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((cout,), lambda i: (0,)),
-            pl.BlockSpec((cout,), lambda i: (0,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, h, wd, cout), x.dtype),
-            jax.ShapeDtypeStruct((cout,), jnp.float32),
-            jax.ShapeDtypeStruct((cout,), jnp.float32),
-        ],
-        interpret=interpret,
-        name="conv3x3_bn_stats",
-    )(x, w)
-
-
-def conv3x3_bn_relu_train(x, w, gamma, beta, eps=1e-3, interpret=False):
-    """Trainable fused conv3x3(s1, SAME) + batch-stats BN + relu.
-
-    Forward: the Pallas conv3x3_bn_stats kernel — conv output AND the BN
-    statistics in ONE HBM pass (the separate stats read is the pass that
-    makes BN training HBM-bound, PERF.md roofline). Backward:
-    jax.custom_vjp with the standard conv/BN backward in XLA ops —
-    identical structure to what autodiff emits for the unfused forward,
-    so only the forward's traffic changes.
-
-    Returns (out (N,H,W,Cout), mean (Cout,) f32, var (Cout,) f32); mean/
-    var feed the moving-average update (no gradient flows through them).
-    """
-    import functools as _ft
-
-    import jax
-    import jax.numpy as jnp
-
-    n, h, wd, cin = x.shape
-    cnt = n * h * wd
-
-    def _fwd_core(x, w, gamma, beta):
-        y_raw, s, q = conv3x3_bn_stats(x, w, interpret=interpret)
-        mean = s / cnt
-        var = jnp.maximum(q / cnt - jnp.square(mean), 0.0)
-        inv32 = jax.lax.rsqrt(var + eps) * gamma.astype(jnp.float32)
-        shift = beta.astype(jnp.float32) - mean * inv32
-        pre = y_raw * inv32.astype(y_raw.dtype) + shift.astype(y_raw.dtype)
-        return jnp.maximum(pre, 0), mean, var, y_raw
-
-    @_ft.partial(jax.custom_vjp)
-    def f(x, w, gamma, beta):
-        out, mean, var, _ = _fwd_core(x, w, gamma, beta)
-        return out, mean, var
-
-    def f_fwd(x, w, gamma, beta):
-        out, mean, var, y_raw = _fwd_core(x, w, gamma, beta)
-        return (out, mean, var), (x, w, gamma, y_raw, mean, var, out)
-
-    def f_bwd(res, cots):
-        x, w, gamma, y_raw, mean, var, out = res
-        dout, dmean, dvar = cots
-        inv = jax.lax.rsqrt(var + eps)
-        g32 = gamma.astype(jnp.float32)
-        dy = jnp.where(out > 0, dout, 0).astype(jnp.float32)
-        y32 = y_raw.astype(jnp.float32)
-        xhat = (y32 - mean) * inv
-        red = (0, 1, 2)
-        dbeta = jnp.sum(dy, axis=red)
-        dgamma = jnp.sum(dy * xhat, axis=red)
-        dxhat = dy * g32
-        # batch-stats BN backward (mean/var are functions of y_raw)
-        dy_raw = (inv / cnt) * (
-            cnt * dxhat - jnp.sum(dxhat, axis=red)
-            - xhat * jnp.sum(dxhat * xhat, axis=red))
-        # cotangents of the exposed stats outputs (e.g. a
-        # stats-regularization term): mean = Σy/cnt,
-        # var = Σy²/cnt − mean² ⇒ ∂var/∂y = 2(y − mean)/cnt
-        dy_raw = dy_raw + dmean.astype(jnp.float32) / cnt \
-            + dvar.astype(jnp.float32) * 2.0 * (y32 - mean) / cnt
-        dy_raw = dy_raw.astype(y_raw.dtype)
-        # conv backward: dgrad via transposed kernel, wgrad via x*dy conv
-        dx = jax.lax.conv_general_dilated(
-            dy_raw, jnp.flip(jnp.asarray(w), (0, 1)).swapaxes(2, 3),
-            (1, 1), "SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
-        # wgrad: x^T (Cin,H,W,N) conv dy^T (H,W,N,Cout) with pad 1 ->
-        # (Cin, 3, 3, Cout)
-        dw = jax.lax.conv_general_dilated(
-            jnp.transpose(jnp.asarray(x), (3, 1, 2, 0)),
-            jnp.transpose(dy_raw, (1, 2, 0, 3)), (1, 1),
-            ((1, 1), (1, 1)),
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
-        dw = jnp.transpose(dw, (1, 2, 0, 3)).astype(w.dtype)
-        return (dx.astype(x.dtype), dw, dgamma.astype(gamma.dtype),
-                dbeta.astype(gamma.dtype))
-
-    f.defvjp(f_fwd, f_bwd)
-    return f(x, w, gamma, beta)

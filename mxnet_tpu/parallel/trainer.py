@@ -20,6 +20,65 @@ from .optim import make_update_fn
 
 __all__ = ["ShardedTrainer", "make_update_fn"]
 
+# the attributes that hold each step variant's live executables
+_VARIANT_EXECUTABLES = {"fused": ("_step",), "masked": ("_step_masked",),
+                        "accum": ("_grads_fn", "_apply_fn")}
+
+
+def _step_functions(compute_loss, update, fp_on):
+    """The sharded training step, written once: ``(fused, grads, apply)``.
+    Every step program, live or shadow-replayed, is one of these three
+    compiled for a mesh (``ShardedTrainer._programs``), so the variants
+    cannot drift apart.
+
+    ``length`` (optional, (B,) int32 per-row valid token counts) masks
+    pad tokens out of the loss: the mask is built in-graph from an iota
+    compare, so the program stays ONE executable across calls (length
+    values are runtime data), and enters as a normalized per-token
+    sample weight, making the scalar loss exactly
+    sum(loss*mask)/sum(mask) — bitwise-equal to weighting with an
+    explicitly precomputed host-side mask."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..resilience import integrity as _integrity
+
+    def loss_of(params, aux, x, y, length):
+        if length is None:
+            return compute_loss(params, aux, x, y)
+        t = int(x.shape[1])
+        mask = (jnp.arange(t, dtype=jnp.int32)[None, :]
+                < length.astype(jnp.int32)[:, None]
+                ).astype(jnp.float32)
+        # normalize so the loss's final mean over B*T elements
+        # becomes the mean over the sum(mask) REAL tokens
+        w = (mask * (float(mask.size) / jnp.sum(mask)))[..., None]
+        return compute_loss(params, aux, x, y, w)
+
+    def grads(params, aux, x, y, length=None):
+        (loss, new_aux), g = jax.value_and_grad(
+            loss_of, has_aux=True)(params, aux, x, y, length)
+        return g, new_aux, loss
+
+    def apply(params, grads, opt_state):
+        # the scope names the update's ops in the compiled program, where
+        # a trace tells them from forward (``jvp``) and backward
+        # (``transpose(jvp)``) ops
+        with jax.named_scope("optimizer"):
+            return update(params, grads, opt_state)
+
+    def fused(params, aux, opt_state, x, y, length=None):
+        g, new_aux, loss = grads(params, aux, x, y, length)
+        new_params, new_opt = apply(params, g, opt_state)
+        if fp_on:
+            # in-graph step fingerprint (resilience.integrity): one extra
+            # uint32 output of the SAME program — zero extra executables
+            return (new_params, new_aux, new_opt, loss,
+                    _integrity.step_fold(new_params, g))
+        return new_params, new_aux, new_opt, loss
+
+    return fused, grads, apply
+
 
 class ShardedTrainer:
     """Compiles a full training step over a mesh.
@@ -131,19 +190,22 @@ class ShardedTrainer:
 
     def _bind_mesh(self, mesh):
         """(Re)derive every mesh-dependent binding — NamedShardings for
-        params/aux/batch, the multi-process flag, and the compiled step/
-        elastic executables (invalidated: they bake the old mesh in).
-        Used at construction and by the peer-loss mesh-shrink resume;
-        does NOT move any arrays (placement is _place or a restore)."""
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
+        params/aux/batch/opt_state, the multi-process flag, and the
+        compiled step executables (invalidated: they bake the old mesh
+        in). Used at construction and by the peer-loss mesh-shrink
+        resume; does NOT move any arrays (placement is _place or a
+        restore)."""
         self.mesh = mesh
-        self._param_sharding = {
-            k: NamedSharding(mesh, self._spec_for(k)) for k in self.params}
-        repl = NamedSharding(mesh, P())
-        self._aux_sharding = {k: repl for k in self.aux}
-        self._batch_sharding = NamedSharding(mesh, P(self._batch_axis))
+        self._shardings = self._shardings_on(mesh)
+        (self._param_sharding, self._aux_sharding,
+         self._batch_sharding) = self._shardings[:3]
         self._multiproc = self._is_multiprocess()
+        self._drop_executables()
+
+    def _drop_executables(self):
+        """Forget the compiled step programs: the next step rebuilds (and
+        re-captures) them under the current mesh, hyperparameters and
+        kernel schedule table."""
         self._step = None
         self._step_masked = None
         self._grads_fn = None
@@ -201,39 +263,40 @@ class ShardedTrainer:
         self.opt_state = jax.tree.map(put, self.opt_state,
                                       self._opt_sharding())
 
-    def _opt_sharding(self, mesh=None, param_sharding=None):
-        """Sharding pytree for opt_state: param-shaped state leaves
-        (momenta, adam moments, master copies) follow their parameter's
-        sharding; everything else (step counter, rng keys) is replicated.
-        Used both for placement and for the step's in/out shardings — the
-        two MUST agree, or the donated state input aliases an
-        incompatibly-sharded output buffer (XLA INTERNAL size-mismatch).
-        ``mesh``/``param_sharding`` override the trainer's own bindings
-        so the integrity shadow replay can mirror the same structure
-        onto a different same-shape mesh."""
+    def _shardings_on(self, mesh):
+        """``(params, aux, batch, opt_state)`` shardings on ``mesh``: the
+        live mesh's (``_bind_mesh``) and the integrity shadow mesh's come
+        from here. Param-shaped opt_state leaves (momenta, adam moments,
+        master copies) follow their parameter's sharding; everything
+        else (step counter, rng keys) is replicated."""
         import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
 
-        if mesh is None:
-            mesh = self.mesh
-        if param_sharding is None:
-            param_sharding = self._param_sharding
-        repl = jax.sharding.NamedSharding(
-            mesh, jax.sharding.PartitionSpec())
+        param = {k: NamedSharding(mesh, self._spec_for(k))
+                 for k in self.params}
+        repl = NamedSharding(mesh, P())
 
         def shard_for(name, leaf):
-            ps = param_sharding.get(name)
             p = self.params.get(name)
-            if ps is not None and p is not None \
-                    and hasattr(leaf, "shape") \
+            if p is not None and hasattr(leaf, "shape") \
                     and tuple(leaf.shape) == tuple(p.shape):
-                return ps
+                return param[name]
             return repl
 
         state = {
             k: jax.tree.map(lambda v, _k=k: shard_for(_k, v), s)
             for k, s in self.opt_state["state"].items()}
-        return {**{k: repl for k in self.opt_state if k != "state"},
-                "state": state}
+        opt = {**{k: repl for k in self.opt_state if k != "state"},
+               "state": state}
+        return (param, {k: repl for k in self.aux},
+                NamedSharding(mesh, P(self._batch_axis)), opt)
+
+    def _opt_sharding(self):
+        """Sharding pytree for opt_state on the current mesh. Used both
+        for placement and for the step's in/out shardings — the two MUST
+        agree, or the donated state input aliases an
+        incompatibly-sharded output buffer (XLA INTERNAL size-mismatch)."""
+        return self._shardings[3]
 
     def _make_compute_loss(self):
         """The traced loss closure shared by the fused step and the
@@ -292,22 +355,6 @@ class ShardedTrainer:
             return loss.data_.mean(), new_aux
 
         return compute_loss
-
-    def _named_update(self):
-        """The optimizer's update under the ``optimizer`` scope, for
-        every step program (fused, masked, elastic apply, shadow replay):
-        its ops carry the name in the compiled program, where a trace
-        tells them from forward (``jvp``) and backward
-        (``transpose(jvp)``) ops."""
-        import jax
-
-        update = self._update
-
-        def named(params, grads, opt_state):
-            with jax.named_scope("optimizer"):
-                return update(params, grads, opt_state)
-
-        return named
 
     def _capture_fingerprint(self):
         """Structural identity of this trainer's step programs for the
@@ -374,91 +421,58 @@ class ShardedTrainer:
         return _capture.CapturedExec(fn, label=label, fingerprint=fp,
                                      **kwargs)
 
-    def _build_step(self):
-        import jax
-
+    def _programs(self, variant, shardings):
+        """``[(label, function, jit arguments)]`` of one variant of the
+        step — ``"fused"``, ``"masked"`` (one extra ``length`` operand)
+        or ``"accum"`` (a NON-donating gradient program, whose params
+        every microbatch and any further retry reuse, and an apply
+        program for the single optimizer update) — on the mesh whose
+        ``_shardings_on`` are given. The live executables and the
+        integrity shadow replay both compile exactly this."""
         from ..resilience import integrity as _integrity
 
-        update = self._named_update()
-        compute_loss = self._make_compute_loss()
-        # in-graph step fingerprint (resilience.integrity): one extra
-        # uint32 output of the SAME program — zero extra executables.
-        # Armed at build time; the capture fingerprint folds the flag so
-        # an AOT artifact compiled without it can never false-hit.
-        fp_on = self._fp_armed = _integrity.fingerprint_enabled()
-
-        def step(params, aux, opt_state, x, y):
-            (loss, new_aux), grads = jax.value_and_grad(
-                compute_loss, has_aux=True)(params, aux, x, y)
-            new_params, new_opt = update(params, grads, opt_state)
-            if fp_on:
-                return (new_params, new_aux, new_opt, loss,
-                        _integrity.step_fold(new_params, grads))
-            return new_params, new_aux, new_opt, loss
-
+        param, aux, batch, opt = shardings
+        # armed at build time; the capture fingerprint folds the flag so
+        # an AOT artifact compiled without it can never false-hit
+        fp_on = _integrity.fingerprint_enabled()
+        fused, grads, apply = _step_functions(
+            self._make_compute_loss(), self._update, fp_on)
+        if variant == "accum":
+            # gradients land in the parameter shardings so accumulation
+            # never reshards; the microbatch shapes key the signature: an
+            # elastic shrink re-captures at the smaller batch and the
+            # re-capture lands in the retrace forensics instead of
+            # recompiling silently
+            return [
+                ("sharded_grads", grads, dict(
+                    in_shardings=(param, aux, batch, batch),
+                    out_shardings=(param, aux, None), sig_argnums=(2, 3))),
+                ("sharded_apply", apply, dict(
+                    in_shardings=(param, param, opt),
+                    out_shardings=(param, opt)))]
+        label, operands = {"fused": ("sharded_step", 2),
+                           "masked": ("sharded_step_masked", 3)}[variant]
         # opt_state shardings are pinned on BOTH sides: donation aliases
         # each state input buffer to its output, which is only valid when
         # the output keeps the input's sharding (XLA propagation would
         # otherwise shard tp-param momenta and break the aliasing)
-        opt_sharding = self._opt_sharding()
-        out_shardings = (self._param_sharding, self._aux_sharding,
-                         opt_sharding, None) + ((None,) if fp_on else ())
-        self._step = self._capture_exec(
-            step, "sharded_step",
-            in_shardings=(self._param_sharding, self._aux_sharding,
-                          opt_sharding, self._batch_sharding,
-                          self._batch_sharding),
-            out_shardings=out_shardings,
-            donate_argnums=(0, 1, 2), sig_argnums=(3, 4))
+        return [(label, fused, dict(
+            in_shardings=(param, aux, opt) + (batch,) * operands,
+            out_shardings=(param, aux, opt, None)
+            + ((None,) if fp_on else ()),
+            donate_argnums=(0, 1, 2),
+            sig_argnums=tuple(range(3, 3 + operands))))]
 
-    def _build_masked_step(self):
-        """The pad-masked variant of the fused step: one extra (B,) int32
-        ``length`` operand (StreamBatch.length — per-row valid token
-        counts), mask built in-graph from an iota compare so the program
-        stays ONE executable across calls (length values are runtime
-        data, never folded into the signature). The mask enters as a
-        normalized per-token sample weight, making the step's scalar
-        loss exactly sum(loss*mask)/sum(mask) — bitwise-equal to
-        weighting with an explicitly precomputed host-side mask."""
-        import jax
-        import jax.numpy as jnp
-
-        update = self._named_update()
-        compute_loss = self._make_compute_loss()
-
-        def masked_loss(params, aux, x, y, length):
-            t = int(x.shape[1])
-            mask = (jnp.arange(t, dtype=jnp.int32)[None, :]
-                    < length.astype(jnp.int32)[:, None]
-                    ).astype(jnp.float32)
-            # normalize so the loss's final mean over B*T elements
-            # becomes the mean over the sum(mask) REAL tokens
-            w = (mask * (float(mask.size) / jnp.sum(mask)))[..., None]
-            return compute_loss(params, aux, x, y, w)
-
-        from ..resilience import integrity as _integrity
-
-        fp_on = self._fp_armed = _integrity.fingerprint_enabled()
-
-        def step(params, aux, opt_state, x, y, length):
-            (loss, new_aux), grads = jax.value_and_grad(
-                masked_loss, has_aux=True)(params, aux, x, y, length)
-            new_params, new_opt = update(params, grads, opt_state)
-            if fp_on:
-                return (new_params, new_aux, new_opt, loss,
-                        _integrity.step_fold(new_params, grads))
-            return new_params, new_aux, new_opt, loss
-
-        opt_sharding = self._opt_sharding()
-        out_shardings = (self._param_sharding, self._aux_sharding,
-                         opt_sharding, None) + ((None,) if fp_on else ())
-        self._step_masked = self._capture_exec(
-            step, "sharded_step_masked",
-            in_shardings=(self._param_sharding, self._aux_sharding,
-                          opt_sharding, self._batch_sharding,
-                          self._batch_sharding, self._batch_sharding),
-            out_shardings=out_shardings,
-            donate_argnums=(0, 1, 2), sig_argnums=(3, 4, 5))
+    def _build(self, variant):
+        """The live executables of ``variant``, compiled through the
+        capture path on first use (and again after ``_drop_executables``):
+        ``(_step,)``, ``(_step_masked,)`` or ``(_grads_fn, _apply_fn)``."""
+        attrs = _VARIANT_EXECUTABLES[variant]
+        if getattr(self, attrs[0]) is None:
+            programs = self._programs(variant, self._shardings)
+            for attr, (label, fn, kwargs) in zip(attrs, programs):
+                setattr(self, attr, self._capture_exec(fn, label, **kwargs))
+        return tuple(getattr(self, a) for a in attrs)
 
     @classmethod
     def for_multihost(cls, net, loss_fn, optimizer="sgd",
@@ -536,9 +550,7 @@ class ShardedTrainer:
         _, update = make_update_fn(self._optimizer,
                                    dict(self._optimizer_params))
         self._update = update
-        self._step = None  # rebuild (and recompile) with the new rate
-        self._step_masked = None
-        self._grads_fn = self._apply_fn = None  # elastic path too
+        self._drop_executables()  # rebuild (and recompile) with the new rate
 
     @property
     def learning_rate(self):
@@ -620,20 +632,13 @@ class ShardedTrainer:
 
             if _capture._schedule_token() != getattr(self, "_sched_token",
                                                      None):
-                self._step = None
-                self._step_masked = None
-                self._grads_fn = self._apply_fn = None
+                self._drop_executables()
         if length is not None and microbatches is not None \
                 and int(microbatches) != 1:
             raise ValueError(
                 "length= (pad masking) runs the fused step only; "
                 "accumulated microbatches would re-normalize the mask "
                 "per slice — request microbatches=None")
-        if length is not None:
-            if self._step_masked is None:
-                self._build_masked_step()
-        elif self._step is None:
-            self._build_step()
         if isinstance(x, NDArray):
             x = x.data_
         if isinstance(y, NDArray):
@@ -726,21 +731,15 @@ class ShardedTrainer:
                     _faults.maybe_oom_step()
                     with _obs_trace.span("sharded.execute",
                                          microbatches=n):
-                        if length is not None:
-                            if self._step_masked is None:  # mesh rebound
-                                self._build_masked_step()
-                            outs = self._step_masked(self.params, self.aux,
-                                                     self.opt_state, x, y,
-                                                     length)
-                            (self.params, self.aux, self.opt_state,
-                             loss) = outs[:4]
-                            self._last_fp_out = \
-                                outs[4] if len(outs) > 4 else None
-                        elif n <= 1:
-                            if self._step is None:  # mesh rebound mid-retry
-                                self._build_step()
-                            outs = self._step(self.params, self.aux,
-                                              self.opt_state, x, y)
+                        if n <= 1:
+                            # built here, not before the loop: a mesh
+                            # rebound mid-retry dropped the executable
+                            fused, = self._build(
+                                "fused" if length is None else "masked")
+                            batch = (x, y) if length is None \
+                                else (x, y, length)
+                            outs = fused(self.params, self.aux,
+                                         self.opt_state, *batch)
                             (self.params, self.aux, self.opt_state,
                              loss) = outs[:4]
                             self._last_fp_out = \
@@ -1010,91 +1009,62 @@ class ShardedTrainer:
         x, y = self._host_local_batch(x), self._host_local_batch(y)
         return jax.device_put(x, bs), jax.device_put(y, bs)
 
-    def _build_elastic(self):
-        """Two executables for the accumulated path: a NON-donating
-        gradient function (its params are reused by every microbatch and
-        by any further retry) and an apply function for the single
-        optimizer update. Gradients land in the parameter shardings so
-        accumulation never reshards."""
-        import jax
-
-        update = self._named_update()
-        compute_loss = self._make_compute_loss()
-
-        def grads_fn(params, aux, x, y):
-            (loss, new_aux), grads = jax.value_and_grad(
-                compute_loss, has_aux=True)(params, aux, x, y)
-            return grads, new_aux, loss
-
-        # the microbatch shapes key the signature: an elastic shrink
-        # re-captures at the smaller batch and the re-capture lands in
-        # the retrace forensics instead of recompiling silently
-        self._grads_fn = self._capture_exec(
-            grads_fn, "sharded_grads",
-            in_shardings=(self._param_sharding, self._aux_sharding,
-                          self._batch_sharding, self._batch_sharding),
-            out_shardings=(self._param_sharding, self._aux_sharding, None),
-            sig_argnums=(2, 3))
-
-        def apply_fn(params, grads, opt_state):
-            return update(params, grads, opt_state)
-
-        opt_sharding = self._opt_sharding()
-        self._apply_fn = self._capture_exec(
-            apply_fn, "sharded_apply",
-            in_shardings=(self._param_sharding, self._param_sharding,
-                          opt_sharding),
-            out_shardings=(self._param_sharding, opt_sharding))
-
-    def _accum_step(self, n, x, y):
-        """One optimizer update from n accumulated microbatches: grads
-        are computed per microbatch on the SAME params, summed, divided
-        by n (mean-of-means == full-batch mean for equal slices), then
-        applied once. aux chains through microbatches sequentially.
-        Bitwise identical to an explicit step(..., microbatches=n)."""
+    def _accumulate(self, n, programs, batch_sharding, state, x, y, live):
+        """One optimizer update from n accumulated microbatches, on the
+        live executables or the shadow replay's (``programs`` =
+        ``(grads, apply)``): grads are computed per microbatch on the
+        SAME params, summed, divided by n (mean-of-means == full-batch
+        mean for equal slices), then applied once. aux chains through
+        microbatches sequentially. Returns ``(params, aux, opt_state,
+        loss, fingerprint or None)``."""
         import jax
         import jax.numpy as jnp
 
-        from ..resilience import elastic as _elastic
         from ..resilience import faults as _faults
+        from ..resilience import integrity as _integrity
 
-        if self._grads_fn is None:
-            self._build_elastic()
-        _elastic._STATS["elastic_accum_steps"] += 1
-        rows = int(x.shape[0])
-        mb = rows // n
-        params, aux, opt_state = self.params, self.aux, self.opt_state
+        grads_fn, apply_fn = programs
+        params, aux, opt_state = state
+        mb = int(x.shape[0]) // n
         acc = None
         loss_sum = None
-        bs = self._batch_sharding
         for i in range(n):
             sl = slice(i * mb, (i + 1) * mb)
             # an eager slice of a dp-sharded batch comes back replicated;
             # re-place it so the grad executable sees the batch sharding
-            x_i = jax.device_put(x[sl], bs)
-            y_i = jax.device_put(y[sl], bs)
-            grads, aux, loss = self._grads_fn(params, aux, x_i, y_i)
+            x_i = jax.device_put(x[sl], batch_sharding)
+            y_i = jax.device_put(y[sl], batch_sharding)
+            grads, aux, loss = grads_fn(params, aux, x_i, y_i)
             acc = grads if acc is None else jax.tree.map(jnp.add, acc, grads)
             loss_sum = loss if loss_sum is None else loss_sum + loss
         inv = 1.0 / n
         acc = jax.tree.map(lambda g: g * inv, acc)
-        acc = _faults.maybe_sdc_bitflip_grad(acc)
-        params, opt_state = self._apply_fn(params, acc, opt_state)
-        self.params, self.aux, self.opt_state = params, aux, opt_state
-        from ..resilience import integrity as _integrity
-
+        if live:
+            acc = _faults.maybe_sdc_bitflip_grad(acc)
+        params, opt_state = apply_fn(params, acc, opt_state)
+        fp = None
         if _integrity.fingerprint_enabled():
             # the accumulated path has no single fused executable to grow
             # an output on — fold the same fingerprint host-side over the
             # applied params and the accumulated (divided) grads
             import numpy as np
 
-            self._last_fp_out = np.uint32(_integrity.step_fold_host(
+            fp = np.uint32(_integrity.step_fold_host(
                 {k: np.asarray(v) for k, v in params.items()},
                 {k: np.asarray(v) for k, v in acc.items()}))
-        else:
-            self._last_fp_out = None
-        return loss_sum / n
+        return params, aux, opt_state, loss_sum / n, fp
+
+    def _accum_step(self, n, x, y):
+        """The live step as n accumulated microbatches (``_accumulate``).
+        Bitwise identical to an explicit step(..., microbatches=n)."""
+        from ..resilience import elastic as _elastic
+
+        _elastic._STATS["elastic_accum_steps"] += 1
+        (self.params, self.aux, self.opt_state, loss,
+         self._last_fp_out) = self._accumulate(
+            n, self._build("accum"), self._batch_sharding,
+            (self.params, self.aux, self.opt_state), x, y, live=True)
+        return loss
 
     def get_states_bytes(self):
         """Serialize opt_state (host-side npz keyed by pytree path) — the
@@ -1223,133 +1193,53 @@ class ShardedTrainer:
                          microbatches=1, length=None):
         """Re-execute ONE training step from host-side pre-step state on
         an alternate same-shape mesh (the shadow slice of the SDC audit,
-        resilience.integrity.audit_step). Mirrors the live variant
-        exactly — fused, pad-masked, or n-microbatch accumulation — since
-        the variants are not bitwise-interchangeable (different grad
-        arithmetic); the shadow mesh keeps the live mesh's shape and axis
-        names so GSPMD emits the same collective structure and float
-        reduction order. Returns ``(host new_params dict, uint32
-        fingerprint or None)``. The trainer's own state, mesh, and
-        executables are untouched; replay executables are plain
-        non-donating jits cached per (shadow devices, variant, capture
-        fingerprint)."""
+        resilience.integrity.audit_step). Compiles the live variant's own
+        programs (``_programs``: fused, pad-masked, or n-microbatch
+        accumulation — the variants are not bitwise-interchangeable,
+        their grad arithmetic differs) for the shadow mesh, which keeps
+        the live mesh's shape and axis names so GSPMD emits the same
+        collective structure and float reduction order. Returns ``(host
+        new_params dict, uint32 fingerprint or None)``. The trainer's own
+        state, mesh, and executables are untouched; replay executables
+        are plain non-donating jits cached per (shadow devices, variant,
+        capture fingerprint)."""
         import numpy as np
 
         import jax
-        import jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
 
         from ..resilience import integrity as _integrity
 
-        fp_on = _integrity.fingerprint_enabled()
         n = max(1, int(microbatches))
+        variant = ("masked" if length is not None
+                   else "fused" if n <= 1 else "accum")
         key = (tuple(int(d.id) for d in mesh.devices.flat), n,
-               length is not None, fp_on, self._capture_fingerprint())
+               length is not None, _integrity.fingerprint_enabled(),
+               self._capture_fingerprint())
         cached = getattr(self, "_replay_cache", None)
-        if cached is not None and cached[0] == key:
-            shards, fns = cached[1], cached[2]
+        if cached is None or cached[0] != key:
+            shardings = self._shardings_on(mesh)
+            fns = tuple(
+                jax.jit(fn, in_shardings=kwargs["in_shardings"],
+                        out_shardings=kwargs["out_shardings"])
+                for _, fn, kwargs in self._programs(variant, shardings))
+            cached = self._replay_cache = (key, shardings, fns)
+        _, (param_sh, aux_sh, batch_sh, opt_sh), fns = cached
+
+        def put(tree, shardings):
+            return jax.tree.map(
+                lambda leaf, sh: jax.device_put(np.asarray(leaf), sh),
+                tree, shardings)
+
+        state = (put(params, param_sh), put(aux, aux_sh),
+                 put(opt_state, opt_sh))
+        batch = tuple(jax.device_put(np.asarray(a), batch_sh)
+                      for a in (x, y) + (() if length is None
+                                         else (length,)))
+        if variant == "accum":
+            outs = self._accumulate(n, fns, batch_sh, state, *batch,
+                                    live=False)
         else:
-            param_sh = {k: NamedSharding(mesh, self._spec_for(k))
-                        for k in params}
-            repl = NamedSharding(mesh, P())
-            aux_sh = {k: repl for k in aux}
-            batch_sh = NamedSharding(mesh, P(self._batch_axis))
-            opt_sh = self._opt_sharding(mesh=mesh,
-                                        param_sharding=param_sh)
-            shards = (param_sh, aux_sh, batch_sh, opt_sh)
-            update = self._named_update()
-            compute_loss = self._make_compute_loss()
-            if length is not None:
-                def masked_loss(p, a, xx, yy, ll):
-                    t = int(xx.shape[1])
-                    mask = (jnp.arange(t, dtype=jnp.int32)[None, :]
-                            < ll.astype(jnp.int32)[:, None]
-                            ).astype(jnp.float32)
-                    w = (mask * (float(mask.size) / jnp.sum(mask))
-                         )[..., None]
-                    return compute_loss(p, a, xx, yy, w)
-
-                def rstep(p, a, o, xx, yy, ll):
-                    (_loss, _na), grads = jax.value_and_grad(
-                        masked_loss, has_aux=True)(p, a, xx, yy, ll)
-                    new_p, _no = update(p, grads, o)
-                    fp = _integrity.step_fold(new_p, grads) \
-                        if fp_on else jnp.uint32(0)
-                    return new_p, fp
-
-                fns = jax.jit(
-                    rstep,
-                    in_shardings=(param_sh, aux_sh, opt_sh, batch_sh,
-                                  batch_sh, batch_sh),
-                    out_shardings=(param_sh, None))
-            elif n <= 1:
-                def rstep(p, a, o, xx, yy):
-                    (_loss, _na), grads = jax.value_and_grad(
-                        compute_loss, has_aux=True)(p, a, xx, yy)
-                    new_p, _no = update(p, grads, o)
-                    fp = _integrity.step_fold(new_p, grads) \
-                        if fp_on else jnp.uint32(0)
-                    return new_p, fp
-
-                fns = jax.jit(
-                    rstep,
-                    in_shardings=(param_sh, aux_sh, opt_sh, batch_sh,
-                                  batch_sh),
-                    out_shardings=(param_sh, None))
-            else:
-                def grads_fn(p, a, xx, yy):
-                    (loss, new_a), grads = jax.value_and_grad(
-                        compute_loss, has_aux=True)(p, a, xx, yy)
-                    return grads, new_a, loss
-
-                def apply_fn(p, g, o):
-                    return update(p, g, o)
-
-                fns = (
-                    jax.jit(grads_fn,
-                            in_shardings=(param_sh, aux_sh, batch_sh,
-                                          batch_sh),
-                            out_shardings=(param_sh, aux_sh, None)),
-                    jax.jit(apply_fn,
-                            in_shardings=(param_sh, param_sh, opt_sh),
-                            out_shardings=(param_sh, opt_sh)))
-            self._replay_cache = (key, shards, fns)
-        param_sh, aux_sh, batch_sh, opt_sh = shards
-        p_dev = {k: jax.device_put(np.asarray(v), param_sh[k])
-                 for k, v in params.items()}
-        a_dev = {k: jax.device_put(np.asarray(v), aux_sh[k])
-                 for k, v in aux.items()}
-        o_dev = jax.tree.map(
-            lambda leaf, sh: jax.device_put(np.asarray(leaf), sh),
-            opt_state, opt_sh)
-        x_dev = jax.device_put(np.asarray(x), batch_sh)
-        y_dev = jax.device_put(np.asarray(y), batch_sh)
-        if length is not None:
-            l_dev = jax.device_put(np.asarray(length), batch_sh)
-            new_p, fp = fns(p_dev, a_dev, o_dev, x_dev, y_dev, l_dev)
-        elif n <= 1:
-            new_p, fp = fns(p_dev, a_dev, o_dev, x_dev, y_dev)
-        else:
-            gfn, afn = fns
-            rows = int(x_dev.shape[0])
-            mb = rows // n
-            acc = None
-            a_cur = a_dev
-            for i in range(n):
-                sl = slice(i * mb, (i + 1) * mb)
-                x_i = jax.device_put(x_dev[sl], batch_sh)
-                y_i = jax.device_put(y_dev[sl], batch_sh)
-                grads, a_cur, _loss = gfn(p_dev, a_cur, x_i, y_i)
-                acc = grads if acc is None \
-                    else jax.tree.map(jnp.add, acc, grads)
-            inv = 1.0 / n
-            acc = jax.tree.map(lambda g: g * inv, acc)
-            new_p, _o = afn(p_dev, acc, o_dev)
-            host_p = {k: np.asarray(v) for k, v in new_p.items()}
-            fp = np.uint32(_integrity.step_fold_host(
-                host_p,
-                {k: np.asarray(v) for k, v in acc.items()})) \
-                if fp_on else None
-            return host_p, (None if fp is None else int(fp))
-        host_p = {k: np.asarray(v) for k, v in new_p.items()}
-        return host_p, (int(np.asarray(fp)) if fp_on else None)
+            outs = fns[0](*state, *batch)
+        fp = outs[4] if len(outs) > 4 else None
+        return ({k: np.asarray(v) for k, v in outs[0].items()},
+                None if fp is None else int(np.asarray(fp)))

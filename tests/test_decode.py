@@ -266,7 +266,19 @@ def test_stream_router_reroutes_on_replica_death(net, ref_decode):
 
 
 # -------------------------------------------------- operator + SLO wires
-def test_rollout_decode_gates_promote_and_ttft_rollback(net):
+def test_rollout_decode_gates_promote_and_ttft_rollback(net, monkeypatch):
+    import itertools
+    import types
+
+    from mxnet_tpu.serving import operator
+
+    # the canary windows read a clock the test sets (every probe takes
+    # 1 ms): one TTFT sample a window, timed on a host running five other
+    # workers, let a slow baseline sample pass the x100 candidate
+    ticks = itertools.count()
+    monkeypatch.setattr(operator, "time", types.SimpleNamespace(
+        perf_counter=lambda: next(ticks) * 1e-3))
+
     def factory():
         return serving.DecodePredictor(net, page_size=4, num_pages=16,
                                        max_seqs=2, prefill_buckets=(8,),
@@ -275,9 +287,7 @@ def test_rollout_decode_gates_promote_and_ttft_rollback(net):
     batch = np.zeros((1, 8), np.int32)
     with serving.Fleet(factory, replicas=1, mode="thread") as fleet:
         assert fleet.wait_healthy(timeout=30)
-        # a generous latency allowance: sub-ms TTFT probes on a loaded
-        # 1-core CI box can blip a few x from scheduler noise; the
-        # rollback half forces x100, which still trips the gate
+        # the rollback half forces x100 against an allowance of x30
         mgr = serving.RolloutManager(fleet, eval_batch=batch,
                                      canary_calls=4, max_latency_x=30.0)
         params = net.collect_params()

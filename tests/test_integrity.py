@@ -226,6 +226,51 @@ def test_sharded_in_graph_fingerprint_matches_host_fold(monkeypatch):
     assert again.last_fingerprint == fused.last_fingerprint
 
 
+@pytest.mark.parametrize("dp,microbatches,masked", [
+    pytest.param(4, None, False, id="fused"),
+    pytest.param(2, None, True, id="masked"),
+    pytest.param(2, 2, False, id="accum2")])
+def test_shadow_replay_matches_live_step(monkeypatch, dp, microbatches,
+                                         masked):
+    """``integrity_replay`` compiles the live variant's own programs for
+    the shadow mesh: from the same pre-step state it lands on bitwise the
+    live step's parameters and on the live step's fingerprint, for the
+    fused, the pad-masked and the accumulated step alike."""
+    import jax
+    from mxnet_tpu.parallel.mesh import create_mesh
+    from mxnet_tpu.parallel.trainer import ShardedTrainer
+
+    _fp_env(monkeypatch)
+    mx.random.seed(31)
+    net = mx.gluon.nn.Dense(5, in_units=4, flatten=False,
+                            prefix="integ_replay_")
+    net.initialize()
+    trainer = ShardedTrainer(
+        net, mx.gluon.loss.L2Loss(), "sgd",
+        {"learning_rate": 0.1, "momentum": 0.9},
+        mesh=create_mesh({"dp": dp}, jax.devices()[:dp]))
+    rs = np.random.RandomState(5)
+    x = rs.randn(8, 6, 4).astype(np.float32)
+    y = rs.randn(8, 6, 5).astype(np.float32)
+    length = rs.randint(1, 6, (8,)).astype(np.int32) if masked else None
+    trainer.step(x, y)  # the replayed step starts from moving momenta
+    before = jax.tree.map(
+        np.asarray, (trainer.params, trainer.aux, trainer.opt_state))
+    trainer.step(x, y, microbatches=microbatches, length=length)
+    shadow = integrity._shadow_mesh(trainer.mesh)
+    assert ({d.id for d in shadow.devices.flat}
+            != {d.id for d in trainer.mesh.devices.flat})
+    params, fp = trainer.integrity_replay(
+        shadow, *before, x, y, microbatches=microbatches or 1,
+        length=length)
+    assert set(params) == set(trainer.params)
+    for k, v in params.items():
+        live = np.asarray(trainer.params[k])
+        assert not np.array_equal(live, before[0][k]), k  # a real step
+        assert v.tobytes() == live.tobytes(), k
+    assert fp is not None and fp == trainer.last_fingerprint
+
+
 # ------------------------------------------------- checkpoint boundary
 
 def test_manifest_tamper_skips_to_previous_checkpoint(monkeypatch,

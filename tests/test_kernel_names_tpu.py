@@ -123,17 +123,6 @@ def test_flash_backward_is_named_and_compiles_at_real_widths(
     assert "while" not in compiled      # no scan is left in the backward
 
 
-def test_conv3x3_bn_stats_is_named_in_the_lowered_program(one_chip):
-    from mxnet_tpu.ops import pallas_kernels
-
-    text = _lowered(pallas_kernels.conv3x3_bn_stats, one_chip,
-                    ((2, 16, 16, 128), jnp.bfloat16),
-                    ((3, 3, 128, 128), jnp.bfloat16))
-    assert "tpu_custom_call" in text
-    assert 'kernel_name = "conv3x3_bn_stats"' in text
-    assert "conv3x3_bn_stats/pallas_call" in text
-
-
 def test_no_pallas_call_in_the_package_is_left_unnamed():
     """A kernel added later is named the same way (docs/observability.md,
     "The program's own names")."""

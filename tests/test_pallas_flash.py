@@ -467,64 +467,3 @@ def test_several_heads_share_a_grid_step():
     out = flash_attention(q, k, v, causal=True, interpret=True)
     np.testing.assert_allclose(np.asarray(out), _dense(q, k, v, True),
                                atol=1e-5)
-
-
-def test_conv3x3_bn_stats_interpret():
-    """Fused conv+BN-stats kernel: exact vs the XLA composition."""
-    import jax
-    import jax.numpy as jnp
-
-    from mxnet_tpu.ops.pallas_kernels import conv3x3_bn_stats
-
-    rng = np.random.RandomState(0)
-    x = rng.randn(2, 8, 8, 16).astype(np.float32)
-    w = (rng.randn(3, 3, 16, 32) * 0.1).astype(np.float32)
-    y, s, q = conv3x3_bn_stats(x, w, interpret=True)
-    ref = jax.lax.conv_general_dilated(
-        x, w, (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"))
-    assert float(jnp.abs(y - ref).max()) < 1e-5
-    assert float(jnp.abs(s - ref.sum(axis=(0, 1, 2))).max()) < 1e-4
-    assert float(jnp.abs(q - (ref.astype(jnp.float32) ** 2)
-                         .sum(axis=(0, 1, 2))).max()) < 1e-3
-
-
-def test_conv3x3_bn_relu_train_grads_exact():
-    """Trainable fused conv+BN+relu: forward and ALL gradients match the
-    unfused XLA composition (the PERF.md round-5 keep-or-kill evidence)."""
-    import jax
-    import jax.numpy as jnp
-
-    from mxnet_tpu.ops.pallas_kernels import conv3x3_bn_relu_train
-
-    rng = np.random.RandomState(0)
-    c = 8
-    x = rng.randn(2, 8, 8, c).astype(np.float32)
-    w = (rng.randn(3, 3, c, c) * 0.2).astype(np.float32)
-    gamma = (rng.rand(c) + 0.5).astype(np.float32)
-    beta = rng.randn(c).astype(np.float32)
-
-    def ref(x, w, gamma, beta):
-        y = jax.lax.conv_general_dilated(
-            x, w, (1, 1), "SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
-        mean = y.mean(axis=(0, 1, 2))
-        var = jnp.maximum((y * y).mean(axis=(0, 1, 2)) - mean ** 2, 0.0)
-        inv = jax.lax.rsqrt(var + 1e-3) * gamma
-        return jnp.maximum(y * inv + (beta - mean * inv), 0)
-
-    def loss(fn):
-        def L(*a):
-            out = fn(*a)
-            out = out[0] if isinstance(out, tuple) else out
-            return jnp.sum(out * jnp.cos(out))
-        return L
-
-    fused = lambda *a: conv3x3_bn_relu_train(*a, interpret=True)  # noqa: E731
-    o_ref = ref(x, w, gamma, beta)
-    o_f = fused(x, w, gamma, beta)[0]
-    assert float(jnp.abs(o_ref - o_f).max()) < 1e-5
-    g_ref = jax.grad(loss(ref), argnums=(0, 1, 2, 3))(x, w, gamma, beta)
-    g_f = jax.grad(loss(fused), argnums=(0, 1, 2, 3))(x, w, gamma, beta)
-    for a, b in zip(g_ref, g_f):
-        rel = float(jnp.abs(a - b).max() / (jnp.abs(a).max() + 1e-9))
-        assert rel < 1e-5, rel

@@ -427,19 +427,49 @@ def test_init_distributed_not_configured_returns_false(monkeypatch):
     assert kd.init_distributed() is False
 
 
-def test_init_distributed_timeout_with_backoff():
+def test_init_distributed_timeout_with_backoff(monkeypatch, tmp_path):
     """Acceptance: unreachable coordinator fails within the configured
-    deadline (no hang) after exponential-backoff retries."""
+    deadline (no hang) after exponential-backoff retries. The bootstrap
+    reads an injected clock, so the bounds hold on a loaded host: every
+    delay is the one the loop chose, and the time that passes is the
+    time it slept. Its rank claim goes under ``tmp_path``: a claim lives
+    as long as its process, and ``tests/test_watchdog.py`` claims rank 0
+    of the same endpoint from another xdist worker (whichever file came
+    second got a DistConfigError in place of the TimeoutError)."""
+    import tempfile
+    import types
+
     from mxnet_tpu.kvstore import dist as kd
 
-    t0 = time.monotonic()
+    monkeypatch.setattr(tempfile, "tempdir", str(tmp_path))
+    clock = types.SimpleNamespace(now=100.0, slept=[])
+    clock.monotonic = lambda: clock.now
+
+    def sleep(seconds):
+        clock.slept.append(seconds)
+        clock.now += seconds
+
+    clock.sleep = sleep
+    monkeypatch.setattr(kd, "time", clock)
     with faults.inject("dist_connect_timeout", times=None) as fault:
         with pytest.raises(TimeoutError, match="coordinator"):
             kd.init_distributed("127.0.0.1:9", num_processes=2, process_id=0,
                                 timeout=2.0, max_retries=3, backoff=0.1)
-    elapsed = time.monotonic() - t0
-    assert elapsed < 10.0            # bounded, no indefinite hang
     assert fault.fired == 4          # initial attempt + 3 backoff retries
+    # jittered over the upper half of an exponential ceiling
+    assert len(clock.slept) == 3
+    for delay, ceiling in zip(clock.slept, (0.1, 0.2, 0.4)):
+        assert ceiling / 2 <= delay <= ceiling
+    assert clock.now - 100.0 < 2.0   # bounded, no indefinite hang
+    assert not kd._initialized
+    # with retries to spare the deadline ends it: never a sleep past it
+    clock.now, clock.slept = 100.0, []
+    with faults.inject("dist_connect_timeout", times=None) as fault:
+        with pytest.raises(TimeoutError, match="within 2.0s"):
+            kd.init_distributed("127.0.0.1:9", num_processes=2, process_id=0,
+                                timeout=2.0, max_retries=60, backoff=0.1)
+    assert 4 < fault.fired < 60
+    assert clock.now - 100.0 == pytest.approx(2.0)
     assert not kd._initialized
 
 

@@ -1086,6 +1086,15 @@ def _drill_step_time_anomaly(mx, workdir):
     def loss_fn(out, y):
         return ((out - y) ** 2).sum()
 
+    def evaluate(now):
+        # the drill sets the step durations the detector reads from the
+        # trace ring, as it sets ``now``: the wall time of a CPU step on
+        # a loaded host doubles on its own, before or after the fault
+        for s in trace.spans():
+            if s["name"] in alerts.StepTimeDriftRule.STEP_ROOTS:
+                s["dur_ns"] = 10_000_000
+        alerts.evaluate(now=now, force=True)
+
     alerts.reset()
     prev_trace = trace.set_enabled(True)
     prev_alerts = alerts.set_enabled(False)
@@ -1098,13 +1107,13 @@ def _drill_step_time_anomaly(mx, workdir):
         for _ in range(10):
             step(x, y, batch_size=2)
         t = 1000.0
-        alerts.evaluate(now=t, force=True)  # banks the clean baseline
+        evaluate(t)  # banks the clean baseline
         if alerts.incidents():
             return False, "incident open before the injection"
         with faults.inject("step_time_anomaly", times=1) as f:
             step(x, y, batch_size=2)   # the next ingest inflates this one
             t += 5.0
-            alerts.evaluate(now=t, force=True)
+            evaluate(t)
         ok, why, inc = _assert_one_incident(alerts, "step_time_drift",
                                             want_ledger_key=True)
         if not ok:
@@ -1117,7 +1126,7 @@ def _drill_step_time_anomaly(mx, workdir):
         for _ in range(3):
             step(x, y, batch_size=2)
         t += alerts.get_rule("step_time_drift").cooldown_s + 1.0
-        alerts.evaluate(now=t, force=True)
+        evaluate(t)
         resolved = (not alerts.open_incidents()
                     and alerts.incidents()[0]["status"] == "resolved")
         ok = (f.fired == 1 and ledgered and resolved
@@ -1361,16 +1370,26 @@ def _drill_rollout_gate(mx, workdir, kind):
     window). Either way the rollout must return ``rollback``, the prior
     artifact must keep serving bit-identical answers, and a client
     hammer riding through the whole window must see ZERO errors."""
+    import itertools
     import threading
+    import types
 
     import numpy as np
 
     from mxnet_tpu import serving
     from mxnet_tpu.resilience import faults
+    from mxnet_tpu.serving import operator
 
     serving.reset_stats()
     fleet, candidate, x = _operator_fleet(mx, serving)
     gate = "health" if kind == "rollout_bad_weights" else "latency"
+    # the canary windows read a clock the drill sets (every call takes
+    # 1 ms, the fault's factor on top): on a loaded host, beside the
+    # hammer, a clean baseline window can read several times the
+    # candidate's, and the inflated p50 then passes the latency gate
+    ticks = itertools.count()
+    operator.time = types.SimpleNamespace(
+        perf_counter=lambda: next(ticks) * 1e-3)
     try:
         if not fleet.wait_healthy(timeout=20):
             return False, "fleet never became healthy"
@@ -1410,6 +1429,7 @@ def _drill_rollout_gate(mx, workdir, kind):
                     f"client_err={results['err']} "
                     f"rollbacks={s['rollout_rollbacks']}")
     finally:
+        operator.time = time
         fleet.close()
 
 

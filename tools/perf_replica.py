@@ -179,7 +179,7 @@ def build_framework(bs):
         net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
         {"learning_rate": 0.1, "momentum": 0.9}, mesh=mesh,
         dtype="bfloat16")
-    trainer._build_step()
+    trainer._build("fused")
     return trainer
 
 
