@@ -20,7 +20,10 @@ Phases (any failure -> non-zero exit, no result line):
            ledger entry with flops and bytes. Then ten eager
            gluon.Trainer.step calls of the MNIST MLP (eager donation).
   Kernels  the Pallas flash kernel (forward, backward, one ring hop with
-           a traced offset), paged decode attention (bf16 and int8 KV)
+           a traced offset), the gated delta rule's kernels (output and
+           five gradients in bf16 against float32 autodiff of the
+           ``jax.numpy`` form, beside what that form reads in bf16),
+           paged decode attention (bf16 and int8 KV)
            and the int8 conv / FC ops, compiled, against the XLA dense
            composition in f32-highest.
   Four chips (when >= 4 are visible) the Train phase again on {"dp": 4}
@@ -55,6 +58,8 @@ BF16_ATOL = 3e-2
 FULL = {"image": 224, "batch": 256, "steps": 5,
         "flash": [(2, 12, 1024, 64), (1, 4, 8192, 128)],
         "hop": (1, 4, 1024, 64),
+        # (B, T, key heads, value heads, Dk, Dv): Qwen3-Next's cell
+        "delta_rule": (1, 8192, 16, 32, 128, 128),
         "decode": {"b": 8, "h": 12, "d": 64, "page": 16, "pages": 256},
         # ResNet-18 stage-2 3x3 conv and the classifier, batch 128
         "conv": {"data": (128, 128, 28, 28), "weight": (128, 128, 3, 3)},
@@ -63,6 +68,7 @@ FULL = {"image": 224, "batch": 256, "steps": 5,
 REHEARSAL = {"image": 64, "batch": 8, "steps": 2,
              "flash": [(1, 2, 256, 64)],
              "hop": (1, 2, 128, 64),
+             "delta_rule": (1, 150, 1, 2, 128, 128),
              "decode": {"b": 2, "h": 2, "d": 32, "page": 8, "pages": 8},
              "conv": {"data": (2, 8, 8, 8), "weight": (8, 8, 3, 3)},
              "fc": {"data": (4, 32), "weight": (16, 32)},
@@ -309,6 +315,78 @@ def max_err(a, b):
                                  - b.astype(jnp.float32))))
 
 
+def delta_rule_check(shape, interpret, tag):
+    """The gated delta rule's kernels in bf16, output and all five
+    gradients, against float32 autodiff of the ``jax.numpy`` chunked
+    form at highest precision: largest difference over the largest
+    entry, beside what the ``jax.numpy`` form itself reads in bf16 (the
+    scan the kernels replace on the chip)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops.delta_rule_kernels import gated_delta_rule_kernels
+    from mxnet_tpu.ops.linear_attention import _chunked_delta_rule
+
+    b, t, hk, hv, dk, dv = shape
+    rs = np.random.RandomState(3)
+
+    def draw(*dims):
+        return jnp.asarray(rs.randn(*dims), jnp.float32)
+
+    # as the mixer hands them over: SiLU outputs, decays by head
+    q, k, v = (jax.nn.silu(draw(b, t, h, d))
+               for h, d in ((hk, dk), (hk, dk), (hv, dv)))
+    g = -jnp.asarray(rs.uniform(1e-6, 16, hv), jnp.float32) \
+        * jax.nn.softplus(draw(b, t, hv) + 1.0)
+    exact = (q, k, v, g, jax.nn.sigmoid(draw(b, t, hv)))
+    half = tuple(x.astype(jnp.bfloat16) for x in exact[:3]) + exact[3:]
+    weight = draw(b, t, hv, dv)
+
+    def both(fn):
+        def loss(*a):
+            out = fn(*a)
+            return jnp.sum(out.astype(jnp.float32) * weight), out
+
+        def run(*a):
+            (_, out), grads = jax.value_and_grad(
+                loss, argnums=(0, 1, 2, 3, 4), has_aux=True)(*a)
+            return (out,) + grads
+
+        return jax.jit(run)
+
+    def scan(*a):
+        return _chunked_delta_rule(*a, 64)
+
+    def kernels(*a):
+        return gated_delta_rule_kernels(*a, interpret=interpret)
+
+    with jax.default_matmul_precision("highest"):
+        want = both(scan)(*exact)
+    t0 = time.perf_counter()
+    got = jax.block_until_ready(both(kernels)(*half))
+    dt = time.perf_counter() - t0
+    names = ("o", "dq", "dk", "dv", "dg", "dbeta")
+    errs = {}
+
+    def rms(x):
+        return float(jnp.sqrt(jnp.mean(jnp.square(x.astype(jnp.float32)))))
+
+    for label, result in (("kernels", got), ("scan", both(scan)(*half))):
+        errs[label] = [max_err(x, w) / float(jnp.max(jnp.abs(w)))
+                       for x, w in zip(result, want)]
+        spread = [rms(x.astype(jnp.float32) - w) / rms(w)
+                  for x, w in zip(result, want)]
+        log(f"{tag} gated delta rule {shape} bf16, {label} against float32 "
+            "autodiff, largest err/largest entry (rms err/rms): " + " ".join(
+                f"{n} {e:.4f} ({r:.4f})"
+                for n, e, r in zip(names, errs[label], spread)))
+    log(f"{tag} gated delta rule kernels compile+run {dt:.1f} s")
+    check(max(errs["kernels"]) <= BF16_ATOL,
+          f"gated delta rule kernels {shape} outside bf16 tolerance: "
+          f"{errs['kernels']}")
+
+
 def kernels_phase(sz, interpret, tag):
     import jax
     import jax.numpy as jnp
@@ -370,6 +448,8 @@ def kernels_phase(sz, interpret, tag):
             f"err {err:.4f}")
         check(err <= BF16_ATOL and bool(jnp.all(jnp.isfinite(lse))),
               f"ring hop q_offset={qo} k_offset={ko}: err {err}")
+
+    delta_rule_check(sz["delta_rule"], interpret, tag)
 
     b_, h_, t_, d_ = sz["flash"][0]
     blocks = tune.schedule.flash_fwd_blocks(b_ * h_, t_, d_, "bfloat16",

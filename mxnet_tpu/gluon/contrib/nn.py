@@ -361,7 +361,9 @@ class GatedDeltaNet(HybridBlock):
     [q, k, v] pass a causal depthwise convolution of ``conv_kernel``
     taps without bias, then SiLU; ``beta = sigmoid(b)``,
     ``g = -exp(A_log) * softplus(a + dt_bias)`` in float32; the chunked
-    delta rule (``ops/linear_attention.py``); a gated RMSNorm per value
+    delta rule (``ops/linear_attention.py``: its Pallas kernels on a
+    TPU where both head sizes are multiples of 128, ``jax.numpy``
+    elsewhere, the same result); a gated RMSNorm per value
     head with z; ``out_proj``. All but the four projections sits under
     the scope ``linear_attention``. x (B, T, units)."""
 

@@ -13,9 +13,19 @@ for each token, a decay ``g_t <= 0`` and a write strength ``beta_t``:
 the decays are a mask ``exp(cumsum g)``, the chunk's writes are the WY
 factors of a unit lower-triangular solve, and the state moves once a
 chunk, so a sequence of T tokens is T / chunk sequential steps of
-matrix products instead of T rank-one updates. Everything is
-``jax.numpy`` under one ``lax.scan``: differentiable as it stands, the
-backward pass is the scan's own.
+matrix products instead of T rank-one updates.
+
+One algorithm, two implementations, chosen by what the code can see.
+On a TPU, at head sizes on the lane grid
+(``tune.schedule.delta_rule_shape_supported``), it runs as the Pallas
+kernels ``gated_delta_rule_fwd`` and ``gated_delta_rule_bwd``
+(``ops/delta_rule_kernels.py``): the state and every chunk's tensors
+stay in VMEM, the triangular system is inverted by products, and the
+backward pass is written, under one ``jax.custom_vjp``. Everywhere else
+(the CPU, other head sizes) it is ``jax.numpy`` under one ``lax.scan``
+(:func:`_chunked_delta_rule`), differentiable as it stands with the
+scan's own backward pass; that form is also the kernels' oracle in the
+tests, which run them in interpret mode.
 """
 from __future__ import annotations
 
@@ -47,6 +57,22 @@ def _chunked(x, n, c, hk):
     return jnp.moveaxis(x, (3, 4), (1, 2))
 
 
+def _kernels_take(v, dk, chunk):
+    """Whether this call runs as the Pallas kernels: its computation
+    lands on a TPU (where ``v`` lives; a tracer has no device and lands
+    on jax's default backend, as ``parallel.ring_attention`` reads it)
+    and the shape has a legal schedule."""
+    from ..tune import schedule
+    from .pallas_kernels import pallas_available
+
+    if isinstance(v, jax.core.Tracer):
+        on_tpu = pallas_available()
+    else:
+        on_tpu = next(iter(v.devices())).platform == "tpu"
+    return on_tpu and schedule.delta_rule_shape_supported(
+        dk, v.shape[-1], chunk)
+
+
 @register("gated_delta_rule")
 def gated_delta_rule(q, k, v, g, beta, chunk=64):
     """``q``, ``k`` (B, T, Hk, Dk), ``v`` (B, T, Hv, Dv), ``g`` and
@@ -58,7 +84,18 @@ def gated_delta_rule(q, k, v, g, beta, chunk=64):
     the solve, the state and every accumulation are float32; the
     operands of the products that do not come out of the solve keep
     ``v``'s dtype (under a bf16 policy that is what the MXU takes of
-    them anyway)."""
+    them anyway). Kernels or ``jax.numpy``: the module's docstring."""
+    q, k, v, g, beta = (jnp.asarray(x) for x in (q, k, v, g, beta))
+    if _kernels_take(v, k.shape[-1], chunk):
+        from .delta_rule_kernels import gated_delta_rule_kernels
+
+        return gated_delta_rule_kernels(q, k, v, g, beta, chunk=chunk)
+    return _chunked_delta_rule(q, k, v, g, beta, chunk)
+
+
+def _chunked_delta_rule(q, k, v, g, beta, chunk):
+    """:func:`gated_delta_rule` in ``jax.numpy`` under one ``lax.scan``
+    over the chunks."""
     f32 = jnp.float32
     b, t, hv, dv = v.shape
     hk, dk = k.shape[2:]

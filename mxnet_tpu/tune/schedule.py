@@ -72,6 +72,12 @@ LANES = 128
 FLASH_VMEM_BUDGET = 12 * 2 ** 20
 FLASH_VMEM_CEILING = 48 * 2 ** 20
 DECODE_PAGE_BLOCK_CANDIDATES = (16, 8, 4, 2, 1)
+# Chunks of the gated delta rule one grid step of its kernels takes
+# (ops/delta_rule_kernels.py): the chunks of a step are unrolled, so the
+# compiler runs the part of a later chunk that does not read the state
+# (its products and its triangular inverse) beside the earlier chunk's
+# state update; more of them is more code, not more work.
+DELTA_RULE_CHUNK_CANDIDATES = (16, 8, 4, 2, 1)
 SEARCH_SPACE = {
     # Pallas streaming flash-attention forward (ops/pallas_kernels.py);
     # also the ring-attention per-hop kernel, keyed at the hop's local
@@ -95,12 +101,17 @@ SEARCH_SPACE = {
     # (decode batch, pages-per-sequence) shape — the one-token-per-
     # sequence serving hot path (serving/decode.py)
     "decode_attn": {"block_pages": DECODE_PAGE_BLOCK_CANDIDATES},
+    # the gated delta rule's forward and backward kernels
+    # (ops/delta_rule_kernels.py): chunks a grid step, keyed per
+    # (value heads, T, Dk, Dv) shape
+    "delta_rule_fwd": {"chunks": DELTA_RULE_CHUNK_CANDIDATES},
+    "delta_rule_bwd": {"chunks": DELTA_RULE_CHUNK_CANDIDATES},
 }
 
 # What a kernel runs when the table has no entry (block sizes are
-# legalized down to the shape). The flash forward's and backward's are
-# chip-measured (PERF.md, PRs 28 and 31); the others are the
-# hand-written pre-autotune constants.
+# legalized down to the shape). The flash forward's and backward's and
+# the gated delta rule's are chip-measured (PERF.md, PRs 28, 31 and
+# 33); the others are the hand-written pre-autotune constants.
 DEFAULT_SCHEDULES = {
     "flash_fwd": {"block_q": 512, "block_k": 512},
     "flash_bwd": {"block_q": 512, "block_k": 512},
@@ -108,6 +119,8 @@ DEFAULT_SCHEDULES = {
     "int8_conv": {"operand_width": "int8"},
     "int8_requant": {"path": "via_fp32"},
     "decode_attn": {"block_pages": 8},
+    "delta_rule_fwd": {"chunks": 8},
+    "delta_rule_bwd": {"chunks": 4},
 }
 
 _LOCK = threading.Lock()
@@ -322,6 +335,12 @@ def int8_requant_shape_key(rows, cols):
     return f"r{int(rows)}-c{int(cols)}"
 
 
+def delta_rule_shape_key(bh, t, dk, dv):
+    """Gated delta rule table key: value heads over the batch, tokens,
+    key and value head sizes."""
+    return f"bh{int(bh)}-t{int(t)}-dk{int(dk)}-dv{int(dv)}"
+
+
 def decode_shape_key(batch, pages):
     """Paged decode attention table key: the fixed decode-batch width
     and the per-sequence page-table width (kv capacity in pages)."""
@@ -512,6 +531,81 @@ def flash_bwd_heads(bh, bq, bk, rows, d, itemsize):
     a window of ``rows`` q rows each."""
     return _heads(bh, bq, bk, lambda hb: flash_bwd_vmem_bytes(
         hb, bq, bk, rows, d, itemsize))
+
+
+# ------------------------------------------ gated-delta-rule resolution
+
+def delta_rule_shape_supported(dk, dv, chunk):
+    """Whether the gated delta rule's kernels take the shape: a head is
+    a column block of the (B, T, heads x size) projections, so both
+    head sizes lie on the lane grid, and a chunk's rows on the sublane
+    grid of a 16-bit operand (two :data:`MIN_SUBLANE`). What
+    ``ops.linear_attention.gated_delta_rule`` asks before it takes the
+    kernels; everything else runs its ``jax.numpy`` form."""
+    return int(dk) > 0 and int(dv) > 0 and int(dk) % LANES == 0 \
+        and int(dv) % LANES == 0 and int(chunk) > 0 \
+        and int(chunk) % (2 * MIN_SUBLANE) == 0
+
+
+def delta_rule_chunks(kernel, bh, t, n_chunks, dk, dv, dtype,
+                      interpret=False, chunks=None):
+    """Chunks one grid step of ``kernel`` (``delta_rule_fwd`` /
+    ``delta_rule_bwd``) takes: the caller's ``chunks`` (the search
+    driver's candidates), else the table's, else the default's,
+    legalized to the largest divisor of the sequence's ``n_chunks`` that
+    is no larger (a step holds whole chunks and the grid whole steps;
+    one chunk a step is always legal)."""
+    if chunks is None:
+        chunks = kernel_schedule(
+            kernel, delta_rule_shape_key(bh, t, dk, dv), str(dtype),
+            resolve_backend(interpret))["chunks"]
+    nb = max(1, min(int(chunks), int(n_chunks)))
+    while int(n_chunks) % nb:
+        nb -= 1
+    return nb
+
+
+def delta_rule_heads(rep, chunk):
+    """Value heads of one key head that share a chunk's tiles in the
+    gated-delta-rule kernels: as many of the ``rep`` heads the key head
+    serves (a divisor of it) as fill the MXU's :data:`LANES` rows with
+    their chunks, block diagonal by head. Two at chunks of 64: every
+    product of the chunk program, the triangular inverse's ten first,
+    then serves both heads."""
+    heads = max(1, min(int(rep), LANES // int(chunk)))
+    while int(rep) % heads:
+        heads -= 1
+    return heads
+
+
+def delta_rule_vmem_bytes(kernel, nb, chunk, rep, dk, dv, itemsize):
+    """What one grid step of a gated-delta-rule kernel holds in VMEM,
+    from its shapes: the q / k and v / o row blocks of ``nb`` chunks
+    (the backward's dout and dq / dk / dv beside them), the entering
+    state of every chunk and value head and the triangular inverse of
+    every chunk and group of heads where the forward saves them or the
+    backward reads them (all double-buffered), the float32 state
+    scratch, and the float32 copies the chunk programs make of a
+    chunk's rows and its (heads x chunk) square tiles, all ``nb``
+    chunks' live at once since they are unrolled."""
+    rows = nb * chunk
+    wide = delta_rule_heads(rep, chunk) * chunk
+    tiles = rep * chunk * _pad(wide, LANES)     # a tile a group of heads
+    kv = rows * (2 * dk + 2 * rep * dv) * itemsize
+    saved = nb * (rep * dk * dv + tiles) * 4
+    state = rep * dk * dv * 4
+    work = rows * (2 * dk + rep * (2 * dk + 3 * dv)) * 4 + nb * 8 * tiles * 4
+    if kernel == "delta_rule_bwd":
+        kv = 2 * kv + rows * rep * dv * itemsize
+        work *= 2
+    return 2 * (kv + saved) + state + work
+
+
+def delta_rule_vmem_limit(kernel, nb, chunk, rep, dk, dv, itemsize):
+    """A gated-delta-rule kernel's ``vmem_limit_bytes``
+    (:func:`_vmem_limit`)."""
+    return _vmem_limit(delta_rule_vmem_bytes(kernel, nb, chunk, rep, dk,
+                                             dv, itemsize))
 
 
 def decode_attn_block_pages(batch, pages, dtype, interpret=False,

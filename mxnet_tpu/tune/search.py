@@ -24,13 +24,14 @@ synthetic ones to drive the gate logic.
 """
 from __future__ import annotations
 
+import functools
 import time
 
 from . import _STATS, measure, schedule
 
 __all__ = ["Workload", "run_search", "flash_fwd_workload",
-           "flash_bwd_workload", "int8_fc_workload", "int8_conv_workload",
-           "int8_requant_workload"]
+           "flash_bwd_workload", "delta_rule_workload", "int8_fc_workload",
+           "int8_conv_workload", "int8_requant_workload"]
 
 
 class Workload:
@@ -231,6 +232,57 @@ def flash_bwd_workload(b=2, h=1, t=256, d=32, causal=True, interpret=False,
         _flash_block_pairs(t, quick=quick, min_block=min_block,
                            grain=schedule.LANES),
         label=label or "flash_bwd", reference=ref)
+
+
+def delta_rule_workload(kernel="delta_rule_fwd", b=1, t=256, hk=1, hv=2,
+                        dk=128, dv=128, chunk=64, interpret=False, seed=11,
+                        quick=False, label=None, dtype="float32"):
+    """Gated-delta-rule sweep at one shape and dtype: chunks a grid
+    step of the forward kernel alone (``delta_rule_fwd``), or of the
+    backward kernel (``delta_rule_bwd``) on what the forward under its
+    own schedule saves and the cotangent of a sum of squares. Inputs as
+    the mixer hands them over: SiLU outputs, decays of a few tenths to a
+    few units a token, write strengths in (0, 1)."""
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+
+    rs = np.random.RandomState(seed)
+    q, k, v = (jnp.asarray(jax.nn.silu(rs.randn(b, t, h, d).astype(
+        np.float32)), dtype) for h, d in ((hk, dk), (hk, dk), (hv, dv)))
+    g = jnp.asarray(-rs.uniform(0.0, 4.0, (b, t, hv)), jnp.float32)
+    beta = jnp.asarray(rs.uniform(0.0, 1.0, (b, t, hv)), jnp.float32)
+    back = kernel == "delta_rule_bwd"
+
+    def build(sched):
+        from ..ops.delta_rule_kernels import gated_delta_rule_kernels
+
+        def run(*args, **chunks):
+            return gated_delta_rule_kernels(*args, chunk=chunk,
+                                            interpret=interpret, **chunks)
+
+        if not back:
+            return jax.jit(functools.partial(
+                run, chunks=sched["chunks"])), (q, k, v, g, beta)
+        out, vjp = jax.vjp(functools.partial(
+            run, bwd_chunks=sched["chunks"]), q, k, v, g, beta)
+        return jax.jit(vjp), (2 * out,)
+
+    n = -(-t // chunk)
+    space = sorted({schedule.delta_rule_chunks(
+        kernel, b * hv, t, n, dk, dv, dtype, chunks=c)
+        for c in schedule.SEARCH_SPACE[kernel]["chunks"]}, reverse=True)
+    if quick:
+        space = space[:2]
+    ref = schedule.delta_rule_chunks(
+        kernel, b * hv, t, n, dk, dv, dtype,
+        chunks=schedule.DEFAULT_SCHEDULES[kernel]["chunks"])
+    return Workload(
+        kernel, schedule.delta_rule_shape_key(b * hv, t, dk, dv),
+        str(dtype), schedule.resolve_backend(interpret), build,
+        [{"chunks": c} for c in space], label=label or kernel,
+        reference={"chunks": ref})
 
 
 def decode_attn_workload(b=4, pages=8, page_size=16, h=2, d=32, seed=9,

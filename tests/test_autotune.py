@@ -248,6 +248,116 @@ def test_committed_tpu_entries_resolve_for_the_training_cells(
     assert tune.stats()["autotune_table_misses"] == 1
 
 
+# (value heads over the batch, T, chunks of the sequence, Dk, Dv, dtype,
+# itemsize, key heads' group): the Qwen3-Next cell's, sequences of a
+# prime number of chunks and of one, wide heads in float32
+@pytest.mark.parametrize("bh,t,n,dk,dv,dtype,itemsize,rep", [
+    (32, 8192, 128, 128, 128, "bfloat16", 2, 2),
+    (4, 150, 3, 128, 256, "float32", 4, 2),
+    (2, 64, 1, 128, 128, "bfloat16", 2, 1),
+    (8, 832, 13, 256, 256, "float32", 4, 4),
+    (16, 65536, 1024, 128, 128, "bfloat16", 2, 1)])
+@pytest.mark.parametrize("kernel", ["delta_rule_fwd", "delta_rule_bwd"])
+def test_default_delta_rule_schedule_is_legal_by_shape(
+        kernel, bh, t, n, dk, dv, dtype, itemsize, rep, monkeypatch):
+    """Chunks a grid step follow from what the builder sees: the
+    default's, cut to a divisor of the sequence's chunks, a step that
+    fits VMEM under the limit the kernel asks for; the caller's override
+    is legalized the same way."""
+    monkeypatch.setenv("MXNET_TPU_AUTOTUNE", "0")   # no table: the default
+    assert schedule.delta_rule_shape_supported(dk, dv, 64)
+    nb = schedule.delta_rule_chunks(kernel, bh, t, n, dk, dv, dtype,
+                                    interpret=True)
+    want = schedule.DEFAULT_SCHEDULES[kernel]["chunks"]
+    assert 1 <= nb <= want and n % nb == 0
+    assert nb == max(c for c in range(1, want + 1) if n % c == 0)
+    need = schedule.delta_rule_vmem_bytes(kernel, nb, 64, rep, dk, dv,
+                                          itemsize)
+    limit = schedule.delta_rule_vmem_limit(kernel, nb, 64, rep, dk, dv,
+                                           itemsize)
+    if limit is None:
+        assert need <= schedule.FLASH_VMEM_BUDGET
+    else:
+        assert need < limit <= schedule.FLASH_VMEM_CEILING
+    for asked in schedule.SEARCH_SPACE[kernel]["chunks"]:
+        got = schedule.delta_rule_chunks(kernel, bh, t, n, dk, dv, dtype,
+                                         chunks=asked)
+        assert 1 <= got <= asked and n % got == 0
+    assert schedule.delta_rule_chunks(kernel, bh, t, n, dk, dv, dtype,
+                                      chunks=10 ** 6) == n
+
+
+@pytest.mark.parametrize("dk,dv,chunk,ok", [
+    (128, 128, 64, True), (256, 128, 32, True), (128, 384, 16, True),
+    (64, 128, 64, False), (128, 8, 64, False), (192, 128, 64, False),
+    (128, 128, 40, False), (128, 128, 8, False), (0, 128, 64, False)])
+def test_delta_rule_shape_gate(dk, dv, chunk, ok):
+    """Heads on the lane grid, a chunk's rows on a 16-bit operand's
+    sublane grid: what ``gated_delta_rule`` asks before the kernels."""
+    assert schedule.delta_rule_shape_supported(dk, dv, chunk) is ok
+
+
+@pytest.mark.parametrize("kernel", ["delta_rule_fwd", "delta_rule_bwd"])
+def test_delta_rule_workload_and_table_entry_steer_the_kernels(
+        kernel, tmp_path, monkeypatch):
+    """``delta_rule_workload`` times one kernel alone at its shape and
+    dtype over the chunks a grid step may take, and the entry it writes
+    is the one the builder's lookup hits."""
+    import jax.numpy as jnp
+
+    wl = search.delta_rule_workload(kernel, b=1, t=256, hk=1, hv=2,
+                                    interpret=True, dtype="bfloat16")
+    assert wl.kernel == kernel and wl.dtype == "bfloat16"
+    assert wl.shape_key == "bh2-t256-dk128-dv128"
+    # four chunks: a step takes all, two or one of them
+    assert wl.candidates() == [{"chunks": 4}, {"chunks": 2}, {"chunks": 1}]
+    assert wl.reference() == {"chunks": min(
+        4, schedule.DEFAULT_SCHEDULES[kernel]["chunks"])}
+    fn, args = wl.build(wl.reference())
+    if kernel == "delta_rule_fwd":
+        assert [a.dtype for a in args] == [jnp.bfloat16] * 3 \
+            + [jnp.float32] * 2
+        assert fn(*args).shape == (1, 256, 2, 128)
+    else:
+        assert [a.shape for a in fn(*args)] == [
+            (1, 256, 1, 128)] * 2 + [(1, 256, 2, 128)] + [(1, 256, 2)] * 2
+    quick = search.delta_rule_workload(kernel, b=1, t=256, hk=1, hv=2,
+                                       interpret=True, quick=True)
+    tbl = str(tmp_path / "t.json")
+    res = search.run_search(quick, tbl, rounds=1, iters=1)
+    assert res["key"] == f"{kernel}|interpret|float32|bh2-t256-dk128-dv128"
+    assert res["rejected"] == 0 and res["candidates"] == 2
+    won = schedule.load_single_table(tbl)[res["key"]]["schedule"]
+    assert set(won) == {"chunks"}
+    assert schedule.validate_table(json.load(open(tbl))) == []
+    monkeypatch.setenv("MXNET_TPU_SCHEDULE_TABLE", tbl)
+    tune.reset_stats()
+    assert schedule.delta_rule_chunks(kernel, 2, 256, 4, 128, 128,
+                                      "float32", interpret=True) \
+        == won["chunks"]
+    assert tune.stats()["autotune_table_hits"] == 1
+
+
+def test_autotune_production_sweep_names_the_delta_rule_cell():
+    """``tools/autotune.py`` outside the demo sweeps both kernels at the
+    Qwen3-Next cell's shape in bfloat16 (built lazily: nothing runs)."""
+    spec = importlib.util.spec_from_file_location(
+        "autotune_tool", os.path.join(ROOT, "tools", "autotune.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    found = {wl.label: wl for wl in tool.build_workloads(
+        quick=False, interpret=True) if wl.kernel.startswith("delta_rule")}
+    assert sorted(found) == ["qwen3next_delta_rule_bwd",
+                             "qwen3next_delta_rule_fwd"]
+    for wl in found.values():
+        assert wl.shape_key == "bh32-t8192-dk128-dv128"
+        assert wl.dtype == "bfloat16"
+        assert [c["chunks"] for c in wl.candidates()] == [16, 8, 4, 2, 1]
+    assert not any(wl.kernel.startswith("delta_rule")
+                   for wl in tool.build_workloads(quick=True,
+                                                  interpret=True))
+
+
 def test_flash_fwd_workload_is_keyed_by_its_dtype(tmp_path):
     """The workload builds inputs of the dtype it is given and keys the
     entry by it; bf16 candidates pass the gate at bf16's rounding."""

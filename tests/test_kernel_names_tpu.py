@@ -123,6 +123,65 @@ def test_flash_backward_is_named_and_compiles_at_real_widths(
     assert "while" not in compiled      # no scan is left in the backward
 
 
+def _through_delta_rule(*args):
+    """The gated delta rule under the ``linear_attention`` scope, as
+    ``GatedDeltaNet`` calls it, straight through the kernels (the
+    dispatch of ``gated_delta_rule`` asks jax's default backend, which
+    is the CPU here)."""
+    from mxnet_tpu.ops import delta_rule_kernels
+
+    with jax.named_scope("linear_attention"):
+        return delta_rule_kernels.gated_delta_rule_kernels(*args)
+
+
+# (B, T, key heads, value heads, Dk, Dv), dtype: the Qwen3-Next cell's
+# linear attention, a sequence off the chunk grid with heads of two
+# sizes in float32, one key head a value head
+_DELTA_RULE_SHAPES = [((1, 8192, 16, 32, 128, 128), jnp.bfloat16),
+                      ((2, 200, 2, 4, 128, 256), jnp.float32),
+                      ((1, 520, 2, 2, 256, 128), jnp.bfloat16)]
+
+
+@pytest.mark.parametrize("shape,dtype", _DELTA_RULE_SHAPES)
+def test_delta_rule_kernels_are_named_and_compile_at_real_widths(
+        one_chip, shape, dtype):
+    """Forward alone: one kernel, ``gated_delta_rule_fwd``. Under
+    ``jax.grad``: the forward that saves the entering states under the
+    forward half of the ``linear_attention`` scope and
+    ``gated_delta_rule_bwd`` under the backward half (what
+    ``linear_attention_*_ms_per_step`` join on), Mosaic takes both tile
+    programs at these widths, and neither the scan nor the triangular
+    solve of the ``jax.numpy`` form is left."""
+    b, t, hk, hv, dk, dv = shape
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((b, t, hk, dk), dtype), ((b, t, hk, dk), dtype),
+        ((b, t, hv, dv), dtype), ((b, t, hv), jnp.float32),
+        ((b, t, hv), jnp.float32))]
+    forward = jax.jit(_through_delta_rule).lower(*args)
+    text = forward.as_text(debug_info=True)
+    assert 'kernel_name = "gated_delta_rule_fwd"' in text
+    assert "linear_attention/gated_delta_rule_fwd/pallas_call" in text
+    compiled = forward.compile().as_text()
+    assert compiled.count("tpu_custom_call") >= 1
+    assert "while" not in compiled and "triangular" not in compiled
+
+    lowered = jax.jit(jax.grad(
+        lambda *a: jnp.sum(_through_delta_rule(*a).astype(jnp.float32)),
+        argnums=(0, 1, 2, 3, 4))).lower(*args)
+    text = lowered.as_text(debug_info=True)
+    for kernel, half in (("gated_delta_rule_fwd", "jvp(linear_attention)"),
+                         ("gated_delta_rule_bwd",
+                          "transpose(jvp(linear_attention))")):
+        assert f'kernel_name = "{kernel}"' in text
+        named = [line for line in text.splitlines()
+                 if f"{kernel}/pallas_call" in line]
+        assert named and all(f"{half}/{kernel}" in line for line in named), \
+            named[:2]
+    compiled = lowered.compile().as_text()
+    assert compiled.count("tpu_custom_call") >= 2
+    assert "while" not in compiled and "triangular" not in compiled
+
+
 def test_no_pallas_call_in_the_package_is_left_unnamed():
     """A kernel added later is named the same way (docs/observability.md,
     "The program's own names")."""

@@ -69,7 +69,9 @@ def build_workloads(quick, interpret=False):
     # The backward kernel at the same shape, and at the Qwen3-Next
     # cell's (configs/qwen3-next-80b-a3b.json under train_seq8192_bs1:
     # one row, 16 query heads after the K/V repeat, 8192 positions, head
-    # 256): the ``flash_bwd|tpu|bfloat16|...`` entries
+    # 256): the ``flash_bwd|tpu|bfloat16|...`` entries. That cell's
+    # gated delta rule (one row, 16 key / 32 value heads of 128, 8192
+    # positions, chunks of 64), each kernel alone
     production = [] if quick else [
         search.flash_fwd_workload(b=8, h=16, t=1024, d=64, causal=True,
                                   dtype="bfloat16", min_block=128,
@@ -82,7 +84,12 @@ def build_workloads(quick, interpret=False):
         search.flash_bwd_workload(b=1, h=16, t=8192, d=256, causal=True,
                                   dtype="bfloat16", min_block=256,
                                   interpret=interpret,
-                                  label="qwen3next_train_bwd")]
+                                  label="qwen3next_train_bwd")] + [
+        search.delta_rule_workload(kernel, b=1, t=8192, hk=16, hv=32,
+                                   dk=128, dv=128, dtype="bfloat16",
+                                   interpret=interpret,
+                                   label="qwen3next_" + kernel)
+        for kernel in ("delta_rule_fwd", "delta_rule_bwd")]
     return production + [
         search.flash_fwd_workload(b=2, h=1, t=256, d=32, causal=True,
                                   **kw, label="flash_fwd"),
