@@ -58,6 +58,8 @@ BF16_ATOL = 3e-2
 FULL = {"image": 224, "batch": 256, "steps": 5,
         "flash": [(2, 12, 1024, 64), (1, 4, 8192, 128)],
         "hop": (1, 4, 1024, 64),
+        # ((B, H, T, D), window): a window layer of Trinity-Mini's cell
+        "window": ((1, 32, 8192, 128), 2048),
         # (B, T, key heads, value heads, Dk, Dv): Qwen3-Next's cell
         "delta_rule": (1, 8192, 16, 32, 128, 128),
         "decode": {"b": 8, "h": 12, "d": 64, "page": 16, "pages": 256},
@@ -68,6 +70,7 @@ FULL = {"image": 224, "batch": 256, "steps": 5,
 REHEARSAL = {"image": 64, "batch": 8, "steps": 2,
              "flash": [(1, 2, 256, 64)],
              "hop": (1, 2, 128, 64),
+             "window": ((1, 2, 256, 64), 100),
              "delta_rule": (1, 150, 1, 2, 128, 128),
              "decode": {"b": 2, "h": 2, "d": 32, "page": 8, "pages": 8},
              "conv": {"data": (2, 8, 8, 8), "weight": (8, 8, 3, 3)},
@@ -292,7 +295,7 @@ def eager_phase(kvstore, tag):
 
 # ----------------------------------------------------------------- Kernels
 
-def dense_attention(q, k, v, causal, q_off=0, k_off=0):
+def dense_attention(q, k, v, causal, q_off=0, k_off=0, window=None):
     """The XLA dense composition in f32-highest — the reference."""
     import jax
     import jax.numpy as jnp
@@ -302,9 +305,12 @@ def dense_attention(q, k, v, causal, q_off=0, k_off=0):
         q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
         s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
         if causal:
-            qpos = q_off + jnp.arange(q.shape[2])
-            kpos = k_off + jnp.arange(k.shape[2])
-            s = jnp.where(qpos[:, None] >= kpos[None, :], s, -1e30)
+            ahead = (q_off + jnp.arange(q.shape[2]))[:, None] \
+                - (k_off + jnp.arange(k.shape[2]))[None, :]
+            seen = ahead >= 0
+            if window is not None:
+                seen &= ahead < window
+            s = jnp.where(seen, s, -1e30)
         return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
 
 
@@ -313,6 +319,58 @@ def max_err(a, b):
 
     return float(jnp.max(jnp.abs(a.astype(jnp.float32)
                                  - b.astype(jnp.float32))))
+
+
+def window_check(shape, window, interpret, tag):
+    """The flash kernels under a window in bf16, output and dq / dk /
+    dv, against float32 autodiff of the dense masked softmax at highest
+    precision, four heads at a time so that the dense scores fit
+    (heads are independent under a loss that is a sum over them):
+    largest difference over the largest entry."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops.pallas_kernels import flash_attention_with_grad
+
+    rs = np.random.RandomState(5)
+    q, k, v = (jnp.asarray(rs.randn(*shape) * 0.5, jnp.bfloat16)
+               for _ in range(3))
+    weight = jnp.asarray(rs.randn(*shape), jnp.float32)
+
+    def both(fn):
+        def run(q, k, v, w):
+            def loss(q, k, v):
+                out = fn(q, k, v)
+                return jnp.sum(out.astype(jnp.float32) * w), out
+
+            (_, out), grads = jax.value_and_grad(
+                loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+            return (out,) + grads
+
+        return jax.jit(run)
+
+    t0 = time.perf_counter()
+    got = jax.block_until_ready(both(
+        lambda q, k, v: flash_attention_with_grad(
+            q, k, v, causal=True, window=window, interpret=interpret))(
+                q, k, v, weight))
+    dt = time.perf_counter() - t0
+    dense = both(lambda q, k, v: dense_attention(q, k, v, True,
+                                                 window=window))
+    errs = [0.0] * 4
+    for h in range(0, shape[1], 4):
+        want = dense(*(x[:, h:h + 4] for x in (q, k, v, weight)))
+        for i, (x, w) in enumerate(zip(got, want)):
+            errs[i] = max(errs[i], max_err(x[:, h:h + 4], w)
+                          / max(1.0, float(jnp.max(jnp.abs(w)))))
+    log(f"{tag} flash fwd+bwd {shape} bf16 window {window}, against "
+        "float32 dense masked autodiff, largest err/scale: " + " ".join(
+            f"{n} {e:.4f}" for n, e in zip(("o", "dq", "dk", "dv"), errs))
+        + f" (tol {BF16_ATOL}); compile+run {dt:.1f} s")
+    check(max(errs) <= BF16_ATOL,
+          f"flash under window {window} at {shape} outside bf16 "
+          f"tolerance: {errs}")
 
 
 def delta_rule_check(shape, interpret, tag):
@@ -449,6 +507,7 @@ def kernels_phase(sz, interpret, tag):
         check(err <= BF16_ATOL and bool(jnp.all(jnp.isfinite(lse))),
               f"ring hop q_offset={qo} k_offset={ko}: err {err}")
 
+    window_check(*sz["window"], interpret, tag)
     delta_rule_check(sz["delta_rule"], interpret, tag)
 
     b_, h_, t_, d_ = sz["flash"][0]

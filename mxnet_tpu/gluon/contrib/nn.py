@@ -300,11 +300,21 @@ class GatedAttention(HybridBlock):
     the ``num_kv_heads`` K/V heads serves ``num_heads / num_kv_heads``
     query heads; the result times ``sigmoid(gate)``; ``out_proj``. No
     biases; ``head_dim`` is free of ``units / num_heads``. ``impl`` as
-    MultiHeadAttention's 'dense' / 'flash'. x (B, T, units)."""
+    MultiHeadAttention's 'dense' / 'flash'. x (B, T, units).
+
+    As Trinity's (``afmoe``) layers have it: ``window`` lets a query see
+    the keys ``0 <= i - j < window`` only (the kernels skip the tiles
+    behind it), under the scope ``window_attention`` inside
+    ``attention``; ``rotary_dim=0`` rotates nothing (its full-attention
+    layers carry no positions); ``zero_centered_norm=False`` takes the
+    plain RMSNorm, its weight trained from one; ``gate_proj=True`` gives
+    the gate a projection of its own beside a ``q_proj`` of queries
+    alone."""
 
     def __init__(self, units, num_heads, num_kv_heads, head_dim,
                  rotary_dim=None, rope_theta=10000.0, epsilon=1e-6,
-                 impl="dense", **kwargs):
+                 impl="dense", window=None, zero_centered_norm=True,
+                 gate_proj=False, **kwargs):
         super().__init__(**kwargs)
         if num_heads % num_kv_heads:
             raise ValueError(f"num_heads {num_heads} not divisible by "
@@ -315,38 +325,62 @@ class GatedAttention(HybridBlock):
         self._rotary = {"rotary_dim": head_dim if rotary_dim is None
                         else int(rotary_dim), "theta": float(rope_theta)}
         self._impl = impl
+        self._window = None if window is None else int(window)
+
+        def head_norm(prefix):
+            return _nn.RMSNorm(head_dim, epsilon,
+                               zero_centered=zero_centered_norm,
+                               prefix=prefix)
+
         with self.name_scope():
-            self.q_proj = _dense(num_heads * 2 * head_dim, units, "q_")
+            self.q_proj = _dense(
+                num_heads * head_dim * (1 if gate_proj else 2), units, "q_")
             self.k_proj = _dense(num_kv_heads * head_dim, units, "k_")
             self.v_proj = _dense(num_kv_heads * head_dim, units, "v_")
-            self.q_norm = _nn.RMSNorm(head_dim, epsilon, zero_centered=True,
-                                      prefix="qnorm_")
-            self.k_norm = _nn.RMSNorm(head_dim, epsilon, zero_centered=True,
-                                      prefix="knorm_")
+            self.gate_proj = _dense(num_heads * head_dim, units, "gate_") \
+                if gate_proj else None
+            self.q_norm = head_norm("qnorm_")
+            self.k_norm = head_norm("knorm_")
             self.out_proj = _dense(units, num_heads * head_dim, "out_")
 
     def hybrid_forward(self, F, x):
         b, t = x.shape[0], x.shape[1]
         h, kv, d = self._heads, self._kv, self._dim
-        qg = F.reshape(self.q_proj(x), shape=(b, t, h, 2 * d))
-        q = F.slice_axis(qg, axis=-1, begin=0, end=d)
-        gate = F.reshape(F.slice_axis(qg, axis=-1, begin=d, end=2 * d),
-                         shape=(b, t, h * d))
+        if self.gate_proj is None:
+            qg = F.reshape(self.q_proj(x), shape=(b, t, h, 2 * d))
+            q = F.slice_axis(qg, axis=-1, begin=0, end=d)
+            gate = F.reshape(F.slice_axis(qg, axis=-1, begin=d, end=2 * d),
+                             shape=(b, t, h * d))
+        else:
+            q = F.reshape(self.q_proj(x), shape=(b, t, h, d))
+            gate = self.gate_proj(x)
         k = F.reshape(self.k_proj(x), shape=(b, t, kv, d))
         v = F.reshape(self.v_proj(x), shape=(b, t, kv, d))
-        pos = F.arange(0, t, dtype="int32")
-        q = F.rotary_embedding(F.transpose(self.q_norm(q), axes=(0, 2, 1, 3)),
-                               pos, **self._rotary)
-        k = F.rotary_embedding(F.transpose(self.k_norm(k), axes=(0, 2, 1, 3)),
-                               pos, **self._rotary)
-        v = F.transpose(v, axes=(0, 2, 1, 3))
+
+        def heads_first(x, norm=None):
+            x = F.transpose(x if norm is None else norm(x),
+                            axes=(0, 2, 1, 3))
+            if norm is None or not self._rotary["rotary_dim"]:
+                return x
+            return F.rotary_embedding(x, pos, **self._rotary)
+
+        pos = F.arange(0, t, dtype="int32") \
+            if self._rotary["rotary_dim"] else None
+        q, k = heads_first(q, self.q_norm), heads_first(k, self.k_norm)
+        v = heads_first(v)
         if h != kv:
             k = F.repeat(k, repeats=h // kv, axis=1)
             v = F.repeat(v, repeats=h // kv, axis=1)
+        impl = "flash" if self._impl == "flash" else "xla"
         with _jit.scope("attention"):
-            out = F.scaled_dot_product_attention(
-                q, k, v, causal=True,
-                impl="flash" if self._impl == "flash" else "xla")
+            if self._window is None:
+                out = F.scaled_dot_product_attention(q, k, v, causal=True,
+                                                     impl=impl)
+            else:
+                with _jit.scope("window_attention"):
+                    out = F.scaled_dot_product_attention(
+                        q, k, v, causal=True, impl=impl,
+                        window=self._window)
         out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
                         shape=(b, t, h * d))
         return self.out_proj(out * F.sigmoid(gate))
@@ -456,7 +490,15 @@ class SparseMoE(HybridBlock):
     factor, no token dropped for any routing. What the other experts
     would add is left out: that is the part of the result one device of
     an expert-parallel group gives. ``shared_hidden`` adds a shared
-    expert behind a sigmoid gate, ``sigmoid(shared_gate x) * shared(x)``.
+    expert behind a sigmoid gate, ``sigmoid(shared_gate x) * shared(x)``
+    (``shared_gate=False``: ``shared(x)`` as it is).
+
+    ``score_func='sigmoid'`` scores each expert on its own;
+    ``route_scale`` multiplies the chosen weights; ``expert_bias=True``
+    keeps a per-expert state (all ``num_experts_total``, no gradient,
+    zero at first) that is added to the scores for the choice alone
+    (``ops.moe.moe_router``): what moves it between steps to balance the
+    experts is not built.
 
     The auxiliary state ``expert_tokens`` (count + 1, moved by every
     call as BatchNorm moves its statistics) holds the last call's
@@ -466,7 +508,8 @@ class SparseMoE(HybridBlock):
 
     def __init__(self, units, hidden, num_experts_total, top_k,
                  experts_held=None, shared_hidden=0, renormalize=True,
-                 **kwargs):
+                 score_func="softmax", route_scale=1.0, expert_bias=False,
+                 shared_gate=True, **kwargs):
         super().__init__(**kwargs)
         if experts_held is None:
             experts_held = (0, num_experts_total)
@@ -476,7 +519,8 @@ class SparseMoE(HybridBlock):
         if not 0 <= first < first + count <= num_experts_total:
             raise ValueError(f"experts_held {experts_held} is not a range "
                              f"of the {num_experts_total} experts")
-        self._route = {"top_k": int(top_k), "renormalize": bool(renormalize)}
+        self._route = {"top_k": int(top_k), "renormalize": bool(renormalize),
+                       "score_func": score_func, "scale": float(route_scale)}
         self._first = first
 
         def per_expert(fan_in, fan_out):    # Xavier of one expert's matrix
@@ -494,24 +538,34 @@ class SparseMoE(HybridBlock):
             self.expert_tokens = self.params.get(
                 "expert_tokens", shape=(count + 1,), grad_req="null",
                 init="zeros", differentiable=False)
+            if expert_bias:
+                self.expert_bias = self.params.get(
+                    "expert_bias", shape=(num_experts_total,),
+                    grad_req="null", init="zeros", differentiable=False)
             if shared_hidden:
                 self.shared = GatedMLP(units, shared_hidden,
                                        prefix="shared_")
-                self.shared_gate = _dense(1, units, "shared_gate_")
+                self.shared_gate = _dense(1, units, "shared_gate_") \
+                    if shared_gate else None
             else:
                 self.shared = self.shared_gate = None
 
     def hybrid_forward(self, F, x, router_weight, experts_gate_up_weight,
-                       experts_down_weight, expert_tokens):
+                       experts_down_weight, expert_tokens, expert_bias=None):
         with _jit.scope("moe"):
             with _jit.scope("moe_router"):
-                weights, experts = F.moe_router(x, router_weight,
-                                                **self._route)
+                scored = (x, router_weight) if expert_bias is None \
+                    else (x, router_weight, expert_bias)
+                weights, experts = F.moe_router(*scored, **self._route)
             with _jit.scope("moe_experts"):
                 out = F.moe_experts(x, weights, experts,
                                     experts_gate_up_weight,
                                     experts_down_weight, expert_tokens,
                                     first_expert=self._first)
             if self.shared is not None:
-                out = out + F.sigmoid(self.shared_gate(x)) * self.shared(x)
+                if self.shared_gate is None:
+                    out = out + self.shared(x)
+                else:
+                    out = out + F.sigmoid(self.shared_gate(x)) \
+                        * self.shared(x)
         return out

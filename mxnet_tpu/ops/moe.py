@@ -29,17 +29,36 @@ from .registry import register
 
 
 @register("moe_router", num_outputs=2)
-def moe_router(data, weight, top_k=1, renormalize=True):
+def moe_router(data, weight, bias=None, top_k=1, renormalize=True,
+               score_func="softmax", scale=1.0):
     """``data`` (..., d), ``weight`` (experts, d) -> (weights (..., k)
     float32, experts (..., k) int32): logits over all experts and their
-    softmax in float32, the ``top_k`` largest, their weights
-    renormalised to sum to one when ``renormalize``."""
+    scores in float32 (``score_func`` 'softmax' over the experts, or
+    'sigmoid' of each), the ``top_k`` largest, their weights
+    renormalised to sum to one when ``renormalize``, times ``scale``.
+    ``bias`` (experts,) moves the choice and not the weights: the
+    ``top_k`` are taken of ``scores + bias``, the weights are the scores
+    at those experts without it (a per-expert balancing state that
+    carries no gradient)."""
+    if score_func not in ("softmax", "sigmoid"):
+        raise ValueError(f"unknown score_func {score_func!r}")
     logits = jnp.einsum("...d,ed->...e", data.astype(jnp.float32),
                         weight.astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, experts = jax.lax.top_k(probs, int(top_k))
+    if score_func == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+    else:
+        probs = jax.nn.sigmoid(logits)
+    if bias is None:
+        weights, experts = jax.lax.top_k(probs, int(top_k))
+    else:
+        _, experts = jax.lax.top_k(
+            probs + jax.lax.stop_gradient(bias.astype(jnp.float32)),
+            int(top_k))
+        weights = jnp.take_along_axis(probs, experts, axis=-1)
     if renormalize:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if scale != 1.0:
+        weights = weights * scale
     return weights, experts.astype(jnp.int32)
 
 
@@ -56,15 +75,19 @@ def _block_of_rows(x, flat_w, order, offsets, gate_up, down, top_k, block):
     sizes = bounds[1:] - bounds[:-1]
     valid = (lo + jnp.arange(n_rows) < offsets[-1])[:, None]
     # rows past the assignments held belong to no group: what the
-    # grouped product leaves there is not defined, so they are zeroed
-    # going in (which zeroes their gradient coming back) and going out
+    # grouped product leaves there is not defined (on the chip, whatever
+    # the buffer held), so they are zeroed going in (which zeroes their
+    # gradient coming back) and going out
     xs = jnp.where(valid, x[token], 0)
     h = jax.lax.ragged_dot(xs, gate_up, sizes)
     inner = down.shape[1]
     act = (jax.nn.silu(h[:, :inner].astype(jnp.float32))
            * h[:, inner:].astype(jnp.float32)).astype(x.dtype)
     ys = jax.lax.ragged_dot(act, down, sizes)
-    ys = jnp.where(valid, ys.astype(jnp.float32) * flat_w[take][:, None], 0)
+    # zeroed BEFORE the weights multiply them: selected away afterwards,
+    # a row that holds a NaN still gives its weight NaN x 0 = NaN in the
+    # backward pass, and through it the router and every layer before
+    ys = jnp.where(valid, ys, 0).astype(jnp.float32) * flat_w[take][:, None]
     return jnp.zeros(x.shape, jnp.float32).at[token].add(ys)
 
 

@@ -657,9 +657,14 @@ def _interleaved_encdec_valatt(keys_values, attention, heads=1):
 
 
 @register("scaled_dot_product_attention")
-def _sdpa(q, k, v, mask=None, causal=False, scale=None, impl="xla"):
+def _sdpa(q, k, v, mask=None, causal=False, scale=None, impl="xla",
+          window=None):
     """TPU-native fused attention (new capability; long-context story lives
     in parallel/ring_attention.py). q,k,v: (B, H, L, D).
+
+    ``window`` (with ``causal``): a query sees the keys
+    ``0 <= i - j < window``, itself counted; in the kernels the tiles
+    wholly behind the window are skipped as those past the diagonal are.
 
     impl='flash' opts into the Pallas streaming kernel
     (ops/pallas_kernels.py): O(T) HBM instead of the O(T^2) score matrix.
@@ -677,13 +682,19 @@ def _sdpa(q, k, v, mask=None, causal=False, scale=None, impl="xla"):
         # the kernel or an error: an unsupported shape (ScheduleError) or
         # a non-TPU device (Pallas refuses to lower) raises — never the
         # dense composition under the name the caller asked for
-        return flash_attention_with_grad(q, k, v, causal=causal, scale=scale)
+        return flash_attention_with_grad(q, k, v, causal=causal, scale=scale,
+                                         window=window)
+    if window is not None and not causal:
+        raise ValueError("a window is 0 <= i - j < window, so it needs "
+                         "causal=True")
     d = q.shape[-1]
     s = scale if scale is not None else 1.0 / _np.sqrt(d)
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * s
     if causal:
         L, S = logits.shape[-2], logits.shape[-1]
         cm = jnp.tril(jnp.ones((L, S), bool))
+        if window is not None:
+            cm &= ~jnp.tril(jnp.ones((L, S), bool), -int(window))
         logits = jnp.where(cm, logits, -1e30)
     if mask is not None:
         logits = jnp.where(mask.astype(bool), logits, -1e30)

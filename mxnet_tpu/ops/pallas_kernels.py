@@ -116,11 +116,72 @@ def _lanes(x, n):
     return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
+def _note_build(name, bh, t, d, bq, bk, causal, window):
+    """One ``kernel.build`` span (no time in it, and nothing at all
+    while span tracing is off) for each kernel built: its shape, its
+    tile and, under ``causal``, how many tiles its grid visits of the
+    tiles at or below the diagonal (by the schedule, T and the window,
+    offsets at zero). The builders are cached, so a kernel is noted once
+    however many layers call it."""
+    import time
+
+    from ..observability import trace
+
+    if not trace.enabled():
+        return
+    tiles = {}
+    if causal:
+        visited, below = _schedule().flash_tiles(t, bq, bk, window)
+        tiles = {"tiles_visited": visited, "tiles_causal": below}
+    trace.record("kernel.build", time.perf_counter_ns(), 0, kernel=name,
+                 bh=bh, t=t, d=d, block_q=bq, block_k=bk, window=window,
+                 **tiles)
+
+
+def _window_of(window, causal, t, q_offset=0, k_offset=0):
+    """The window a kernel is built with: None for none, and for one
+    that shuts no key out (``window >= T`` with both blocks at the
+    sequence's start), which is the causal kernel."""
+    if window is None:
+        return None
+    window = int(window)
+    if window < 1:
+        raise ValueError(f"flash_attention: window {window} < 1")
+    if not causal:
+        raise ValueError(
+            "flash_attention: a window is 0 <= i - j < window, so it "
+            "needs causal=True")
+    return None if window >= t and _at_start(q_offset, k_offset) \
+        else window
+
+
+def _at_start(q_offset, k_offset):
+    """Both blocks sit at the sequence's start, and not by a traced
+    value: the grids of a window count their steps exactly then."""
+    return all(isinstance(o, int) and o == 0 for o in (q_offset, k_offset))
+
+
+def _first_k_block(q_first, k_offset, bk, window):
+    """The first K block that holds a key some query at or after
+    ``q_first`` sees under ``window`` (not below 0). The forward's index
+    map and its kernel both count a q block's K steps from it."""
+    import jax.numpy as jnp
+
+    return jnp.maximum((q_first - (window - 1) - k_offset) // bk, 0)
+
+
 def _mha_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                m_ref, l_ref, acc_ref, *, scale, causal, n_kb):
+                m_ref, l_ref, acc_ref, *, scale, causal, n_kb, window=None,
+                n_steps=None):
     """Grid = (BH / HB, n_q_blocks, n_k_blocks); the k dimension is
     innermost, so the VMEM scratch (m, l, acc) carries across K blocks of
     one (heads, q-block) pair and the outputs write on the last step.
+
+    Under a ``window`` (a query sees the keys ``0 <= i - j < window``)
+    the innermost dimension has ``n_steps`` steps, as many as the K
+    blocks a q block's band of keys can touch, and counts them from the
+    band's first block (:func:`_first_k_block`): blocks behind the
+    window are not grid steps at all.
 
     qoff_ref/koff_ref: scalar-prefetch global position offsets — ring
     attention runs the kernel on (local Q, rotated K/V) block pairs whose
@@ -138,23 +199,32 @@ def _mha_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    kb = pl.program_id(2)
+    step = pl.program_id(2)
     qi = pl.program_id(1)
     hb, bq, d = q_ref.shape
     bk = k_ref.shape[1]
 
-    @pl.when(kb == 0)
+    @pl.when(step == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    if window is None:
+        kb, n_steps = step, n_kb
+    else:
+        kb = _first_k_block(qoff_ref[0] + qi * bq, koff_ref[0], bk,
+                            window) + step
 
     def _tile(masked):
         if masked:
             # qpos >= kpos, as row - col >= (first kpos) - (first qpos)
             rel = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) - \
                 jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            keep = rel >= (koff_ref[0] + kb * bk) - (qoff_ref[0] + qi * bq)
+            ahead = (koff_ref[0] + kb * bk) - (qoff_ref[0] + qi * bq)
+            keep = rel >= ahead
+            if window is not None:
+                keep &= rel < ahead + window
         # unrolled on purpose: what several heads a step gain is the
         # compiler interleaving their code (rolled into a fori_loop,
         # eight heads of 128 x 128 ran 2.5 x slower; PERF.md, PR 28)
@@ -184,12 +254,18 @@ def _mha_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         k_first = koff_ref[0] + kb * bk
         live = k_first <= q_first + bq - 1
         crosses = k_first + bk - 1 > q_first
+        if window is not None:
+            # a step past the last K block, or (a hop far in the past) a
+            # block wholly behind the window, is dead as one in the
+            # future is; a tile the window's edge runs through is masked
+            live &= (kb < n_kb) & (k_first + bk - 1 > q_first - window)
+            crosses |= k_first < q_first + bq - window
         pl.when(live & crosses)(lambda: _tile(True))
         pl.when(live & jnp.logical_not(crosses))(lambda: _tile(False))
     else:
         _tile(False)
 
-    @pl.when(kb == n_kb - 1)
+    @pl.when(step == n_steps - 1)
     def _finish():
         for h in range(hb):
             l_fin = jnp.maximum(l_ref[h], 1e-20)
@@ -221,7 +297,8 @@ def _row_of(x):
 
 
 @functools.lru_cache(maxsize=32)
-def _build_flash(bh, t, d, dtype_str, scale, causal, interpret, bq, bk, hb):
+def _build_flash(bh, t, d, dtype_str, scale, causal, interpret, bq, bk, hb,
+                 window=None, at_start=False):
     """One pallas_call per (shape, dtype, config, SCHEDULE): bq/bk/hb are
     part of the cache key, so a schedule-table change re-builds instead
     of serving the old tiling."""
@@ -230,9 +307,14 @@ def _build_flash(bh, t, d, dtype_str, scale, causal, interpret, bq, bk, hb):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    n_kb = t // bk
+    sched = _schedule()
+    n_kb = n_steps = t // bk
     kernel = functools.partial(_mha_kernel, scale=scale, causal=causal,
                                n_kb=n_kb)
+    if window is not None:
+        n_steps = sched.flash_window_steps(t, bq, bk, window, at_start)
+        kernel = functools.partial(kernel, window=window, n_steps=n_steps)
+    _note_build("flash_attention_fwd", bh, t, d, bq, bk, causal, window)
 
     def q_map(b, i, kb, *_):
         return (b, i, 0)
@@ -244,14 +326,16 @@ def _build_flash(bh, t, d, dtype_str, scale, causal, interpret, bq, bk, hb):
             # A hop wholly in the future has no live block; it names
             # block 0 throughout and computes nothing.
             last = (qoff_ref[0] + (i + 1) * bq - 1 - koff_ref[0]) // bk
+            if window is not None:
+                kb += _first_k_block(qoff_ref[0] + i * bq, koff_ref[0], bk,
+                                     window)
             kb = jnp.minimum(kb, jnp.clip(last, 0, n_kb - 1))
         return (b, kb, 0)
 
-    sched = _schedule()
     lanes = sched.LANES
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # q_offset, k_offset (SMEM)
-        grid=(bh // hb, t // bq, n_kb),
+        grid=(bh // hb, t // bq, n_steps),
         in_specs=[
             pl.BlockSpec((hb, bq, d), q_map),
             pl.BlockSpec((hb, bk, d), kv_map),
@@ -284,7 +368,7 @@ def _build_flash(bh, t, d, dtype_str, scale, causal, interpret, bq, bk, hb):
 
 
 def _flash_fwd(q, k, v, causal, scale, interpret, q_offset, k_offset,
-               block_q, block_k):
+               block_q, block_k, window=None):
     """The forward kernel on jax arrays: (out (B, H, T, D), the row
     log-sum-exp as the kernel lays it, (BH, T / BLOCK_Q, 1, BLOCK_Q)
     float32: row-major the flat sequence, which is how the backward
@@ -297,12 +381,14 @@ def _flash_fwd(q, k, v, causal, scale, interpret, q_offset, k_offset,
             f"flash_attention: unsupported shape — q {q.shape} vs k "
             f"{k.shape} / v {v.shape} (self-attention only)")
     sched = _schedule()
+    window = _window_of(window, causal, t, q_offset, k_offset)
     bq, bk = sched.flash_fwd_blocks(
         b * h, t, d, str(q.dtype), interpret=bool(interpret),
-        block_q=block_q, block_k=block_k)
+        block_q=block_q, block_k=block_k, window=window)
     hb = sched.flash_fwd_heads(b * h, bq, bk, d, q.dtype.itemsize)
     fn = _build_flash(b * h, t, d, str(q.dtype), float(scale), bool(causal),
-                      bool(interpret), bq, bk, hb)
+                      bool(interpret), bq, bk, hb, window,
+                      _at_start(q_offset, k_offset))
     out, lse = fn(jnp.asarray(q_offset, jnp.int32).reshape(1),
                   jnp.asarray(k_offset, jnp.int32).reshape(1),
                   q.reshape(b * h, t, d), k.reshape(b * h, t, d),
@@ -312,9 +398,14 @@ def _flash_fwd(q, k, v, causal, scale, interpret, q_offset, k_offset,
 
 def flash_attention(q, k, v, causal=False, scale=None, interpret=False,
                     return_lse=False, q_offset=0, k_offset=0,
-                    block_q=None, block_k=None):
+                    block_q=None, block_k=None, window=None):
     """Fused attention forward: q/k/v (B, H, T, D) -> (B, H, T, D)
     (plus the per-row log-sum-exp when return_lse=True).
+
+    ``window`` (with ``causal``): a query sees the keys
+    ``0 <= i - j < window``, itself counted. K blocks wholly behind the
+    window are no grid steps (no arithmetic, no DMA), the tile its edge
+    runs through is masked; ``window >= T`` is the causal kernel.
 
     q_offset/k_offset (int or traced scalar) place the Q and K/V blocks in
     a larger global sequence for causal masking — the ring-attention hop
@@ -340,13 +431,14 @@ def flash_attention(q, k, v, causal=False, scale=None, interpret=False,
                               scale=scale,
                               interpret=interpret, return_lse=return_lse,
                               q_offset=q_offset, k_offset=k_offset,
-                              block_q=block_q, block_k=block_k)
+                              block_q=block_q, block_k=block_k,
+                              window=window)
         if return_lse:
             return NDArray(out[0], ctx), NDArray(out[1], ctx)
         return NDArray(out, ctx)
     s = scale if scale is not None else 1.0 / _np.sqrt(q.shape[-1])
     out, lse = _flash_fwd(q, k, v, causal, s, interpret, q_offset, k_offset,
-                          block_q, block_k)
+                          block_q, block_k, window)
     if return_lse:
         return out, lse.reshape(q.shape[:3] + (1,))
     return out
@@ -358,14 +450,33 @@ def flash_attention(q, k, v, causal=False, scale=None, interpret=False,
 # direction, and nothing of size T x block passes through HBM
 # ---------------------------------------------------------------------------
 
+def _first_q_block(k_first, q_offset, bq, lo):
+    """The first q block, not before block ``lo``, that holds a query
+    which sees a key at or after ``k_first``. Under a window the
+    backward's index maps and its kernel both count a K block's q steps
+    from it."""
+    import jax.numpy as jnp
+
+    return jnp.maximum((k_first - q_offset) // bq, lo)
+
+
 def _mha_bwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                     lse_ref, dd_ref, dq_ref, dk_ref, dv_ref,
-                    dq_acc, dk_acc, dv_acc, *, scale, causal, n_kb, n_qw):
+                    dq_acc, dk_acc, dv_acc, *, scale, causal, n_kb, n_qw,
+                    window=None, n_steps=None):
     """Grid = (BH / HB, q windows, n_k_blocks, q blocks a window), the q
     blocks innermost: dk / dv of one K block gather over them in VMEM
     scratch and leave on the last; dq of the whole window gathers in
     scratch over the K blocks and leaves on the last of those. One tile
     pair is five matrix products and one ``exp``.
+
+    Under an attention ``window`` (``0 <= i - j < window``; not the q
+    windows above, which cut the sequence for VMEM) the innermost
+    dimension has ``n_steps`` steps, as many as the q blocks whose
+    queries can see a K block's keys, counted from the first of them
+    (:func:`_first_q_block`): q blocks past the window are not grid
+    steps at all. dq is then zeroed whole on a q window's first step and
+    written whole on its last, since no K block visits every q block.
 
     Every operand has the SEQUENCE ALONG LANES, (D, block), and the tile
     is computed transposed, s^T = K.Q^T (BK, BQ): ``lse`` and
@@ -386,19 +497,30 @@ def _mha_bwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    w, kb, qi = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    w, kb, step = pl.program_id(1), pl.program_id(2), pl.program_id(3)
     hb, d, bq = q_ref.shape
     bk = k_ref.shape[2]
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init_dkv():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @pl.when(kb == 0)
-    def _init_dq():
-        for h in range(hb):
-            dq_acc[h, qi] = jnp.zeros((d, bq), jnp.float32)
+    if window is None:
+        qi, n_steps = step, n_qw
+
+        @pl.when(kb == 0)
+        def _init_dq():
+            for h in range(hb):
+                dq_acc[h, qi] = jnp.zeros((d, bq), jnp.float32)
+    else:
+        # the q block of this step, counted inside the q window
+        qi = _first_q_block(koff_ref[0] + kb * bk, qoff_ref[0], bq,
+                            w * n_qw) - w * n_qw + step
+
+        @pl.when((kb == 0) & (step == 0))
+        def _init_dq():
+            dq_acc[...] = jnp.zeros_like(dq_acc)
 
     q_first = qoff_ref[0] + (w * n_qw + qi) * bq
     k_first = koff_ref[0] + kb * bk
@@ -409,6 +531,8 @@ def _mha_bwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
             rel = jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 1) - \
                 jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
             keep = rel >= k_first - q_first
+            if window is not None:
+                keep &= rel < k_first - q_first + window
         rows = (((0,), (0,)), ((), ()))     # (D, BK) x (D, BQ) -> (BK, BQ)
         lanes = (((1,), (1,)), ((), ()))    # (D, BQ) x (BK, BQ) -> (D, BK)
         # unrolled, as the forward's heads are (PERF.md, PR 28)
@@ -441,26 +565,40 @@ def _mha_bwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         # a resident block again); only one that crosses it is masked
         live = k_first <= q_first + bq - 1
         crosses = k_first + bk - 1 > q_first
+        if window is not None:
+            # a step past the q window's last block, or a q block wholly
+            # past the attention window, is dead as one before the K
+            # block is; a tile the window's edge runs through is masked
+            live &= (qi < n_qw) & (k_first + bk - 1 > q_first - window)
+            crosses |= k_first < q_first + bq - window
         pl.when(live & crosses)(lambda: _tile(True))
         pl.when(live & jnp.logical_not(crosses))(lambda: _tile(False))
     else:
         _tile(False)
 
-    @pl.when(qi == n_qw - 1)
+    @pl.when(step == n_steps - 1)
     def _finish_dkv():
         for h in range(hb):
             dk_ref[0, h] = (dk_acc[h] * scale).astype(dk_ref.dtype)
             dv_ref[0, h] = dv_acc[h].astype(dv_ref.dtype)
 
-    @pl.when(kb == n_kb - 1)
-    def _finish_dq():
-        for h in range(hb):
-            dq_ref[h, qi] = (dq_acc[h, qi] * scale).astype(dq_ref.dtype)
+    if window is None:
+        @pl.when(kb == n_kb - 1)
+        def _finish_dq():
+            for h in range(hb):
+                dq_ref[h, qi] = (dq_acc[h, qi] * scale).astype(dq_ref.dtype)
+    else:
+        @pl.when((kb == n_kb - 1) & (step == n_steps - 1))
+        def _finish_dq():
+            for h in range(hb):
+                for g in range(n_qw):
+                    dq_ref[h, g] = (dq_acc[h, g] * scale).astype(
+                        dq_ref.dtype)
 
 
 @functools.lru_cache(maxsize=32)
 def _build_flash_bwd(bh, t, d, dtype_str, scale, causal, interpret, bq, bk,
-                     hb, n_win):
+                     hb, n_win, window=None, at_start=False):
     """The backward's pallas_call for one (shape, dtype, config,
     SCHEDULE), on (BH, D, T) operands. ``n_win`` q windows share the K
     blocks: each window's dk / dv part comes out on its own (float32
@@ -471,14 +609,28 @@ def _build_flash_bwd(bh, t, d, dtype_str, scale, causal, interpret, bq, bk,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    sched = _schedule()
     n_kb, n_qb = t // bk, t // bq
-    n_qw = n_qb // n_win
+    n_qw = n_steps = n_qb // n_win
     dtype = jnp.dtype(dtype_str)
     part = dtype if n_win == 1 else jnp.dtype(jnp.float32)
     kernel = functools.partial(_mha_bwd_kernel, scale=scale, causal=causal,
                                n_kb=n_kb, n_qw=n_qw)
+    if window is not None:
+        n_steps = min(n_qw, sched.flash_window_steps(
+            t, bq, bk, window, at_start, backward=True))
+        kernel = functools.partial(kernel, window=window, n_steps=n_steps)
+    _note_build("flash_attention_bwd", bh, t, d, bq, bk, causal, window)
 
     def q_block(w, kb, qi, qoff_ref, koff_ref):
+        if window is not None:
+            # the K block's live q blocks in turn, then the last of them
+            # again (resident: a dead step issues no DMA)
+            k_first = koff_ref[0] + kb * bk
+            last = (k_first + bk - 1 + window - 1 - qoff_ref[0]) // bq
+            g = jnp.minimum(_first_q_block(k_first, qoff_ref[0], bq,
+                                           w * n_qw) + qi, last)
+            return jnp.clip(g, w * n_qw, (w + 1) * n_qw - 1)
         g = w * n_qw + qi
         if causal:
             # a q block in the K block's past names the first live one
@@ -500,12 +652,16 @@ def _build_flash_bwd(bh, t, d, dtype_str, scale, causal, interpret, bq, bk,
             last = (qoff_ref[0] + (w + 1) * n_qw * bq - 1
                     - koff_ref[0]) // bk
             kb = jnp.minimum(kb, jnp.clip(last, 0, n_kb - 1))
+            if window is not None:
+                # and K blocks wholly behind the q window's first query
+                kb = jnp.maximum(kb, jnp.minimum(_first_k_block(
+                    qoff_ref[0] + w * n_qw * bq, koff_ref[0], bk, window),
+                    n_kb - 1))
         return (b, 0, kb)
 
-    sched = _schedule()
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # q_offset, k_offset (SMEM)
-        grid=(bh // hb, n_win, n_kb, n_qw),
+        grid=(bh // hb, n_win, n_kb, n_steps),
         in_specs=[
             pl.BlockSpec((hb, d, bq), q_map),           # q^T
             pl.BlockSpec((hb, d, bk), kv_map),          # k^T
@@ -556,7 +712,7 @@ def _seq_minor(x):
 
 
 def _flash_bwd(qt, kt, vt, out, lse, dout, dlse, scale, causal, interpret,
-               q_offset, k_offset, block_k=None, block_q=None):
+               q_offset, k_offset, block_k=None, block_q=None, window=None):
     """dq, dk, dv of one flash-attention call through the backward
     kernel. ``qt``, ``kt``, ``vt`` are (B, H, D, T) (:func:`_seq_minor`);
     ``lse`` is the forward's, in any shape that is row-major the
@@ -572,14 +728,17 @@ def _flash_bwd(qt, kt, vt, out, lse, dout, dlse, scale, causal, interpret,
     sched = _schedule()
     # a long sequence off the lane grid runs padded with zeros
     tp = sched.flash_bwd_length(t)
+    window = _window_of(window, causal, t, q_offset, k_offset)
     bq, bk = sched.flash_bwd_block(bh, tp, d, str(out.dtype),
                                    interpret=bool(interpret),
-                                   block_k=block_k, block_q=block_q)
+                                   block_k=block_k, block_q=block_q,
+                                   window=window)
     itemsize = out.dtype.itemsize
     n_win = sched.flash_bwd_windows(tp, bq, bk, d, itemsize)
     hb = sched.flash_bwd_heads(bh, bq, bk, tp // n_win, d, itemsize)
     fn = _build_flash_bwd(bh, tp, d, str(out.dtype), float(scale),
-                          bool(causal), bool(interpret), bq, bk, hb, n_win)
+                          bool(causal), bool(interpret), bq, bk, hb, n_win,
+                          window, _at_start(q_offset, k_offset))
     dd = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     if dlse is not None:
         dd = dd - dlse.astype(jnp.float32).reshape(dd.shape)
@@ -608,7 +767,8 @@ def _flash_bwd(qt, kt, vt, out, lse, dout, dlse, scale, causal, interpret,
 
 def flash_attention_with_lse(q, k, v, causal=False, scale=None,
                              interpret=False, q_offset=0, k_offset=0,
-                             block_q=None, block_k=None, bwd_block_k=None):
+                             block_q=None, block_k=None, bwd_block_k=None,
+                             window=None):
     """Differentiable (out, lse) pair — the ring-attention building block:
     per-hop results merge by log-sum-exp, so the lse output needs a
     gradient path too (its cotangent enters the backward kernel through
@@ -619,7 +779,8 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None,
     ``flash_attention_bwd``) whose tiles resolve through the schedule
     registry (docs/autotune.md): block_q / block_k override the
     forward's, bwd_block_k the width of the backward's K block
-    (legalized: any width the scan before it took still works)."""
+    (legalized: any width the scan before it took still works).
+    ``window`` as :func:`flash_attention`'s, placed by the offsets."""
     import jax
     import jax.numpy as jnp
 
@@ -628,7 +789,7 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None,
     def fwd(q, k, v, qo, ko):
         return _flash_fwd(q, k, v, causal, s, interpret,
                           qo.astype(jnp.int32), ko.astype(jnp.int32),
-                          block_q, block_k)
+                          block_q, block_k, window)
 
     @jax.custom_vjp
     def f(q, k, v, qo, ko):
@@ -647,7 +808,7 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None,
         dq, dk, dv = _flash_bwd(
             q, k, v, out, lse, dout, dlse, s, causal, interpret,
             qo.astype(jnp.int32), ko.astype(jnp.int32),
-            block_k=bwd_block_k)
+            block_k=bwd_block_k, window=window)
         return dq, dk, dv, jnp.zeros_like(qo), jnp.zeros_like(ko)
 
     f.defvjp(f_fwd, f_bwd)
@@ -657,7 +818,7 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None,
 
 def flash_attention_with_grad(q, k, v, causal=False, scale=None,
                               interpret=False, block_q=None, block_k=None,
-                              bwd_block_k=None):
+                              bwd_block_k=None, window=None):
     """Differentiable flash attention: the forward kernel paired by
     jax.custom_vjp with the backward kernel (``flash_attention_bwd``:
     probabilities recomputed tile by tile from the forward's saved
@@ -665,7 +826,9 @@ def flash_attention_with_grad(q, k, v, causal=False, scale=None,
     dtype, float32 accumulation, dead causal tiles skipped). Same
     shape / placement / schedule rules as flash_attention, NDArrays
     included; bwd_block_k overrides the width of the backward's K
-    block (any width: it is legalized onto the kernel's lane grid)."""
+    block (any width: it is legalized onto the kernel's lane grid).
+    ``window`` as :func:`flash_attention`'s: both kernels leave the
+    tiles wholly outside it out of their grids."""
     import jax
 
     if hasattr(q, "_data"):
@@ -676,13 +839,13 @@ def flash_attention_with_grad(q, k, v, causal=False, scale=None,
             q._data, k._data, v._data, causal=causal, scale=scale,
             interpret=interpret,
             block_q=block_q, block_k=block_k,
-            bwd_block_k=bwd_block_k), ctx)
+            bwd_block_k=bwd_block_k, window=window), ctx)
 
     s = scale if scale is not None else 1.0 / _np.sqrt(q.shape[-1])
 
     def fwd(q, k, v):
         return _flash_fwd(q, k, v, causal, s, interpret, 0, 0,
-                          block_q, block_k)
+                          block_q, block_k, window)
 
     @jax.custom_vjp
     def f(q, k, v):
@@ -695,7 +858,8 @@ def flash_attention_with_grad(q, k, v, causal=False, scale=None,
     def f_bwd(res, dout):
         q, k, v, out, lse = res
         return _flash_bwd(q, k, v, out, lse, dout, None, s, causal,
-                          interpret, 0, 0, block_k=bwd_block_k)
+                          interpret, 0, 0, block_k=bwd_block_k,
+                          window=window)
 
     f.defvjp(f_fwd, f_bwd)
     return f(q, k, v)

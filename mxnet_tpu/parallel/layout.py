@@ -122,7 +122,8 @@ class SpecLayout:
         ``embed_``/``head_`` for the tables) and qwen3_next's
         (``attn_q_``/``attn_k_``/``attn_v_``, ``linattn_qkvz_``/
         ``linattn_ba_``/``linattn_out_``, ``moe_experts_*``,
-        ``moe_shared_gate_up_``/``moe_shared_down_``); first match wins
+        ``moe_shared_gate_up_``/``moe_shared_down_``) and trinity's
+        (``attn_gate_``, ``mlp_gate_up_``/``mlp_down_``); first match wins
         and anything unmatched — norms, positional table, small biases,
         a router, a depthwise convolution — replicates, which is exactly
         the layout's intent.
@@ -130,12 +131,12 @@ class SpecLayout:
         return (
             (r".*attn_qkv_weight$", self.qkv_projection()),
             (r".*attn_qkv_bias$", self.column_bias()),
-            (r".*attn_[qkv]_weight$", self.qkv_projection()),
+            (r".*attn_([qkv]|gate)_weight$", self.qkv_projection()),
             (r".*linattn_(qkvz|ba)_weight$", self.qkv_projection()),
             (r".*(attn|linattn)_out_weight$", self.attn_output()),
             (r".*moe_experts_(gate_up|down)_weight$", self.experts()),
-            (r".*moe_shared_gate_up_weight$", self.ffn_up()),
-            (r".*moe_shared_down_weight$", self.ffn_down()),
+            (r".*(moe_shared|mlp)_gate_up_weight$", self.ffn_up()),
+            (r".*(moe_shared|mlp)_down_weight$", self.ffn_down()),
             (r".*ff1_weight$", self.ffn_up()),
             (r".*ff1_bias$", self.column_bias()),
             (r".*ff2_weight$", self.ffn_down()),

@@ -317,8 +317,11 @@ def kernel_schedule(kernel, shape_key, dtype, backend):
 # the search workloads both derive keys here, so a tuned entry can never
 # go dead because the two sides formatted the same shape differently.
 
-def flash_shape_key(bh, t, d):
-    return f"bh{int(bh)}-t{int(t)}-d{int(d)}"
+def flash_shape_key(bh, t, d, window=None):
+    """Flash attention table key; a kernel built with a window is keyed
+    apart (``-w<window>``), one without reads as it always did."""
+    key = f"bh{int(bh)}-t{int(t)}-d{int(d)}"
+    return key if window is None else f"{key}-w{int(window)}"
 
 
 def int8_fc_shape_key(m, k, n):
@@ -404,7 +407,7 @@ def flash_fwd_heads(bh, bq, bk, d, itemsize):
 
 
 def flash_fwd_blocks(bh, t, d, dtype, interpret=False, block_q=None,
-                     block_k=None):
+                     block_k=None, window=None):
     """Resolved + legalized (block_q, block_k) for the flash forward.
     Explicit overrides must already be legal (the search driver's
     contract); table/default blocks are legalized down. How many heads
@@ -435,7 +438,8 @@ def flash_fwd_blocks(bh, t, d, dtype, interpret=False, block_q=None,
     else:
         bq = bk = None
     if bq is None or bk is None:
-        sched = kernel_schedule("flash_fwd", flash_shape_key(bh, t, d),
+        sched = kernel_schedule("flash_fwd",
+                                flash_shape_key(bh, t, d, window),
                                 str(dtype), resolve_backend(interpret))
         if bq is None:
             bq = legalize_block(t, sched["block_q"])
@@ -446,6 +450,42 @@ def flash_fwd_blocks(bh, t, d, dtype, interpret=False, block_q=None,
             f"flash schedule: no legal block for T={t} (needs T itself "
             f"or a multiple-of-{MIN_SUBLANE} divisor)")
     return bq, bk
+
+
+def _live_tiles(t, bq, bk, window=None):
+    """(T / bq, T / bk) booleans: the (q block, K block) tiles that hold
+    a (query, key) pair with ``0 <= i - j`` (``< window``), both blocks
+    at the sequence's start."""
+    import numpy as np
+
+    q_first = np.arange(int(t) // bq)[:, None] * bq
+    k_first = np.arange(int(t) // bk)[None, :] * bk
+    live = k_first <= q_first + bq - 1
+    if window is not None:
+        live &= k_first + bk - 1 > q_first - window
+    return live
+
+
+def flash_tiles(t, bq, bk, window=None):
+    """(tiles a causal flash kernel computes under ``window``, tiles at
+    or below the diagonal) at a (block_q, block_k) tile: the same for
+    the forward and the backward, which visit the same pairs."""
+    return (int(_live_tiles(t, bq, bk, window).sum()),
+            int(_live_tiles(t, bq, bk).sum()))
+
+
+def flash_window_steps(t, bq, bk, window, at_start=False, backward=False):
+    """Steps of a flash kernel's innermost grid dimension under a
+    window: the K blocks one q block's queries see (forward), the q
+    blocks that see one K block's keys (``backward``). Counted exactly
+    where both blocks are known to sit at the sequence's start; else
+    the most a band of ``window + block - 1`` positions can touch
+    wherever it lies (a ring hop's traced offsets)."""
+    if at_start:
+        return int(_live_tiles(t, bq, bk, window).sum(
+            0 if backward else 1).max())
+    outer, inner = (bk, bq) if backward else (bq, bk)
+    return min(int(t) // inner, (int(window) + outer - 3) // inner + 2)
 
 
 def flash_bwd_length(t):
@@ -462,7 +502,7 @@ def flash_bwd_length(t):
 
 
 def flash_bwd_block(bh, t, d, dtype, interpret=False, block_k=None,
-                    block_q=None):
+                    block_q=None, window=None):
     """The flash backward's tile, (block_q, block_k), at a length
     :func:`flash_bwd_length` gives, so every shape the forward runs on
     has one. Each axis is the caller's override (``bwd_block_k=``, the
@@ -477,7 +517,8 @@ def flash_bwd_block(bh, t, d, dtype, interpret=False, block_k=None,
     t = int(t)
     want = {"block_q": block_q, "block_k": block_k}
     if block_q is None or block_k is None:
-        sched = kernel_schedule("flash_bwd", flash_shape_key(bh, t, d),
+        sched = kernel_schedule("flash_bwd",
+                                flash_shape_key(bh, t, d, window),
                                 str(dtype), resolve_backend(interpret))
         want = {axis: sched[axis] if b is None else b
                 for axis, b in want.items()}

@@ -123,6 +123,53 @@ def test_flash_backward_is_named_and_compiles_at_real_widths(
     assert "while" not in compiled      # no scan is left in the backward
 
 
+# ((B, H, T, D), dtype, window, the offsets traced): a window layer of
+# Trinity-Mini's cell (static offsets: the grids count their steps
+# exactly, 5 of 16); the same as a ring hop would call it; a window off
+# the tile grid; a short sequence off the lane grid
+_WINDOW_SHAPES = [((1, 32, 8192, 128), jnp.bfloat16, 2048, False),
+                  ((1, 4, 8192, 128), jnp.bfloat16, 2048, True),
+                  ((1, 2, 2048, 64), jnp.bfloat16, 700, False),
+                  ((1, 2, 200, 32), jnp.float32, 77, True)]
+
+
+@pytest.mark.parametrize("shape,dtype,window,traced", _WINDOW_SHAPES)
+def test_window_kernels_are_named_and_compile_at_real_widths(
+        one_chip, shape, dtype, window, traced):
+    """Under a window both kernels keep their names (one kernel each,
+    window or not), sit under the ``window_attention`` scope where the
+    block opens it, and Mosaic takes the shortened grids and the
+    clamped index maps at these widths."""
+    from mxnet_tpu.ops import pallas_kernels
+
+    def loss(q, k, v, qo, ko):
+        with jax.named_scope("attention"), \
+                jax.named_scope("window_attention"):
+            if traced:
+                out, _ = pallas_kernels.flash_attention_with_lse(
+                    q, k, v, causal=True, q_offset=qo, k_offset=ko,
+                    window=window)
+            else:
+                out = pallas_kernels.flash_attention_with_grad(
+                    q, k, v, causal=True, window=window)
+        return jnp.sum(out.astype(jnp.float32))
+
+    qkv = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    off = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        qkv, qkv, qkv, off, off)
+    text = lowered.as_text(debug_info=True)
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd"):
+        assert f'kernel_name = "{kernel}"' in text
+        named = [line for line in text.splitlines()
+                 if f"{kernel}/pallas_call" in line]
+        assert named and all(f"window_attention/{kernel}" in line
+                             for line in named), named[:2]
+    compiled = lowered.compile().as_text()
+    assert compiled.count("tpu_custom_call") >= 2
+    assert "while" not in compiled
+
+
 def _through_delta_rule(*args):
     """The gated delta rule under the ``linear_attention`` scope, as
     ``GatedDeltaNet`` calls it, straight through the kernels (the

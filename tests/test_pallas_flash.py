@@ -467,3 +467,269 @@ def test_several_heads_share_a_grid_step():
     out = flash_attention(q, k, v, causal=True, interpret=True)
     np.testing.assert_allclose(np.asarray(out), _dense(q, k, v, True),
                                atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# a window: a query sees the keys 0 <= i - j < window, itself counted
+# ---------------------------------------------------------------------------
+
+def _window_ref(q, k, v, window, q_offset=0, k_offset=0):
+    """float32 dense masked softmax: (out, lse, rows that see a key)."""
+    q, k, v = (np.asarray(x, np.float32) for x in (q, k, v))
+    s = np.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    ahead = (q_offset + np.arange(q.shape[2]))[:, None] \
+        - (k_offset + np.arange(k.shape[2]))[None, :]
+    keep = (ahead >= 0) & (ahead < window)
+    s = np.where(keep, s, -1e30)
+    m = s.max(-1, keepdims=True)
+    e = np.exp(s - m)
+    l = e.sum(-1, keepdims=True)
+    return (np.einsum("bhqk,bhkd->bhqd", e / l, v), m + np.log(l),
+            keep.any(-1))
+
+
+# (T, D, block_q, block_k, window, q_offset, k_offset): a window smaller
+# than a tile, equal to one, not a multiple of one, crossing several,
+# wider than the sequence, one key wide; block_q != block_k both ways; T
+# off the lane grid (tiles of 40); then ring hops, the offsets traced:
+# K/V a sequence behind (the window reaches 99 of its keys), on the
+# diagonal with a window wider than the hop, half a sequence ahead, and
+# so far behind that the window reaches nothing
+_WINDOWS = [
+    (256, 32, 64, 64, 40, 0, 0), (256, 32, 64, 64, 64, 0, 0),
+    (256, 32, 64, 64, 100, 0, 0), (256, 32, 64, 64, 200, 0, 0),
+    (256, 32, 64, 64, 300, 0, 0), (256, 32, 64, 64, 1, 0, 0),
+    (256, 32, 128, 32, 70, 0, 0), (256, 32, 32, 128, 70, 0, 0),
+    (200, 32, 40, 40, 77, 0, 0),
+    (256, 32, 64, 64, 100, 512, 256), (256, 32, 64, 64, 300, 256, 256),
+    (256, 32, 64, 64, 100, 256, 384), (256, 32, 64, 64, 100, 1024, 0)]
+
+
+@pytest.mark.parametrize("dtype,atol", [("float32", 1e-5),
+                                        ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("t,d,bq,bk,window,q_offset,k_offset", _WINDOWS)
+def test_window_forward_matches_the_dense_masked_softmax(
+        t, d, bq, bk, window, q_offset, k_offset, dtype, atol):
+    import jax
+    import jax.numpy as jnp
+
+    q, k, v = (jnp.asarray(a, dtype)
+               for a in _qkv(B=1, H=2, T=t, D=d, seed=window + bq))
+    kwargs = dict(causal=True, interpret=True, return_lse=True, block_q=bq,
+                  block_k=bk, window=window)
+    if q_offset or k_offset:
+        out, lse = jax.jit(lambda q, k, v, qo, ko: flash_attention(
+            q, k, v, q_offset=qo, k_offset=ko, **kwargs))(
+                q, k, v, jnp.int32(q_offset), jnp.int32(k_offset))
+    else:
+        out, lse = flash_attention(q, k, v, **kwargs)
+    assert out.dtype == q.dtype and lse.shape == (1, 2, t, 1)
+    out, lse = np.asarray(out, np.float32), np.asarray(lse)
+    ref, ref_lse, seen = _window_ref(q, k, v, window, q_offset, k_offset)
+    np.testing.assert_allclose(out[:, :, seen], ref[:, :, seen], atol=atol)
+    np.testing.assert_allclose(lse[:, :, seen], ref_lse[:, :, seen],
+                               atol=atol)
+    # a row that sees nothing carries no weight into the ring's merge
+    assert (lse[:, :, ~seen] < -1e29).all()
+    if not seen.any():
+        assert (out == 0).all()
+
+
+# the backward's tiles lie on the lane grid: (T, D, (block_q, block_k) or
+# None for the default, window, q_offset, k_offset). T = 200 is one block
+# of T; T = 520 runs padded to 640 in tiles of 128
+_BWD_WINDOWS = [
+    (384, 32, (128, 128), 40, 0, 0), (384, 32, (128, 128), 128, 0, 0),
+    (384, 32, (128, 128), 200, 0, 0), (512, 32, (128, 256), 300, 0, 0),
+    (512, 32, (256, 128), 130, 0, 0), (384, 32, (128, 128), 1000, 0, 0),
+    (200, 32, None, 77, 0, 0), (520, 32, None, 150, 0, 0),
+    (256, 32, (128, 128), 100, 512, 256),
+    (256, 32, (128, 128), 300, 256, 256),
+    (256, 32, (128, 128), 100, 256, 384),
+    (256, 32, (128, 128), 100, 1024, 0)]
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("t,d,tile,window,q_offset,k_offset", _BWD_WINDOWS)
+def test_window_backward_matches_float32_dense_autodiff(
+        t, d, tile, window, q_offset, k_offset, dtype, tol, tmp_path,
+        monkeypatch):
+    """dq, dk, dv of ``flash_attention_with_lse`` under a window (traced
+    offsets, a non-zero ``dlse``) against autodiff through the float32
+    dense masked softmax, each relative to the gradient's largest entry;
+    rows that see no key give nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops.pallas_kernels import flash_attention_with_lse
+    from mxnet_tpu.tune import schedule
+
+    rng = np.random.RandomState(t + window + k_offset)
+    q, k, v = (jnp.asarray(rng.randn(1, 2, t, d).astype(np.float32) * 0.3,
+                           dtype) for _ in range(3))
+    w_out = jnp.asarray(rng.randn(1, 2, t, d).astype(np.float32))
+    w_lse = jnp.asarray(rng.randn(1, 2, t, 1).astype(np.float32))
+    table = str(tmp_path / "table.json")
+    monkeypatch.setenv("MXNET_TPU_SCHEDULE_TABLE", table)
+    monkeypatch.setattr(schedule, "default_table_path", lambda: table)
+    if tile is not None:
+        # a kernel with a window is keyed apart: the entry without one
+        # is not read
+        tp = schedule.flash_bwd_length(t)
+        schedule.put_entry(table, "flash_bwd",
+                           schedule.flash_shape_key(2, tp, d, window), dtype,
+                           "interpret", {"block_q": tile[0],
+                                         "block_k": tile[1]})
+        schedule.put_entry(table, "flash_bwd",
+                           schedule.flash_shape_key(2, tp, d), dtype,
+                           "interpret", {"block_q": tp, "block_k": tp})
+        assert schedule.flash_bwd_block(2, tp, d, dtype, interpret=True,
+                                        window=window) == tile
+
+    def loss_flash(q_, k_, v_, qo, ko):
+        out, lse = flash_attention_with_lse(
+            q_, k_, v_, causal=True, interpret=True, q_offset=qo,
+            k_offset=ko, window=window)
+        return jnp.sum(out.astype(jnp.float32) * w_out) + \
+            jnp.sum(lse * w_lse)
+
+    ahead = (q_offset + np.arange(t))[:, None] \
+        - (k_offset + np.arange(t))[None, :]
+    keep = jnp.asarray((ahead >= 0) & (ahead < window))
+    seen = np.asarray(keep).any(-1)
+    mask = jnp.asarray(seen, jnp.float32)[:, None]
+
+    def loss_dense(q_, k_, v_):
+        s = jnp.einsum("bhqd,bhkd->bhqk", q_, k_) / np.sqrt(d)
+        s = jnp.where(keep, s, -1e30)
+        lse = jax.scipy.special.logsumexp(s, -1, keepdims=True)
+        out = jnp.einsum("bhqk,bhkd->bhqd", jnp.exp(s - lse), v_)
+        return jnp.sum(out * w_out * mask) + jnp.sum(lse * w_lse * mask)
+
+    got = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(
+        q, k, v, jnp.int32(q_offset), jnp.int32(k_offset))
+    want = jax.grad(loss_dense, argnums=(0, 1, 2))(
+        *(x.astype(jnp.float32) for x in (q, k, v)))
+    for a, ref, name in zip(got, want, "qkv"):
+        assert a.dtype == q.dtype and a.shape == q.shape
+        a, ref = np.asarray(a, np.float32), np.asarray(ref)
+        if not seen.any():
+            assert (a == 0).all(), f"grad {name} of a hop out of the window"
+            continue
+        scale = np.abs(ref).max()
+        np.testing.assert_allclose(a / scale, ref / scale, atol=tol,
+                                   err_msg=f"grad {name}")
+
+
+def test_window_through_the_op_and_its_gradient():
+    """``scaled_dot_product_attention(window=)``: the dense composition
+    masks as the kernels do, and ``flash_attention_with_grad`` (static
+    offsets: the grids count their steps exactly) gives its gradients."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops.pallas_kernels import flash_attention_with_grad
+
+    q, k, v = (jnp.asarray(a) for a in _qkv(B=1, H=2, T=384, D=32, seed=6))
+    dense = mx.nd.scaled_dot_product_attention(
+        mx.nd.array(q), mx.nd.array(k), mx.nd.array(v), causal=True,
+        window=100).asnumpy()
+    np.testing.assert_allclose(dense, _window_ref(q, k, v, 100)[0],
+                               atol=1e-5)
+    with pytest.raises(Exception, match="causal"):
+        mx.nd.scaled_dot_product_attention(
+            mx.nd.array(q), mx.nd.array(k), mx.nd.array(v), window=100)
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, window=100, interpret=True)
+
+    def loss(fn):
+        return lambda *a: jnp.sum(jnp.sin(fn(*a)))
+
+    got = jax.grad(loss(lambda *a: flash_attention_with_grad(
+        *a, causal=True, interpret=True, window=100)),
+        argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda *a: mx.nd.scaled_dot_product_attention(
+        *(mx.nd.array(x) for x in a), causal=True, window=100).data_),
+        argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
+
+
+def test_window_grids_leave_out_the_tiles_behind_it():
+    """What the schedule counts: under a window the innermost grid
+    dimension is as long as the tiles a block's band can touch, exactly
+    where the offsets are known to be zero; a window no narrower than
+    the sequence is the causal kernel itself."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_kernels
+    from mxnet_tpu.tune import schedule
+
+    # Trinity-Mini's cell: T = 8192, window 2048, tiles of 512
+    assert schedule.flash_tiles(8192, 512, 512, 2048) == (70, 136)
+    assert schedule.flash_tiles(8192, 512, 512) == (136, 136)
+    assert schedule.flash_window_steps(8192, 512, 512, 2048, True) == 5
+    assert schedule.flash_window_steps(8192, 512, 512, 2048, True,
+                                       backward=True) == 5
+    assert schedule.flash_window_steps(8192, 512, 512, 2048) == 6
+    assert schedule.flash_window_steps(8192, 512, 256, 2048, True) == 10
+    assert schedule.flash_window_steps(8192, 512, 256, 2048, True,
+                                       backward=True) == 5
+    assert schedule.flash_window_steps(256, 64, 64, 1000) == 4
+    assert schedule.flash_shape_key(32, 8192, 128) == "bh32-t8192-d128"
+    assert schedule.flash_shape_key(32, 8192, 128, 2048) \
+        == "bh32-t8192-d128-w2048"
+    assert pallas_kernels._window_of(None, True, 256) is None
+    assert pallas_kernels._window_of(256, True, 256) is None
+    assert pallas_kernels._window_of(255, True, 256) == 255
+    # a hop's offsets are traced: the window stays whatever its width
+    assert pallas_kernels._window_of(256, True, 256, jnp.int32(0), 0) == 256
+
+
+# sha256 of the jaxprs (kernel bodies, grids and index maps inside) of
+# forward + backward through flash_attention_with_grad and
+# flash_attention_with_lse at the shapes below, causal and not, without a
+# window, as the commit before the window made them. A caller that
+# passes no window builds the kernels it always did.
+_NO_WINDOW_JAXPRS = \
+    "846a16198f57de12ad8f0fac2e490fb1433f44fe91705bd1c4a3c98c01c8030c"
+
+
+def _no_window_digest():
+    import hashlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops.pallas_kernels import (flash_attention_with_grad,
+                                              flash_attention_with_lse)
+
+    def total(outs):
+        return sum(jnp.sum(x.astype(jnp.float32)) for x in outs)
+
+    texts = []
+    for b, h, t, d, dtype in [(1, 2, 256, 64, "float32"),
+                              (1, 2, 384, 32, "bfloat16"),
+                              (2, 4, 64, 32, "float32"),
+                              (1, 1, 520, 32, "float32")]:
+        q = jnp.zeros((b, h, t, d), dtype)
+        for causal in (False, True):
+            texts.append(str(jax.make_jaxpr(jax.grad(
+                lambda q, k, v: total([flash_attention_with_grad(
+                    q, k, v, causal=causal, interpret=True)]),
+                argnums=(0, 1, 2)))(q, q, q)))
+            texts.append(str(jax.make_jaxpr(jax.grad(
+                lambda q, k, v, qo, ko: total(flash_attention_with_lse(
+                    q, k, v, causal=causal, interpret=True, q_offset=qo,
+                    k_offset=ko)), argnums=(0, 1, 2)))(
+                        q, q, q, jnp.int32(0), jnp.int32(0))))
+    return hashlib.sha256("\n".join(texts).encode()).hexdigest()
+
+
+def test_without_a_window_the_kernels_are_what_they_were():
+    """Holds ``window=None`` to the kernels of the commit before windows
+    existed (PR 33's; the digest is ``_no_window_digest()`` run with that
+    commit's ``mxnet_tpu`` on the path). A PR that changes the kernels
+    on purpose computes the digest anew; one that adds an argument has
+    to leave it alone."""
+    assert _no_window_digest() == _NO_WINDOW_JAXPRS
