@@ -162,10 +162,11 @@ class Executor:
 
             # MXNET_BACKWARD_DO_MIRROR: recompute activations in backward
             # instead of keeping them (reference graph_executor.cc:357)
-            from .remat import mirror_enabled
+            from .remat import mirror_enabled, resolve_policy
 
             if mirror_enabled():
-                of_diff = jax.checkpoint(of_diff)
+                of_diff = jax.checkpoint(of_diff,
+                                         policy=resolve_policy(True))
             diff_vals = tuple(arg_vals[i] for i in diff_idx)
             outs, vjp_fn, new_aux = jax.vjp(of_diff, *diff_vals, has_aux=True)
             grads = vjp_fn(tuple(head_grads))

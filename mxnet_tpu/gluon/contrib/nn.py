@@ -10,11 +10,13 @@ GatedMLP and SparseMoE (an expert layer told which experts it holds).
 """
 from __future__ import annotations
 
+import time
 import warnings
 
 from .. import nn as _nn
 from ... import initializer as _init
 from ... import jit as _jit
+from ...observability import trace as _obs_trace
 from ..block import Block, HybridBlock
 
 __all__ = ["Remat", "Concurrent", "HybridConcurrent", "Identity", "SparseEmbedding",
@@ -114,6 +116,16 @@ class Remat(HybridBlock):
     hybridize's discovery trace (where cells hold concrete values that
     must be *captured*, not baked in) it is a transparent pass-through.
 
+    ``policy`` is a ``remat.resolve_policy`` spec. The default keeps the
+    block's input and what its hand-written kernels hand their own
+    backward kernels (``remat.KERNEL_RESIDUAL``: flash attention's
+    ``out`` and ``lse``, the gated delta rule's ``o``, chunk states and
+    inverses), so a kernel runs once a step and everything else is
+    recomputed; ``policy='nothing_saveable'`` runs the kernels again in
+    the backward too. Each time the block is traced under
+    ``jax.checkpoint`` it records one ``remat.trace`` span (no time in
+    it; ``block``, ``policy``) when span tracing is on.
+
     Example::
 
         stage = contrib.nn.Remat(resnet_stage)   # per-stage remat
@@ -121,10 +133,11 @@ class Remat(HybridBlock):
 
     def __init__(self, block, policy=None, **kwargs):
         super().__init__(**kwargs)
-        from ...remat import resolve_policy
+        from ...remat import policy_name, resolve_policy
         with self.name_scope():
             self.block = block
         self._policy = resolve_policy(policy)
+        self._policy_name = policy_name(policy)
 
     def forward(self, *args):
         from ...jit import _active, _notify_io, _notify_mutation
@@ -154,6 +167,8 @@ class Remat(HybridBlock):
         pvals = param_arrays(self.block)
         avals = aux_arrays(self.block)
         xs = [a.data_ if isinstance(a, NDArray) else a for a in args]
+        _obs_trace.record("remat.trace", time.perf_counter_ns(), 0,
+                          block=self.block.name, policy=self._policy_name)
         out, new_aux = jax.checkpoint(fn, policy=self._policy)(
             pvals, avals, *xs)
         # surface the sub-block's aux mutations (BN stats, rng key) to the

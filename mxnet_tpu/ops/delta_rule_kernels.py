@@ -57,6 +57,8 @@ from __future__ import annotations
 
 import functools
 
+from ..remat import kernel_residuals
+
 __all__ = ["gated_delta_rule_kernels", "unit_lower_inverse"]
 
 _EPS = 1e-6      # the L2 norm's, as ``linear_attention._l2norm``
@@ -591,8 +593,8 @@ def gated_delta_rule_kernels(q, k, v, g, beta, chunk=64, interpret=False,
                                                     nb_f))[0])
 
     def f_fwd(q, k, v, g, beta):
-        o, states, inverses = build("fwd_saved", nb_f)(
-            *operands(q, k, v, g, beta, nb_f))
+        o, states, inverses = kernel_residuals(*build("fwd_saved", nb_f)(
+            *operands(q, k, v, g, beta, nb_f)))
         return out_of(o), (q, k, v, g, beta, states, inverses)
 
     def f_bwd(res, dout):

@@ -78,6 +78,8 @@ import functools
 
 import numpy as _np
 
+from ..remat import kernel_residuals
+
 __all__ = ["flash_attention", "flash_attention_with_grad",
            "flash_attention_with_lse", "pallas_available"]
 
@@ -797,7 +799,7 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None,
         return out, lse.reshape(q.shape[:3] + (1,))
 
     def f_fwd(q, k, v, qo, ko):
-        out, lse = fwd(q, k, v, qo, ko)
+        out, lse = kernel_residuals(*fwd(q, k, v, qo, ko))
         return ((out, lse.reshape(q.shape[:3] + (1,))),
                 (_seq_minor(q), _seq_minor(k), _seq_minor(v), out, lse,
                  qo, ko))
@@ -852,7 +854,7 @@ def flash_attention_with_grad(q, k, v, causal=False, scale=None,
         return fwd(q, k, v)[0]
 
     def f_fwd(q, k, v):
-        out, lse = fwd(q, k, v)
+        out, lse = kernel_residuals(*fwd(q, k, v))
         return out, (_seq_minor(q), _seq_minor(k), _seq_minor(v), out, lse)
 
     def f_bwd(res, dout):
