@@ -117,7 +117,8 @@ def check_training(cell, topo):
     grads = sum(int(np.prod(p.shape)) * 4
                 for p in job.net.collect_params().values()
                 if p.grad_req != "null")
-    n_ring = manifest.module("loops", cell.traffic["loop"]).RING
+    n_ring = manifest.module("loops", cell.traffic["loop"]).ring_of(
+        cell.traffic)
     print(f"  beside the step the gluon net keeps {held / 1e9:.3f} GB of "
           f"parameters and {grads / 1e9:.3f} GB of gradient buffers on chip "
           f"0, and the ring of {n_ring} batches "

@@ -15,6 +15,14 @@ are then put through the check's forward pass only and reported, to show
 where the check stops seeing. No time is measured and no result line is
 printed. Without a chip it exits 3, as ``run.py``
 does; ``rehearse.py --degrade`` runs it at the tiny CPU preset.
+
+    python3 benchmarks/degrade.py --workload <training cell> --seed <n> \
+        --program-key sliding_window=8192
+
+plants a fault instead: the PROGRAM is built from the configuration
+with the keys given changed, the reference from the file's own, and the
+cell's check has to say NOT CORRECT (a window layer that sees every
+earlier key is the reading a largest-logit limit is held under).
 """
 from __future__ import annotations
 
@@ -49,16 +57,53 @@ def rounder(bits):
     return jax.jit(lower)
 
 
-def degraded_check(cell, seed, allow_cpu=False):
-    """True if the cell's check catches the program at ``WIDTHS[0]``."""
+def _built(cell, seed, allow_cpu, program_keys=None):
+    """(job, first batch) as the loop has them before the first step:
+    built, the ring made, what the traffic asks of the job before the
+    warm-up (a balanced routing) done. With ``program_keys`` the program
+    is built from the configuration with those keys changed and the
+    reference keeps the sizes of the file's own."""
     from benchmarks.harness import device, manifest
 
     devices, rec = device.require_chips(cell.chips, allow_cpu=allow_cpu)
     model = manifest.module("models", cell.config["model"])
     reference = manifest.module("references", cell.config["reference"])
-    job = model.build_trainer(cell.config, cell.traffic, seed, devices,
-                              reference)
-    x, y = job.make_ring(seed, 1)[0]
+    config, sizes = cell.config, getattr(model, "reference_sizes", None)
+    if program_keys:
+        config = {**cell.config, **program_keys}
+        if sizes is not None:
+            model.reference_sizes = lambda _: sizes(cell.config)
+    try:
+        job = model.build_trainer(config, cell.traffic, seed, devices,
+                                  reference)
+    finally:
+        if sizes is not None:
+            model.reference_sizes = sizes
+    ring = job.make_ring(seed, manifest.module(
+        "loops", cell.traffic["loop"]).ring_of(cell.traffic))
+    job.prepare(ring, cell.traffic,
+                lambda msg: print(f"[{cell.name}] {msg}", flush=True))
+    return job, ring[0]
+
+
+def faulted_check(cell, seed, program_keys, allow_cpu=False):
+    """True if the cell's check catches the program built with
+    ``program_keys`` in place of the configuration's."""
+    job, (x, y) = _built(cell, seed, allow_cpu, program_keys)
+    checked = job.check(float(job.step(x, y)), x, y, seed)
+    print(f"[{cell.name}] the program built with {program_keys}: "
+          f"{checked['said']}")
+    for note in checked["notes"]:
+        print(f"[{cell.name}] NOT CORRECT: {note}")
+    print(f"[{cell.name}] the faulted program "
+          + ("is caught" if checked["notes"] else "PASSED the check"),
+          flush=True)
+    return bool(checked["notes"])
+
+
+def degraded_check(cell, seed, allow_cpu=False):
+    """True if the cell's check catches the program at ``WIDTHS[0]``."""
+    job, (x, y) = _built(cell, seed, allow_cpu)
     lower = rounder(WIDTHS[0])
     job.trainer.params = {k: lower(v) for k, v in job.trainer.params.items()}
     first = float(job.step(x, y))       # taken once: it trains
@@ -93,13 +138,22 @@ def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--program-key", action="append", default=[],
+                    metavar="KEY=JSON", help="plant a fault: build the "
+                    "program, not the reference, with this key changed")
     args = ap.parse_args(argv)
+
+    import json
 
     from benchmarks.harness import cell as cell_mod
     from benchmarks.harness import device, manifest
 
     cell = manifest.Cell(manifest.load(), args.workload)
+    keys = {k: json.loads(v) for k, v in
+            (pair.split("=", 1) for pair in args.program_key)}
     try:
+        if keys:
+            return 0 if faulted_check(cell, args.seed, keys) else 1
         return 0 if degraded_check(cell, args.seed) else 1
     except device.NoChip as e:
         cell_mod.fail(str(e))

@@ -86,21 +86,54 @@ class Run:
         return [s for s in spans if lo <= s["t0_ns"] < hi]
 
 
+def program_scopes():
+    """The scopes the program's own name maps hold: every part of every
+    ``op_name`` (and of the names inside a fusion that has none) of
+    every executable in ``observability.perf``'s ledger. Empty for a
+    program from before the maps."""
+    from benchmarks import attribution
+    from mxnet_tpu.observability import perf
+
+    if not attribution.program_names_its_parts():
+        return set()
+    parts = set()
+    for key in perf.ledger():
+        for entry in (perf.op_names(key) or {}).values():
+            for name in [entry["op_name"], *entry["called"]]:
+                parts.update(name.split("/"))
+    return parts
+
+
 def _read_layers(run):
     """One reader per per-layer metric BENCHMARK.json lists for the cell.
     A reader that finds nothing to read returns None. On the chip that
     is a fault -- the span, counter or executable it reads was renamed
     or is gone, and the metric would leave the ledger unseen -- so the
     run fails and says which; a rehearsal, which has no device trace,
-    leaves the metric out."""
-    values, silent = {}, []
+    leaves the metric out. One answer is no fault: the driver runs the
+    PARENT of a PR with the readers the PR adds, and a program that
+    predates a scope has nothing under it. A reader whose module names
+    the scope it reads (``SCOPE``) is left out of the line where that
+    scope is in none of the program's own name maps; where the program
+    does name the scope and the reader still finds nothing, the run
+    fails as before."""
+    values, silent, scopes = {}, [], None
     for metric in run.cell.per_layer:
-        value = manifest.module("layer_metrics", metric["name"]).read(run)
-        if value is None:
-            silent.append(metric["name"])
-        else:
+        reader = manifest.module("layer_metrics", metric["name"])
+        value = reader.read(run)
+        if value is not None:
             values[metric["name"]] = {"value": float(value),
                                       "unit": metric["unit"]}
+            continue
+        scope = getattr(reader, "SCOPE", None)
+        if scope is not None and not run.rehearsal:
+            scopes = program_scopes() if scopes is None else scopes
+            if scope not in scopes:
+                run.log(f"{metric['name']}: the program predates this "
+                        f"metric (no scope {scope!r} in its name maps); "
+                        "left out of the line")
+                continue
+        silent.append(metric["name"])
     if silent and not run.rehearsal:
         raise RuntimeError(
             f"per-layer metric(s) {silent} of {run.cell.name!r} found "
@@ -144,6 +177,10 @@ def run_cell(cell, seed, seconds, trace, t_process_start, out_root,
     line = {"correct": bool(result["correct"]),
             "attempted": int(result["attempted"]),
             "failed": int(result["failed"]), "device": dev}
+    # what the loop reports besides (a routing's counts), then each
+    # number ``correct`` compared, beside its limit: last in the line
+    line.update(result.get("reported", {}))
+    compared = result.get("compared", {})
     if not trace:
         units = {m["name"]: m["unit"] for m in cell.end_to_end}
         missing = sorted(set(units) - set(run.end_to_end))
@@ -152,6 +189,7 @@ def run_cell(cell, seed, seconds, trace, t_process_start, out_root,
                                f"report {missing}")
         line["metrics"] = {k: {"value": float(run.end_to_end[k]),
                                "unit": units[k]} for k in units}
+        line["compared"] = compared
         return line
 
     path = run.tracer.path()
@@ -174,7 +212,15 @@ def run_cell(cell, seed, seconds, trace, t_process_start, out_root,
     elif not rehearsal:
         raise RuntimeError(f"{path} holds no device plane")
     line["metrics"] = _read_layers(run)
+    line["compared"] = compared
     return line
+
+
+def compared_lines(line):
+    """The numbers compared, each beside its limit, as lines for the end
+    of standard error."""
+    return [f"compared {name}: {pair['value']!r} (limit {pair['limit']!r})"
+            for name, pair in line.get("compared", {}).items()]
 
 
 def fail(msg, code=3):

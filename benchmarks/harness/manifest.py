@@ -99,6 +99,8 @@ class Cell:
         self.config = _read_json(os.path.join(root, cfg["file"]))
         self.traffic = _read_json(os.path.join(
             root, "benchmarks", "traffic", entry["traffic"] + ".json"))
+        # for what a model file has to say about it by name
+        self.traffic.setdefault("name", entry["traffic"])
         self.end_to_end = [m for m in manifest["end_to_end"]
                            if _applies(m, name)]
         self.per_layer = [m for m in manifest["per_layer"]

@@ -1,6 +1,8 @@
 """Device time of attention's backward pass per training step, chip 0:
-backward ops under the ``attention`` scope -- today the scan's ``while``
-and its body (``benchmarks/attribution.py``). Layer: kernels."""
+backward ops under the ``attention`` scope, whatever implements them
+(``benchmarks/attribution.py``): since PR 31 the kernel
+``flash_attention_bwd`` and the ``D`` row sum beside it, before it a
+``lax.scan``'s ``while`` and its body. Layer: kernels."""
 from benchmarks import attribution
 
 

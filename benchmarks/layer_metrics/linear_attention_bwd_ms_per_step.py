@@ -4,6 +4,10 @@ recomputed forward of a ``Remat`` layer among them
 (``benchmarks/scopes.py``). Layer: kernels."""
 from benchmarks import scopes
 
+# the scope this reader needs in the program's names: a program that
+# predates it is left out of the line, not failed (harness/cell.py)
+SCOPE = "linear_attention"
+
 
 def read(run):
     return scopes.scope_ms(run, "linear_attention", "backward")

@@ -5,6 +5,10 @@ pass a ``Remat`` half recomputes among them
 sequence are ``attention_bwd_ms_per_step`` less this. Layer: kernels."""
 from benchmarks import window_attention
 
+# the scope this reader needs in the program's names: a program that
+# predates it is left out of the line, not failed (harness/cell.py)
+SCOPE = "window_attention"
+
 
 def read(run):
     return window_attention.scope_ms(run, "backward")

@@ -5,7 +5,8 @@ mesh. The method is ``bench.py:_throughput``'s (device-resident batch,
 steps chained by the donated state, time ends on ``block_until_ready``
 of the last loss), made to the benchmark's contract: the work is drawn
 from ``--seed`` (a ring of ``RING`` batches made on the device), the
-window lasts ``--seconds``, and a sync about once a second of steps
+window lasts ``--seconds`` (the traffic file's ``ring`` gives another
+number of batches than ``RING``), and a sync about once a second of steps
 bounds how far the host runs ahead. The sync waits for the step BEFORE
 the newest one, so one step is always queued behind the one that runs
 and the sync itself leaves no bubble on the device.
@@ -24,7 +25,8 @@ from __future__ import annotations
 
 import time
 
-RING = 8                # seeded batches, reused in turn
+RING = 8                # seeded batches, reused in turn, unless the
+                        # traffic file gives its own ``ring``
 WARMUP_STEPS = 2
 SYNC_EVERY_S = 1.0      # of steps, by the warm-up steps' period
 TRACE_SECONDS = 3
@@ -51,6 +53,25 @@ def _steps(job, ring, run, seconds, group, losses, marks):
     return time.perf_counter()
 
 
+def _held(run, when):
+    """Where the model has expert layers: the assignments each layer's
+    held experts got in the newest step (the program's own counter),
+    logged and returned, so that the run shows whether the routing held
+    through the window (``TrainJob.routing_check``). None elsewhere."""
+    read = getattr(run.model, "expert_tokens", None)
+    counts = read() if read is not None else None
+    if counts:
+        run.log(f"assignments to the experts held, {when}: " + "; ".join(
+            f"{int(c.sum())} (per expert {int(c.min())}-{int(c.max())})"
+            for c, _ in counts))
+    return counts
+
+
+def ring_of(traffic):
+    """Batches in the ring of a job."""
+    return int(traffic.get("ring", RING))
+
+
 def run(run):
     import jax
     import numpy as np
@@ -60,12 +81,14 @@ def run(run):
     traffic = run.traffic
     job = run.model.build_trainer(run.config, traffic, run.seed,
                                   run.devices, run.reference)
-    ring = job.make_ring(run.seed, RING)
+    ring = job.make_ring(run.seed, ring_of(traffic))
+    job.prepare(ring, traffic, run.log)
     first = job.step(*ring[0])
     first.block_until_ready()
+    held_first = _held(run, "first step")
     t_warm = time.perf_counter()
     for i in range(WARMUP_STEPS):
-        job.step(*ring[(i + 1) % RING]).block_until_ready()
+        job.step(*ring[(i + 1) % len(ring)]).block_until_ready()
     period = (time.perf_counter() - t_warm) / WARMUP_STEPS
     group = max(2, round(SYNC_EVERY_S / period))
     before = job.program_counters()
@@ -84,6 +107,7 @@ def run(run):
         run.tracer.stop()
         trace_steps = len(losses) - steps
     run.peak_bytes_after_window()
+    held_last = _held(run, f"step {WARMUP_STEPS + 1 + len(losses)}")
 
     values = np.asarray(jax.device_get(losses), np.float64)
     bad = int(np.sum(~np.isfinite(values)))
@@ -101,6 +125,8 @@ def run(run):
     checked = job.check(float(first), *ring[0], run.seed)
     run.log(checked["said"])
     notes += checked["notes"]
+    routed = job.routing_check(held_first, held_last)
+    notes += routed["notes"]
     if bad:
         notes.append(f"{bad} of {len(values)} losses are not finite")
 
@@ -109,4 +135,6 @@ def run(run):
     return {"end_to_end": {"train_items_per_s_per_chip":
                            rate / len(run.devices)},
             "attempted": len(values), "failed": bad,
-            "correct": not notes, "notes": notes}
+            "correct": not notes, "notes": notes,
+            "reported": routed["reported"],
+            "compared": {**checked["compared"], **routed["compared"]}}

@@ -7,8 +7,58 @@ the checks that the step stayed on that path (one captured executable,
 no eager fallback, no elastic out-of-memory retry as microbatches),
 taken from ``chip_smoke.py``'s train phase, and the comparison with the
 float32 reference that decides ``correct`` in training.
+
+Also what ``"routing": "balanced"`` in a traffic file asks of a
+configuration that holds a share of its experts (``benchmarks/README.md``,
+"A cell that holds a share of the experts"): ``TrainJob.prepare``,
+between the ring and the warm-up, gives each expert layer a routing bias
+under which the ring routes evenly (``balance_routing``): layer after
+layer in the net's order, the published balancer's rule (the bias of an
+expert with more than the mean load goes down, of one with less goes up)
+is iterated on the ring's own scores until every expert's share of the
+ring's assignments is within ``BALANCE_TOLERANCE`` of ``top_k /
+experts``. The layer still routes over all its experts and computes its
+own; the bias is then a weight like any other, which the reference is
+handed. A bias is one vector a layer, so it evens the ring as a whole:
+with weights from a seed one sequence's tokens route alike and
+another's elsewhere, and only a ring of one batch (the traffic's
+``"ring": 1``) is even in every step. Nothing holds that routing
+through the window but the configuration's step size (the router
+trains like every other weight); ``TrainJob.routing_check`` holds the
+step's own counts to it, at the first step and at the last, and the
+run is not correct where they left it.
 """
 from __future__ import annotations
+
+from benchmarks.harness.manifest import ManifestError
+
+BALANCE_STEP = 0.05     # the first move of an expert's bias, in score
+BALANCE_ROUNDS = 200    # the rule has failed where this many do not do
+BALANCE_TOLERANCE = 0.01    # of the mean load: what the walk evens to
+# What ``correct`` holds the step's own counter to (``routing_check``;
+# PERF.md section 2 has the readings, my chip runs, PR 37). An expert
+# held, at the first step, against ``tokens x top_k / experts``: sound
+# runs read at most 0.020 (the step's kernels round a score or two in a
+# hundred the other way from the walk), weights from a seed with no
+# bias set 0.52 and more. A layer's experts held together, at the last
+# step, against their share: sound runs at most 0.113 (a batch stepped
+# on fifty times at 1e-7 still turns one weight in twenty-five by a
+# unit of bf16), a step size of 1e-5 0.40 and more within five steps.
+ROUTING_FIRST_STEP_LIMIT = 0.05
+ROUTING_LAST_STEP_LIMIT = 0.25
+
+
+def _caster(dtype):
+    """v -> v as ``ShardedTrainer`` casts a parameter or an input under
+    the policy: floating values to ``dtype`` (None: as they are)."""
+    import jax.numpy as jnp
+
+    def cast(v):
+        if dtype and jnp.issubdtype(v.dtype, jnp.floating):
+            return v.astype(dtype)
+        return v
+
+    return cast
 
 
 class TrainJob:
@@ -16,7 +66,7 @@ class TrainJob:
 
     def __init__(self, net, trainer, step, items_per_step, make_ring,
                  reference_weights, reference_fn, train, positions=None,
-                 statistics=None):
+                 statistics=None, routing_parts=None):
         self.net = net
         self.trainer = trainer
         self.step = step                        # step(x, y) -> device loss
@@ -34,7 +84,69 @@ class TrainJob:
         # (the forward pass's moved auxiliary state) -> (means,
         # variances) as the reference gives them; None without such layers
         self.statistics = statistics
+        # () -> the trunk as ``Part``s in the order the tokens pass it;
+        # None for a configuration without expert layers
+        self.routing_parts = routing_parts
+        # assignments an expert gets of one batch at an even share,
+        # ``tokens x top_k / experts``: set by ``balance_routing``
+        self.expert_share = None
         self._forward = self._reference = None
+
+    def prepare(self, ring, traffic, log=print):
+        """What the traffic file asks of the job once the ring exists,
+        before the warm-up; nothing where it asks nothing."""
+        asked = traffic.get("routing")
+        if asked is None:
+            return
+        if asked != "balanced":
+            raise ManifestError(f"traffic {traffic.get('name')!r}: routing "
+                                f"{asked!r} is not known; known: 'balanced'")
+        balance_routing(self, ring, log)
+
+    def routing_check(self, first, last):
+        """Holds what the traffic says of the routing to what ran, by
+        the program's own counter: ``first`` and ``last`` are what the
+        expert layers' ``expert_tokens`` state held after the first step
+        and after the window's last, per layer (assignments to each
+        expert held, tokens that chose none). Every expert held is
+        within ``ROUTING_FIRST_STEP_LIMIT`` of an even share at the
+        first step, and every layer's experts held together within
+        ``ROUTING_LAST_STEP_LIMIT`` of theirs at the last. Returns
+        ``notes``, ``compared`` and ``reported`` (the counts, for the
+        result's line); all empty where the traffic asked for no
+        routing."""
+        import numpy as np
+
+        if self.expert_share is None:
+            return {"notes": [], "compared": {}, "reported": {}}
+        if not first or not last:
+            return {"notes": ["the traffic asks for a balanced routing and "
+                              "the model file reads no expert_tokens"],
+                    "compared": {}, "reported": {}}
+        first, last = (np.asarray([c for c, _ in counts], np.float64)
+                       for counts in (first, last))
+        facts = {
+            "routing_first_step": float(np.max(np.abs(
+                first / self.expert_share - 1.0))),
+            "routing_last_step": float(np.max(np.abs(
+                last.sum(axis=1) / (self.expert_share * last.shape[1])
+                - 1.0)))}
+        limit = {"routing_first_step": ROUTING_FIRST_STEP_LIMIT,
+                 "routing_last_step": ROUTING_LAST_STEP_LIMIT}
+        what = {"routing_first_step": "an expert held, at the first step,",
+                "routing_last_step": "a layer's experts held, at the "
+                                     "window's last step,"}
+        return {
+            "notes": [f"{what[k]} got {facts[k]:.4f} off an even share of "
+                      f"the assignments ({self.expert_share:.0f} an expert),"
+                      f" over the limit {limit[k]}" for k in facts
+                      if not facts[k] <= limit[k]],
+            "compared": {k: {"value": facts[k], "limit": limit[k]}
+                         for k in facts},
+            "reported": {"held_assignments": {
+                "even_share_an_expert": self.expert_share,
+                "first_step": first.astype(int).tolist(),
+                "last_step": last.astype(int).tolist()}}}
 
     def _replicated(self, tree):
         """On every chip of the mesh, so that a function jitted over a
@@ -66,12 +178,7 @@ class TrainJob:
         from mxnet_tpu import parallel
 
         fwd = parallel.functional_call(self.net, train=True)
-        dtype = self.train["compute_dtype"]
-
-        def cast(v):
-            if dtype and jnp.issubdtype(v.dtype, jnp.floating):
-                return v.astype(dtype)
-            return v
+        cast = _caster(self.train["compute_dtype"])
 
         def outputs(params, aux, x, positions):
             out, moved = fwd({k: cast(v) for k, v in params.items()}, aux,
@@ -107,8 +214,9 @@ class TrainJob:
         reference far closer than a logit does after fifty layers of
         bf16. ``params`` stands in for the initial weights on the
         program's side only (``degrade.py``). Returns ``notes`` (the
-        reasons it is not correct), ``said`` (the line for the log) and
-        the differences."""
+        reasons it is not correct), ``said`` (the line for the log), the
+        differences, and under ``compared`` each of them beside its
+        limit, for the result's line."""
         import math
 
         import jax
@@ -166,7 +274,9 @@ class TrainJob:
                  if not facts[k] <= tol[k]]
         if not math.isfinite(first_loss):
             notes.append(f"the first-step loss is {first_loss}")
-        return {"notes": notes, "said": said, **facts}
+        return {"notes": notes, "said": said, **facts,
+                "compared": {k: {"value": facts[k], "limit": tol[k]}
+                             for k in facts}}
 
     def program_counters(self):
         from mxnet_tpu import capture
@@ -198,6 +308,7 @@ def make_trainer(net, config, traffic, devices):
     """(trainer, captured step) over ``traffic["mesh"]`` on ``devices``."""
     from mxnet_tpu import capture, gluon, parallel
 
+    refuse_without_bias(net, config, traffic)
     train = config["train"]
     mesh = parallel.create_mesh(dict(traffic["mesh"]), devices)
     trainer = parallel.ShardedTrainer(
@@ -205,3 +316,195 @@ def make_trainer(net, config, traffic, devices):
         dict(train["optimizer_params"]), mesh=mesh,
         dtype=train["compute_dtype"], remat=train.get("remat") or False)
     return trainer, capture.capture(trainer)
+
+
+# ------------------------------------------- a share of the experts held
+
+class Part:
+    """One part of a net's trunk, as ``balance_routing`` walks it:
+    ``fn`` takes what the part before it gave (the first part: the
+    tokens) and is made of the gluon ``blocks``. Where the part holds an
+    expert layer, ``seen`` (a block of the net) gives what that layer's
+    router sees of the part's input, and ``router`` says how it scores:
+    ``{"weight", "bias"}`` (the two parameters' names), ``"top_k"``,
+    ``"score_func"`` ('sigmoid' or 'softmax') and ``"held"`` ((first
+    expert, experts) this chip holds: the log says what one batch gives
+    them)."""
+
+    def __init__(self, blocks, fn, seen=None, router=None):
+        self.blocks, self.fn = list(blocks), fn
+        self.seen, self.router = seen, router
+
+
+def refuse_without_bias(net, config, traffic):
+    """Refuses, by name, a traffic that asks for a balanced routing of
+    a configuration without a routing bias of its own. Before the
+    trainer is built, so that nothing is compiled for a refused cell."""
+    if "routing" not in traffic:
+        return
+    if not [n for n in net.collect_params().keys()
+            if n.endswith("expert_bias")]:
+        raise ManifestError(
+            f"traffic {traffic.get('name')!r} asks for routing "
+            f"{traffic['routing']!r}, and configuration "
+            f"{config.get('name')!r} has no routing bias of its own (no "
+            "expert_bias parameter): a balanced routing is what a "
+            "per-expert bias keeps. A model without one says under "
+            "'assumed' what holds its balance before it may use such a "
+            "traffic (benchmarks/README.md)")
+
+
+def _pure(blocks, fn):
+    """(values by parameter name, x) -> fn(x), with the blocks'
+    parameters and auxiliary state taken from the values: the view
+    ``ShardedTrainer`` differentiates, of a part of the net."""
+    from mxnet_tpu import gluon, parallel
+
+    class _Of(gluon.Block):
+        def __init__(self):
+            super().__init__(prefix="")
+            for blk in blocks:
+                self.register_child(blk)
+
+        def forward(self, x):
+            return fn(x)
+
+    part = _Of()
+    own = set(part.collect_params().keys())
+    pure = parallel.functional_call(part, train=True)
+    return lambda values, x: pure(
+        {k: v for k, v in values.items() if k in own}, {}, x)[0]
+
+
+def balanced_bias(scores, top_k, tolerance, step=BALANCE_STEP,
+                  rounds=BALANCE_ROUNDS):
+    """``scores`` (assignments' tokens, experts) float32 -> (bias,
+    assignments to each expert under it, rounds taken): the published
+    balancer's rule, ``bias -= step * sign(load - mean load)``, on these
+    tokens until every expert's load is within ``tolerance`` of the
+    mean, ``tokens * top_k / experts``. An expert's step halves each
+    time its load crosses the mean, so the rule settles instead of
+    swinging; the bias starts at zero. Traced: call it under ``jit``."""
+    import jax
+    import jax.numpy as jnp
+
+    tokens, experts = scores.shape
+    mean = tokens * top_k / experts
+
+    def loads(bias):
+        moved = scores + bias
+        kth = jax.lax.top_k(moved, top_k)[0][:, -1:]
+        return jnp.sum(moved >= kth, axis=0, dtype=jnp.float32)
+
+    def unsettled(state):
+        _, _, _, load, done = state
+        return (done < rounds) & jnp.any(jnp.abs(load - mean)
+                                         > tolerance * mean)
+
+    def move(state):
+        bias, size, before, load, done = state
+        side = jnp.sign(load - mean)
+        size = jnp.where(side * before < 0, size / 2, size)
+        bias = bias - size * side
+        return bias, size, side, loads(bias), done + 1
+
+    zero = jnp.zeros(experts, jnp.float32)
+    bias, _, _, load, done = jax.lax.while_loop(
+        unsettled, move, (zero, jnp.full(experts, step, jnp.float32), zero,
+                          loads(zero), 0))
+    return bias, load, done
+
+
+def balance_routing(job, ring, log=print):
+    """Sets every expert layer's routing bias, in the net and in the
+    trainer's state, to what ``balanced_bias`` gives on the ring: one
+    compiled walk of the trunk under the training policy (parameters
+    cast as ``ShardedTrainer`` casts them, auxiliary state as it is),
+    each batch of the ring in turn through a part, a layer's bias
+    settled on all of them to ``BALANCE_TOLERANCE`` before the part that
+    holds the layer runs. The walk is not what the window times: the
+    step's own counts are held to it afterwards (``routing_check``).
+    Span ``setup.balance_routing``."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu import parallel
+    from mxnet_tpu.observability import trace as obs_trace
+
+    if job.routing_parts is None:
+        raise ManifestError("the model file gives no routing_parts: it "
+                            "cannot take a traffic with balanced routing")
+    parts = job.routing_parts()
+    cast = _caster(job.train["compute_dtype"])
+    tokens = jnp.stack([x for x, _ in ring])
+
+    def walk(params, aux, tokens):
+        values = {**{k: cast(v) for k, v in params.items()}, **aux}
+        h, found = tokens, {}
+        for part in parts:
+            if part.router is not None:
+                r = part.router
+                seen = _pure([part.seen], part.seen)
+                x = jax.lax.map(lambda one: seen(values, one), h)
+                logits = jnp.einsum(
+                    "...d,ed->...e", x.astype(jnp.float32),
+                    values[r["weight"]].astype(jnp.float32))
+                scores = jax.nn.sigmoid(logits) \
+                    if r["score_func"] == "sigmoid" \
+                    else jax.nn.softmax(logits, axis=-1)
+                bias, load, rounds = balanced_bias(
+                    scores.reshape(-1, scores.shape[-1]), r["top_k"],
+                    BALANCE_TOLERANCE)
+                # each batch's own assignments under the ring's bias
+                moved = scores.reshape(len(ring), -1, scores.shape[-1]) + bias
+                kth = jax.lax.top_k(moved, r["top_k"])[0][..., -1:]
+                found[r["bias"]] = (bias, load, rounds, jnp.sum(
+                    moved >= kth, axis=1, dtype=jnp.float32))
+                values[r["bias"]] = bias.astype(values[r["bias"]].dtype)
+            fn = _pure(part.blocks, part.fn)
+            h = jax.lax.map(lambda one: fn(values, one), h)
+        return found
+
+    t0 = time.perf_counter()
+    with obs_trace.span("setup.balance_routing", layers=sum(
+            p.router is not None for p in parts), batches=len(ring)):
+        args = (job._replicated(parallel.param_arrays(job.net)),
+                job._replicated(parallel.aux_arrays(job.net)), tokens)
+        lowered = jax.jit(walk).lower(*args)
+        t_lowered = time.perf_counter()
+        compiled = lowered.compile()
+        t_compiled = time.perf_counter()
+        found = jax.device_get(compiled(*args))
+        t_ran = time.perf_counter()
+        named = job.net.collect_params()
+        held = {p.router["bias"]: p.router["held"] for p in parts
+                if p.router is not None}
+        for name, (bias, load, rounds, a_batch) in found.items():
+            off = float(np.max(np.abs(load / load.mean() - 1.0)))
+            first, count = held[name]
+            here = a_batch[:, first:first + count].sum(axis=1)
+            log(f"balanced routing, {name}: {int(rounds)} rounds of the "
+                f"balancer's rule; over the ring's {int(load.sum())} "
+                f"assignments each of {load.size} experts has "
+                f"{int(load.min())}-{int(load.max())} (mean "
+                f"{load.mean():.0f}, farthest {100 * off:.2f} % off); bias "
+                f"{bias.min():+.4f} to {bias.max():+.4f}; the {count} experts "
+                f"held get {[int(n) for n in here]} of a batch")
+            if not off <= BALANCE_TOLERANCE:
+                raise RuntimeError(
+                    f"{name}: the balancer's rule left an expert "
+                    f"{100 * off:.2f} % off the mean load after "
+                    f"{int(rounds)} rounds (tolerance "
+                    f"{100 * BALANCE_TOLERANCE} %)")
+            job.expert_share = float(load.mean()) / len(ring)
+            named[name].set_data(bias)
+            job.trainer.aux[name] = jax.device_put(
+                jnp.asarray(bias, job.trainer.aux[name].dtype),
+                job.trainer.aux[name].sharding)
+    log(f"balanced routing: {len(found)} expert layers in "
+        f"{time.perf_counter() - t0:.2f} s of set-up (the walk traced and "
+        f"lowered in {t_lowered - t0:.2f}, compiled or read from the cache "
+        f"in {t_compiled - t_lowered:.2f}, run in {t_ran - t_compiled:.2f})")

@@ -9,7 +9,9 @@ the backward pass) -> ``capture``.
 Also: the model's FLOPs per token from its shapes, the ring of seeded
 token batches, the positions whose logits the training check compares,
 the laying of the program's parameters into the plain reference's tree,
-and the expert layers' token counts of the last step for the readers.
+the trunk as the parts ``sharded.balance_routing`` walks (a traffic
+with ``"routing": "balanced"``), and the expert layers' token counts of
+the last step for the readers.
 """
 from __future__ import annotations
 
@@ -134,6 +136,31 @@ def reference_weights(net):
             "norm": w(net.norm.weight), "head_w": w(net.head.weight)}
 
 
+def routing_parts(net, config):
+    """The trunk in the order the tokens pass it, for
+    ``sharded.balance_routing``: the embedding (scaled as ``TrinityLM``
+    scales it), then each layer's two halves; the second half of an
+    expert layer names its router, which sees ``pre_mlp_layernorm`` of
+    the half's input."""
+    def embed(tokens):
+        h = net.embed(tokens)
+        return h if net._embed_scale is None else h * net._embed_scale
+
+    parts = [sharded.Part([net.embed], embed)]
+    for blk in net.blocks:
+        parts.append(sharded.Part([blk.mix], blk.mix))
+        router = None
+        if hasattr(blk.mlp, "expert_bias"):
+            router = {"weight": blk.mlp.router_weight.name,
+                      "bias": blk.mlp.expert_bias.name,
+                      "top_k": config["num_experts_per_tok"],
+                      "score_func": config["score_func"],
+                      "held": held(config)}
+        parts.append(sharded.Part([blk.ffn], blk.ffn,
+                                  seen=blk.pre_mlp_layernorm, router=router))
+    return parts
+
+
 def _build_net(config, seed, impl, remat):
     import mxnet_tpu as mx
 
@@ -182,7 +209,8 @@ def build_trainer(config, traffic, seed, devices, reference):
         net, trainer, step, batch * t, make_ring,
         lambda: reference_weights(net),
         functools.partial(reference.check_outputs,
-                          sizes=reference_sizes(config)), train, positions)
+                          sizes=reference_sizes(config)), train, positions,
+        routing_parts=lambda: routing_parts(net, config))
     return _JOB
 
 

@@ -30,8 +30,9 @@ precision, nothing imported from the program. The layer equations
   plus the shared expert, ungated.
 
 Departures from the published model, because the configuration under
-test has them: ``expert_bias`` stays what the weights say (zero at
-first; the update that ``load_balance_coeff`` drives is not built), no
+test has them: ``expert_bias`` stays what the weights say (zero, or
+what a traffic with balanced routing set it to before the first step;
+the update that ``load_balance_coeff`` drives is not built), no
 auxiliary loss, no expert groups (``n_group`` = ``topk_group`` = 1).
 
 Weights arrive as a plain tree; a dense matrix is (out, in):

@@ -8,8 +8,10 @@ The cell is an entry of ``workloads`` in BENCHMARK.json; inputs and
 weights are made from ``--seed``. The last line of standard output is
 one JSON object: ``correct``, ``attempted``, ``failed``, ``metrics``
 (the cell's end-to-end metrics with ``--trace 0``, its per-layer
-metrics with ``--trace 1``), ``device`` and, traced, ``breakdown``.
-Everything else goes on earlier lines or under ``.bench_out/``.
+metrics with ``--trace 1``), ``device``, traced ``breakdown``, and last
+``compared``: each number ``correct`` compared beside its limit, which
+are also the last lines of standard error. Everything else goes on
+earlier lines or under ``.bench_out/``.
 
 Without an accelerator, or with fewer chips than the cell asks for, it
 exits 3 and prints no result. The compile cache is the program's own
@@ -55,6 +57,8 @@ def main(argv=None):
                                  os.path.join(ROOT, ".bench_out"))
     except device.NoChip as e:
         cell_mod.fail(str(e), code=3)
+    for said in cell_mod.compared_lines(line):
+        print(said, file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
     return 0
 

@@ -118,15 +118,30 @@ def seen_pairs(t, window):
     return window * t - window * (window - 1) / 2
 
 
-def least_ms(config, traffic, peaks, products, tensors):
+def full_scope_ms(run, phase):
+    """ms a step of ``phase`` ops under ``attention`` and not under the
+    scope: the layers of a model with window layers that see the whole
+    sequence. None where the program has no op under the scope."""
+    under = scope_ms(run, phase)
+    whole = attribution.attention_ms(run, phase)
+    if under is None or whole is None:
+        return None
+    return whole - under
+
+
+def least_ms(config, traffic, peaks, products, tensors, full=False):
     """(least time in ms of the step's window layers in one pass, which
     bound): max(FLOPs / bf16 peak, bytes / HBM peak) a layer, x window
     layers. FLOPs: ``products`` matrix products of B x H x D
     multiply-adds a seen (query, key) pair. Bytes: ``tensors`` arrays of
-    B x T x H x D read or written once, in the compute dtype."""
+    B x T x H x D read or written once, in the compute dtype. ``full``:
+    of the other layers of ``layer_types`` instead, whose window is the
+    sequence."""
     b, t = int(traffic["batch"]), int(traffic["seq_len"])
     heads, size = int(config["num_attention_heads"]), int(config["head_dim"])
     n_layers, window = window_layers(config)
+    if full:
+        n_layers, window = len(config["layer_types"]) - n_layers, t
     flops = 2 * products * b * heads * size * seen_pairs(t, window)
     moved = tensors * b * t * heads * size \
         * _BYTES[config["train"]["compute_dtype"]]

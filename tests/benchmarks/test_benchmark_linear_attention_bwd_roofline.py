@@ -52,14 +52,14 @@ def test_compute_bound_where_the_heads_are_wide_enough():
 
 
 def test_listed_for_the_cell_that_runs_linear_attention():
-    entry = next(m for m in manifest.load()["per_layer"]
-                 if m["name"] == "linear_attention_bwd_roofline")
-    assert entry == {"name": "linear_attention_bwd_roofline", "unit": "%",
-                     "better": "higher", "source": "device_trace",
-                     "layer": "kernels",
-                     "moves": "train_items_per_s_per_chip",
-                     "workloads": [CELL]}
-    assert manifest.load()["per_layer"][-1] == entry
+    """Wherever it stands in ``per_layer``: later PRs append entries."""
+    found = [m for m in manifest.load()["per_layer"]
+             if m["name"] == "linear_attention_bwd_roofline"]
+    assert found == [{"name": "linear_attention_bwd_roofline", "unit": "%",
+                      "better": "higher", "source": "device_trace",
+                      "layer": "kernels",
+                      "moves": "train_items_per_s_per_chip",
+                      "workloads": [CELL]}]
 
 
 def test_share_of_the_recorded_steps(monkeypatch):
@@ -87,5 +87,6 @@ def test_nothing_to_read_is_none():
     assert READER.read(run) is None
     run.facts["scopes"] = {("moe", "forward"): 1.0}
     assert READER.read(run) is None
+    # forward ops only: a share of a roofline is never 0
     run.facts["scopes"] = {("linear_attention", "forward"): 1.0}
-    assert READER.read(run) == 0.0
+    assert READER.read(run) is None

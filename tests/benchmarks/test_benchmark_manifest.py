@@ -20,8 +20,11 @@ WITH_PENDING = manifest.load(pending=True)
 NAME = re.compile(r"^[A-Za-z0-9][A-Za-z0-9_.\-]{0,63}$")
 LAYER = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
-WIDTH = re.compile(r"(hidden|intermediate|latent|state|proj|head_dim|"
-                   r"_dim$|_rank$|expansion|experts_per_tok|n_embd|n_inner)")
+# a width; the catalog's own key for the depth, ``num_hidden_layers``, is
+# not one, and ``reduced`` may name it
+WIDTH = re.compile(r"(hidden(?!_layers$)|intermediate|latent|state|proj|"
+                   r"head_dim|_dim$|_rank$|expansion|experts_per_tok|"
+                   r"n_embd|n_inner)")
 CELLS = [w["name"] for w in MANIFEST["workloads"]]
 ALL_CELLS = [w["name"] for w in WITH_PENDING["workloads"]]
 
@@ -73,6 +76,32 @@ def test_configurations_have_their_files_and_cut_no_width():
         assert os.path.exists(_bench("references",
                                      body["reference"] + ".py"))
         assert cfg["source"].startswith("http")
+
+
+def test_reduced_may_give_the_depth_under_the_catalogs_key():
+    assert not WIDTH.search("num_hidden_layers")
+    assert not WIDTH.search("num_layers")
+    for width in ("hidden_size", "intermediate_size", "kv_lora_rank",
+                  "moe_intermediate_size", "head_dim", "v_head_dim",
+                  "num_experts_per_tok", "ssm_state_size",
+                  "hidden_layers_dim"):
+        assert WIDTH.search(width), width
+
+
+def test_five_cells_one_on_four_chips_and_every_configuration_has_one():
+    assert len(CELLS) == 5
+    assert [w["name"] for w in MANIFEST["workloads"]
+            if w["chips"] == 4] == ["resnet50_train_dp4"]
+    assert "trinitymini_train_seq8192_balanced" in CELLS
+    assert "trinitymini_train_seq8192" not in CELLS
+    assert {w["config"] for w in MANIFEST["workloads"]} \
+        == {c["name"] for c in MANIFEST["configs"]}
+
+
+def test_only_the_serving_cell_waits_under_pending():
+    assert sorted(os.listdir(_bench("pending"))) \
+        == ["gpt2m_serve_chat_steady.json"]
+    assert set(ALL_CELLS) - set(CELLS) == {"gpt2m_serve_chat_steady"}
 
 
 def test_cells_are_unique_pairs_and_few_take_four_chips():

@@ -115,6 +115,64 @@ def test_a_silent_reader_fails_a_chip_run_and_is_left_out_of_a_rehearsal():
         cell_mod._read_layers(_Silent(rehearsal=False))
 
 
+class _NoTrace:
+    """A chip run of Qwen3-Next's cell whose scope readers find nothing
+    (no device trace), over a program whose name maps are given."""
+    rehearsal, trace, devices, peak_bytes = False, None, [None], None
+
+    def __init__(self, metrics):
+        self.cell = manifest.Cell(MANIFEST, "qwen3next_train_seq8192")
+        self.cell.per_layer = [m for m in self.cell.per_layer
+                               if m["name"] in metrics]
+        self.config, self.traffic = self.cell.config, self.cell.traffic
+        self.facts, self.lines, self.model = {}, [], object()
+
+    def log(self, msg):
+        self.lines.append(msg)
+
+
+def test_a_reader_may_say_the_program_predates_its_scope(monkeypatch):
+    """The driver runs a PR's parent with the PR's readers: a reader
+    that names its ``SCOPE`` is left out where no name map of the
+    program holds that scope, fails the run and is named where one does,
+    and a reader that names none fails as before."""
+    from benchmarks.harness import cell as cell_mod
+    from mxnet_tpu.observability import perf
+
+    def program(*op_names):
+        monkeypatch.setattr(perf, "ledger", lambda: {"sharded_step@abc": {
+            "label": "sharded_step"}})
+        monkeypatch.setattr(perf, "op_names", lambda key: {
+            f"fusion.{i}": {"op_name": name, "kernel": "", "called": []}
+            for i, name in enumerate(op_names)})
+
+    metrics = ("moe_fwd_ms_per_step", "linear_attention_bwd_roofline")
+    older = "jit(sharded_step)/jvp(net0)/net0_blocks_b0_moe/dot_general"
+    program(older)
+    assert cell_mod.program_scopes() >= {"jvp(net0)", "dot_general"}
+    run = _NoTrace(metrics)
+    assert cell_mod._read_layers(run) == {}
+    said = "\n".join(run.lines)
+    for name in metrics:
+        assert f"{name}: the program predates this metric" in said
+    # the program names one of the two scopes: that reader is silent
+    program(older, "jit(sharded_step)/jvp(net0)/net0_blocks_b0_moe/moe/"
+            "moe_router/top_k")
+    with pytest.raises(RuntimeError, match=r"\['moe_fwd_ms_per_step'\]"):
+        cell_mod._read_layers(_NoTrace(metrics))
+    # a name inside a fusion that has none of its own counts too
+    monkeypatch.setattr(perf, "op_names", lambda key: {"fusion.1": {
+        "op_name": "", "kernel": "", "called": [
+            "jit(sharded_step)/jvp(net0)/b0_linattn/linear_attention/mul"]}})
+    with pytest.raises(RuntimeError,
+                       match=r"\['linear_attention_bwd_roofline'\]"):
+        cell_mod._read_layers(_NoTrace(metrics))
+    # a reader without a SCOPE has no such answer
+    program(older)
+    with pytest.raises(RuntimeError, match="moe_busiest_expert_tokens"):
+        cell_mod._read_layers(_NoTrace(("moe_busiest_expert_tokens",)))
+
+
 @pytest.mark.parametrize("entry,args", [
     ("run.py", ["--workload", "resnet50_train_bs256", "--seed", "1",
                 "--seconds", "1", "--trace", "0"]),

@@ -213,14 +213,24 @@ def test_a_program_without_the_scopes_or_the_counts_leaves_the_metrics_out(
 
 
 def test_the_new_metrics_list_the_new_cell_only():
-    listed = {m["name"]: m for m in manifest.load()["per_layer"]}
+    """The ``linear_attention_*`` metrics list this cell alone; a
+    ``moe_*`` list holds it and any other cell whose configuration has
+    experts (a later expert configuration appends its own)."""
+    admitted = manifest.load()
+    listed = {m["name"]: m for m in admitted["per_layer"]}
     for name in ("linear_attention_fwd_ms_per_step",
                  "linear_attention_bwd_ms_per_step",
-                 "linear_attention_fwd_roofline", "moe_fwd_ms_per_step",
-                 "moe_bwd_ms_per_step", "moe_experts_fwd_roofline",
-                 "moe_busiest_expert_tokens"):
+                 "linear_attention_fwd_roofline"):
         assert listed[name]["workloads"] == [CELL]
         assert listed[name]["moves"] == "train_items_per_s_per_chip"
+    for name in ("moe_fwd_ms_per_step", "moe_bwd_ms_per_step",
+                 "moe_experts_fwd_roofline", "moe_busiest_expert_tokens"):
+        assert CELL in listed[name]["workloads"]
+        assert listed[name]["moves"] == "train_items_per_s_per_chip"
+        for cell in listed[name]["workloads"]:
+            config = manifest.Cell(admitted, cell).config
+            assert config["num_experts"] > 0 \
+                and config["num_experts_per_tok"] > 0, (name, cell)
     assert CELL not in listed["attention_fwd_roofline"]["workloads"]
     for name in ("attention_fwd_ms_per_step", "attention_bwd_ms_per_step"):
         assert CELL in listed[name]["workloads"]

@@ -23,10 +23,11 @@ from benchmarks import window_attention  # noqa: E402
 from benchmarks.harness import device, manifest  # noqa: E402
 from tests.benchmarks import test_benchmark_scopes as recorded  # noqa: E402
 
-CELL = "trinitymini_train_seq8192"
+CELL = "trinitymini_train_seq8192_balanced"
 METRICS = ("window_attention_fwd_ms_per_step",
            "window_attention_bwd_ms_per_step",
            "window_attention_fwd_roofline", "window_attention_bwd_roofline")
+FULL_METRICS = ("full_attention_fwd_roofline", "full_attention_bwd_roofline")
 MOE = ("moe_fwd_ms_per_step", "moe_bwd_ms_per_step",
        "moe_experts_fwd_roofline", "moe_busiest_expert_tokens")
 J = "jit(sharded_step)/"
@@ -159,6 +160,16 @@ def test_every_reader_on_the_trace_with_known_answers(program_map):
     assert "window attention forward: least time 4.8840 ms a step " \
         "(compute-bound), took 0.005 ms" in said
     assert "window attention backward: least time 12.2099 ms" in said
+    # the layer that sees the whole sequence: ``attention`` less the
+    # scope, 9 and 20 us, against T (T + 1) / 2 pairs
+    assert _read(FULL_METRICS[0], run) == pytest.approx(
+        100 * 2.7910 / 0.009, rel=1e-4)
+    assert _read(FULL_METRICS[1], run) == pytest.approx(
+        100 * 6.9774 / 0.020, rel=1e-4)
+    said = "\n".join(run.lines)
+    assert "full attention forward: least time 2.7910 ms a step " \
+        "(compute-bound), took 0.009 ms" in said
+    assert "full attention backward: least time 6.9774 ms" in said
 
 
 def test_least_time_by_shapes_at_the_cells_shape():
@@ -194,6 +205,15 @@ def test_least_time_by_shapes_at_the_cells_shape():
                                 / peaks["hbm_bytes_per_s"] * 1e3)
     # a model without window layers has none to count
     assert window_attention.window_layers({"num_layers": 4}) == (0, None)
+    # the one layer that sees the whole sequence: the causal half's pairs
+    full, bound = manifest.module("layer_metrics", FULL_METRICS[0]).least_ms(
+        cell.config, cell.traffic, peaks)
+    assert bound == "compute"
+    assert full == pytest.approx(4 * 32 * 128 * (8192 * 8193 / 2)
+                                 / peaks["bf16_flops_per_s"] * 1e3)
+    assert full == pytest.approx(2.791, abs=1e-3)
+    assert manifest.module("layer_metrics", FULL_METRICS[1]).least_ms(
+        cell.config, cell.traffic, peaks)[0] == pytest.approx(2.5 * full)
 
 
 def test_a_program_without_the_scope_leaves_the_metrics_out(program_map):
@@ -203,23 +223,24 @@ def test_a_program_without_the_scope_leaves_the_metrics_out(program_map):
             for k, v in NAMES.items()}
     program_map(bare)
     run = _Run(_summary())
-    for metric in METRICS:
+    for metric in METRICS + FULL_METRICS:
         assert _read(metric, run) is None, metric
     # and so does a run with no device trace (a rehearsal)
     assert window_attention.of_run(_Run(None)) is None
-    # forward ops only: the backward reads 0, not nothing
+    # forward ops only: the backward's time reads 0, not nothing; its
+    # share of a roofline is never 0
     run = _Run(None)
     run.facts[window_attention.SCOPE] = {"forward": 1.0}
-    assert _read(METRICS[1], run) == 0.0 and _read(METRICS[3], run) == 0.0
+    assert _read(METRICS[1], run) == 0.0 and _read(METRICS[3], run) is None
 
 
 def test_where_the_cells_entries_stand():
-    """In BENCHMARK.json: the configuration, the cell, and its name in
-    the lists of the metrics whose readers serve it unchanged. Under
-    ``benchmarks/pending/``: the four new metrics and the cell's place in
-    the four ``moe_*`` lists, which two tests of the accepted benchmark
-    shut out of BENCHMARK.json (the pending file says which)."""
-    admitted, both = manifest.load(), manifest.load(pending=True)
+    """All of them in BENCHMARK.json, which is what the driver reads: the
+    configuration, the cell, its name in the lists of the metrics whose
+    readers serve it unchanged, the four ``window_attention_*`` metrics
+    and its place in the four ``moe_*`` lists (until PR 37 the last two
+    waited under ``benchmarks/pending/``)."""
+    admitted = manifest.load()
     listed = {m["name"]: m for m in admitted["per_layer"]}
     for name in ("device_idle_share", "peak_hbm_gb", "step_device_ms",
                  "step_mfu", "trainer_host_ms_per_step",
@@ -231,24 +252,29 @@ def test_where_the_cells_entries_stand():
     rate = next(m for m in admitted["end_to_end"]
                 if m["name"] == "train_items_per_s_per_chip")
     assert rate["workloads"][-1] == CELL
-    # readers that would miscount it: five full layers, kernels alone
+    # readers that would miscount it (five full layers, kernels alone):
+    # ``full_attention_*_roofline`` read its one full layer instead
     for name in ("attention_fwd_roofline", "attention_bwd_roofline",
                  "custom_call_ms_per_step"):
         assert CELL not in listed[name]["workloads"]
-    assert not set(METRICS) & set(listed)
-    waiting = {m["name"]: m for m in both["per_layer"]}
-    for name in METRICS:
-        assert waiting[name] == {
+    for name in METRICS + FULL_METRICS:
+        assert listed[name] == {
             "name": name, "unit": "%" if name.endswith("roofline") else "ms",
             "better": "higher" if name.endswith("roofline") else "lower",
             "source": "device_trace", "layer": "kernels",
             "moves": "train_items_per_s_per_chip", "workloads": [CELL]}
     for name in MOE:
-        assert CELL not in listed[name]["workloads"]
-        assert waiting[name]["workloads"] == ["qwen3next_train_seq8192",
-                                              CELL]
-    cell = manifest.Cell(both, CELL)
-    assert set(METRICS) | set(MOE) <= {m["name"] for m in cell.per_layer}
+        assert listed[name]["workloads"] == ["qwen3next_train_seq8192",
+                                             CELL]
+    cell = manifest.Cell(admitted, CELL)
+    assert set(METRICS) | set(FULL_METRICS) | set(MOE) \
+        <= {m["name"] for m in cell.per_layer}
+    # no list names a cell the manifest lacks (the retired one's name)
+    cells = {w["name"] for w in admitted["workloads"]}
+    for m in admitted["end_to_end"] + admitted["per_layer"]:
+        assert set(m.get("workloads", ())) <= cells, m["name"]
+    assert not os.path.exists(os.path.join(
+        ROOT, "benchmarks", "pending", "trinitymini_train_seq8192.json"))
 
 
 def test_the_configuration_keeps_the_published_widths():
@@ -280,6 +306,22 @@ def test_the_configuration_keeps_the_published_widths():
     assert cell.config["layer_types"] == built
     assert cell.config["num_layers"] == len(built)
     assert cell.traffic["batch"] == 1 and cell.traffic["seq_len"] == 8192
+    assert cell.traffic["routing"] == "balanced"
+    assert "router" not in cell.traffic
+
+
+def test_the_training_check_catches_a_window_layer_that_sees_every_key():
+    """``degrade.py --program-key sliding_window=<T>``: the program
+    built with the window as wide as the sequence, the reference with
+    the file's, and the check says so by the median logit."""
+    from benchmarks import degrade
+
+    cell = manifest.Cell(manifest.load(), CELL).rehearse()
+    model = manifest.module("models", cell.config["model"])
+    sizes = model.reference_sizes
+    assert degrade.faulted_check(cell, 3, {"sliding_window": 1024},
+                                 allow_cpu=True)
+    assert model.reference_sizes is sizes       # put back
 
 
 def test_the_training_check_catches_weights_at_three_bits_of_mantissa():
