@@ -23,6 +23,8 @@ Phases (any failure -> non-zero exit, no result line):
            a traced offset), the gated delta rule's kernels (output and
            five gradients in bf16 against float32 autodiff of the
            ``jax.numpy`` form, beside what that form reads in bf16),
+           the short convolution + SiLU kernels the same way, with each
+           kernel's time alone beside the ``jax.numpy`` form's,
            paged decode attention (bf16 and int8 KV)
            and the int8 conv / FC ops, compiled, against the XLA dense
            composition in f32-highest.
@@ -62,6 +64,9 @@ FULL = {"image": 224, "batch": 256, "steps": 5,
         "window": ((1, 32, 8192, 128), 2048),
         # (B, T, key heads, value heads, Dk, Dv): Qwen3-Next's cell
         "delta_rule": (1, 8192, 16, 32, 128, 128),
+        # ((B, T, W), the parts handed on, taps): the projection's output
+        # of one of that cell's linear layers, [q | k | v | z]
+        "conv_silu": ((1, 8192, 12288), (2048, 2048, 4096), 4),
         "decode": {"b": 8, "h": 12, "d": 64, "page": 16, "pages": 256},
         # ResNet-18 stage-2 3x3 conv and the classifier, batch 128
         "conv": {"data": (128, 128, 28, 28), "weight": (128, 128, 3, 3)},
@@ -72,6 +77,7 @@ REHEARSAL = {"image": 64, "batch": 8, "steps": 2,
              "hop": (1, 2, 128, 64),
              "window": ((1, 2, 256, 64), 100),
              "delta_rule": (1, 150, 1, 2, 128, 128),
+             "conv_silu": ((1, 70, 640), (128, 256, 128), 4),
              "decode": {"b": 2, "h": 2, "d": 32, "page": 8, "pages": 8},
              "conv": {"data": (2, 8, 8, 8), "weight": (8, 8, 3, 3)},
              "fc": {"data": (4, 32), "weight": (16, 32)},
@@ -445,6 +451,91 @@ def delta_rule_check(shape, interpret, tag):
           f"{errs['kernels']}")
 
 
+def conv_silu_check(shape, parts, taps, interpret, tag):
+    """The short convolution + SiLU kernels in bf16, the parts and the
+    gradients of input and weight, against float32 autodiff of the
+    ``jax.numpy`` form ``silu(causal_conv1d(x, w))``: largest difference
+    over the largest entry, beside what the ``jax.numpy`` form itself
+    reads in bf16 (what the kernels replace on the chip); then each
+    kernel's time alone (a layer: a call a part), and that form's."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops.conv_silu_kernels import causal_conv_silu_kernels
+    from mxnet_tpu.ops.linear_attention import causal_conv1d
+
+    b, t, width = shape
+    channels = sum(parts)
+    rs = np.random.RandomState(4)
+    x = jnp.asarray(rs.randn(b, t, width), jnp.float32)
+    w = jnp.asarray(rs.uniform(-1, 1, (channels, taps)) * taps ** -0.5,
+                    jnp.float32)
+    douts = tuple(jnp.asarray(rs.randn(b, t, n), jnp.float32)
+                  for n in parts + (width - channels,))
+
+    def form(x, w):
+        mixed = jax.nn.silu(causal_conv1d(x[..., :channels], w))
+        ends = np.cumsum(parts)
+        return tuple(mixed[..., e - n:e] for e, n in zip(ends, parts)) \
+            + (x[..., channels:],)
+
+    def kernels(x, w):
+        return causal_conv_silu_kernels(x, w, parts, interpret=interpret)
+
+    def forward(fn):
+        return jax.jit(lambda x, w: fn(x, w)[:-1])
+
+    def backward(fn):
+        # the forward's results are dead here: only the backward runs
+        return jax.jit(lambda x, w, d: jax.vjp(fn, x, w)[1](d))
+
+    def both(fn, x, w, d):
+        return forward(fn)(x, w) + backward(fn)(x, w, d)
+
+    def half(*arrays):
+        return tuple(a.astype(jnp.bfloat16) for a in arrays)
+
+    want = both(form, x, w, douts)
+    xh, wh = half(x, w)
+    dh = half(*douts)
+    t0 = time.perf_counter()
+    got = jax.block_until_ready(both(kernels, xh, wh, dh))
+    dt = time.perf_counter() - t0
+    names = [f"part{n}" for n in range(len(parts))] + ["dx", "dw"]
+    errs = {}
+    for label, result in (("kernels", got), ("jax.numpy",
+                                             both(form, xh, wh, dh))):
+        errs[label] = [max_err(a, e) / float(jnp.max(jnp.abs(e)))
+                       for a, e in zip(result, want)]
+        log(f"{tag} causal conv + SiLU {shape} parts {parts} {taps} taps "
+            f"bf16, {label} against float32 autodiff, largest err/largest "
+            "entry: " + " ".join(f"{n} {e:.4f}"
+                                 for n, e in zip(names, errs[label])))
+    log(f"{tag} causal conv + SiLU kernels compile+run {dt:.1f} s")
+    check(max(errs["kernels"]) <= BF16_ATOL,
+          f"causal conv + SiLU kernels {shape} outside bf16 tolerance: "
+          f"{errs['kernels']}")
+
+    if interpret:       # a time is the chip's or it is not written
+        return
+
+    def ms(fn, *args, calls=30):
+        jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        return (time.perf_counter() - t0) / calls * 1e3
+
+    times = {label: (ms(forward(fn), xh, wh), ms(backward(fn), xh, wh, dh))
+             for label, fn in (("kernels", kernels), ("jax.numpy", form))}
+    log(f"{tag} causal conv + SiLU, a layer alone, ms forward / backward "
+        "(wall time over back-to-back calls): " + "; ".join(
+            f"{label} {f:.3f} / {bw:.3f}"
+            for label, (f, bw) in times.items()))
+
+
 def kernels_phase(sz, interpret, tag):
     import jax
     import jax.numpy as jnp
@@ -509,6 +600,7 @@ def kernels_phase(sz, interpret, tag):
 
     window_check(*sz["window"], interpret, tag)
     delta_rule_check(sz["delta_rule"], interpret, tag)
+    conv_silu_check(*sz["conv_silu"], interpret, tag)
 
     b_, h_, t_, d_ = sz["flash"][0]
     blocks = tune.schedule.flash_fwd_blocks(b_ * h_, t_, d_, "bfloat16",

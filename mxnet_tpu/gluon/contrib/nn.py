@@ -408,7 +408,9 @@ class GatedDeltaNet(HybridBlock):
     the output gate z (``num_v_heads`` of ``head_v_dim``), laid
     [q | k | v | z]; ``ba_proj`` gives b then a (``num_v_heads`` each).
     [q, k, v] pass a causal depthwise convolution of ``conv_kernel``
-    taps without bias, then SiLU; ``beta = sigmoid(b)``,
+    taps without bias, then SiLU (``causal_conv_silu``: on a TPU one
+    Pallas kernel pass each way that hands q, k, v on as three arrays);
+    ``beta = sigmoid(b)``,
     ``g = -exp(A_log) * softplus(a + dt_bias)`` in float32; the chunked
     delta rule (``ops/linear_attention.py``: its Pallas kernels on a
     TPU where both head sizes are multiples of 128, ``jax.numpy``
@@ -451,20 +453,15 @@ class GatedDeltaNet(HybridBlock):
         key_dim, value_dim = hk * dk, hv * dv
         qkvz, ba = self.qkvz_proj(x), self.ba_proj(x)
 
-        def cut(src, begin, size, *heads):
-            out = F.slice_axis(src, axis=-1, begin=begin, end=begin + size)
-            return F.reshape(out, shape=(b, t) + heads) if heads else out
-
         with _jit.scope("linear_attention"):
-            mixed = F.Activation(F.causal_conv1d(
-                cut(qkvz, 0, 2 * key_dim + value_dim), conv_weight),
-                act_type="silu")
-            q = cut(mixed, 0, key_dim, hk, dk)
-            k = cut(mixed, key_dim, key_dim, hk, dk)
-            v = cut(mixed, 2 * key_dim, value_dim, hv, dv)
-            z = cut(qkvz, 2 * key_dim + value_dim, value_dim, hv, dv)
-            beta = F.sigmoid(F.cast(cut(ba, 0, hv), dtype="float32"))
-            a = F.cast(cut(ba, hv, hv), dtype="float32")
+            q, k, v, z = F.causal_conv_silu(
+                qkvz, conv_weight, parts=(key_dim, key_dim, value_dim))
+            q, k = (F.reshape(a, shape=(b, t, hk, dk)) for a in (q, k))
+            v, z = (F.reshape(a, shape=(b, t, hv, dv)) for a in (v, z))
+            beta, a = (F.cast(F.slice_axis(ba, axis=-1, begin=begin,
+                                           end=begin + hv), dtype="float32")
+                       for begin in (0, hv))
+            beta = F.sigmoid(beta)
             g = -F.exp(F.cast(A_log, dtype="float32")) * F.Activation(
                 a + F.cast(dt_bias, dtype="float32"), act_type="softrelu")
             out = self.norm(F.gated_delta_rule(q, k, v, g, beta,

@@ -15,8 +15,18 @@ factors of a unit lower-triangular solve, and the state moves once a
 chunk, so a sequence of T tokens is T / chunk sequential steps of
 matrix products instead of T rank-one updates.
 
-One algorithm, two implementations, chosen by what the code can see.
-On a TPU, at head sizes on the lane grid
+One algorithm each, two implementations, chosen by what the code can
+see. Which part of a ``GatedDeltaNet`` mixer runs where, on a TPU at
+Qwen3-Next's widths: the short convolution with its SiLU
+(:func:`causal_conv_silu`) as the Pallas kernels
+``causal_conv_silu_fwd`` / ``causal_conv_silu_bwd``
+(``ops/conv_silu_kernels.py``: one pass over the projection's output
+each way, q, k and v handed on as three arrays, no slice in between);
+the delta rule as the kernels below; the gates, the gated norm with z
+and the projections as XLA's own fusions. Everywhere else all of it is
+``jax.numpy``.
+
+The delta rule: on a TPU, at head sizes on the lane grid
 (``tune.schedule.delta_rule_shape_supported``), it runs as the Pallas
 kernels ``gated_delta_rule_fwd`` and ``gated_delta_rule_bwd``
 (``ops/delta_rule_kernels.py``): the state and every chunk's tensors
@@ -47,6 +57,47 @@ def causal_conv1d(data, weight):
     return sum(padded[:, j:j + t, :] * weight[:, j] for j in range(k))
 
 
+def _on_tpu(x):
+    """Whether a computation on ``x`` lands on a TPU: where ``x``
+    lives; a tracer has no device and lands on jax's default backend,
+    as ``parallel.ring_attention`` reads it."""
+    from .pallas_kernels import pallas_available
+
+    if isinstance(x, jax.core.Tracer):
+        return pallas_available()
+    return next(iter(x.devices())).platform == "tpu"
+
+
+@register("causal_conv_silu", num_outputs=lambda p: len(p["parts"]) + 1)
+def causal_conv_silu(data, weight, parts):
+    """``silu(causal_conv1d(data[..., :C], weight))`` handed on in
+    column ``parts`` (widths that sum to C, the rows of ``weight``
+    (C, K)), then the columns of ``data`` (B, T, W >= C) past C as they
+    are (none: an array of no columns): ``len(parts) + 1`` arrays in
+    ``data``'s dtype. On a TPU with every part on the lane grid
+    (``tune.schedule.conv_silu_shape_supported``) the Pallas kernels of
+    ``ops/conv_silu_kernels.py``, float32 inside with one rounding;
+    ``jax.numpy`` in ``data``'s dtype everywhere else."""
+    from ..tune import schedule
+
+    data, weight = jnp.asarray(data), jnp.asarray(weight)
+    parts = tuple(int(p) for p in parts)
+    channels = weight.shape[0]
+    if sum(parts) != channels or data.shape[-1] < channels:
+        raise ValueError(
+            f"causal_conv_silu: parts {parts} of a weight {weight.shape} "
+            f"over data {data.shape}")
+    if _on_tpu(data) and schedule.conv_silu_shape_supported(
+            parts, weight.shape[1], data.shape[-1]):
+        from .conv_silu_kernels import causal_conv_silu_kernels
+
+        return causal_conv_silu_kernels(data, weight, parts)
+    mixed = jax.nn.silu(causal_conv1d(data[..., :channels], weight))
+    firsts = [sum(parts[:n]) for n in range(1, len(parts))]
+    return tuple(jnp.split(mixed, firsts, axis=-1)) \
+        + (data[..., channels:],)
+
+
 def _l2norm(x, eps=1e-6):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
 
@@ -59,17 +110,11 @@ def _chunked(x, n, c, hk):
 
 def _kernels_take(v, dk, chunk):
     """Whether this call runs as the Pallas kernels: its computation
-    lands on a TPU (where ``v`` lives; a tracer has no device and lands
-    on jax's default backend, as ``parallel.ring_attention`` reads it)
-    and the shape has a legal schedule."""
+    lands on a TPU (:func:`_on_tpu`) and the shape has a legal
+    schedule."""
     from ..tune import schedule
-    from .pallas_kernels import pallas_available
 
-    if isinstance(v, jax.core.Tracer):
-        on_tpu = pallas_available()
-    else:
-        on_tpu = next(iter(v.devices())).platform == "tpu"
-    return on_tpu and schedule.delta_rule_shape_supported(
+    return _on_tpu(v) and schedule.delta_rule_shape_supported(
         dk, v.shape[-1], chunk)
 
 

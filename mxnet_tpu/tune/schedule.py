@@ -78,6 +78,15 @@ DECODE_PAGE_BLOCK_CANDIDATES = (16, 8, 4, 2, 1)
 # (its products and its triangular inverse) beside the earlier chunk's
 # state update; more of them is more code, not more work.
 DELTA_RULE_CHUNK_CANDIDATES = (16, 8, 4, 2, 1)
+# The short causal convolution + SiLU kernels (ops/conv_silu_kernels.py):
+# rows of the sequence and channels one grid step takes, the rows of
+# history (forward) or of what follows (backward) a step reads beside its
+# tile (one sublane tile of a 16-bit operand), and the most taps: a pass
+# inside a tile hands the next one float32 sublane tile, 8 rows.
+CONV_SILU_ROW_CANDIDATES = (2048, 1024, 512, 256, 128, 64, 32, 16)
+CONV_SILU_COL_CANDIDATES = (2048, 1024, 512, 256, 128)
+CONV_SILU_HALO = 16
+CONV_SILU_MAX_TAPS = 8
 SEARCH_SPACE = {
     # Pallas streaming flash-attention forward (ops/pallas_kernels.py);
     # also the ring-attention per-hop kernel, keyed at the hop's local
@@ -106,12 +115,20 @@ SEARCH_SPACE = {
     # (value heads, T, Dk, Dv) shape
     "delta_rule_fwd": {"chunks": DELTA_RULE_CHUNK_CANDIDATES},
     "delta_rule_bwd": {"chunks": DELTA_RULE_CHUNK_CANDIDATES},
+    # the short causal convolution + SiLU and its backward
+    # (ops/conv_silu_kernels.py): the (rows, channels) tile of a grid
+    # step, keyed per (batch, T, channels, taps) shape
+    "conv_silu_fwd": {"rows": CONV_SILU_ROW_CANDIDATES,
+                      "cols": CONV_SILU_COL_CANDIDATES},
+    "conv_silu_bwd": {"rows": CONV_SILU_ROW_CANDIDATES,
+                      "cols": CONV_SILU_COL_CANDIDATES},
 }
 
 # What a kernel runs when the table has no entry (block sizes are
-# legalized down to the shape). The flash forward's and backward's and
-# the gated delta rule's are chip-measured (PERF.md, PRs 28, 31 and
-# 33); the others are the hand-written pre-autotune constants.
+# legalized down to the shape). The flash forward's and backward's, the
+# gated delta rule's and the short convolution's are chip-measured
+# (PERF.md, PRs 28, 31, 33 and 39); the others are the hand-written
+# pre-autotune constants.
 DEFAULT_SCHEDULES = {
     "flash_fwd": {"block_q": 512, "block_k": 512},
     "flash_bwd": {"block_q": 512, "block_k": 512},
@@ -121,6 +138,8 @@ DEFAULT_SCHEDULES = {
     "decode_attn": {"block_pages": 8},
     "delta_rule_fwd": {"chunks": 8},
     "delta_rule_bwd": {"chunks": 4},
+    "conv_silu_fwd": {"rows": 512, "cols": 1024},
+    "conv_silu_bwd": {"rows": 1024, "cols": 512},
 }
 
 _LOCK = threading.Lock()
@@ -342,6 +361,12 @@ def delta_rule_shape_key(bh, t, dk, dv):
     """Gated delta rule table key: value heads over the batch, tokens,
     key and value head sizes."""
     return f"bh{int(bh)}-t{int(t)}-dk{int(dk)}-dv{int(dv)}"
+
+
+def conv_silu_shape_key(b, t, channels, taps):
+    """Short-convolution table key: batch, tokens, the channels of the
+    part a call convolves, taps."""
+    return f"b{int(b)}-t{int(t)}-c{int(channels)}-k{int(taps)}"
 
 
 def decode_shape_key(batch, pages):
@@ -647,6 +672,71 @@ def delta_rule_vmem_limit(kernel, nb, chunk, rep, dk, dv, itemsize):
     (:func:`_vmem_limit`)."""
     return _vmem_limit(delta_rule_vmem_bytes(kernel, nb, chunk, rep, dk,
                                              dv, itemsize))
+
+
+# -------------------------------------------- short-convolution resolution
+
+def conv_silu_shape_supported(parts, taps, width=None):
+    """Whether the short-convolution kernels take the shape: each of the
+    ``parts`` the channels are handed on in is a column range of the
+    input read in place and an array of its own going out, so every
+    part's width (and with it every part's first column) lies on the
+    lane grid; at most :data:`CONV_SILU_MAX_TAPS` taps; the input is at
+    least as wide as the parts together. What
+    ``ops.linear_attention.causal_conv_silu`` asks before it takes the
+    kernels; everything else runs its ``jax.numpy`` form."""
+    parts = tuple(int(p) for p in parts)
+    return bool(parts) and all(p > 0 and p % LANES == 0 for p in parts) \
+        and 1 <= int(taps) <= CONV_SILU_MAX_TAPS \
+        and (width is None or int(width) >= sum(parts))
+
+
+def conv_silu_tile(kernel, b, t, channels, offset, taps, dtype,
+                   interpret=False, rows=None, cols=None):
+    """The (rows, channels) tile of one grid step of ``kernel``
+    (``conv_silu_fwd`` / ``conv_silu_bwd``) over a part of ``channels``
+    channels that starts at column ``offset`` of its input: the caller's
+    (the search driver's candidates), else the table's, else the
+    default's. Rows are legalized to a multiple of
+    :data:`CONV_SILU_HALO` no longer than the padded sequence (the last
+    tile may hang over the end: the kernels mask it), channels to the
+    largest multiple of the lane tile that is no larger and divides both
+    the part's width and its offset (a block index counts whole
+    tiles)."""
+    if rows is None or cols is None:
+        sched = kernel_schedule(
+            kernel, conv_silu_shape_key(b, t, channels, taps), str(dtype),
+            resolve_backend(interpret))
+        rows = sched["rows"] if rows is None else rows
+        cols = sched["cols"] if cols is None else cols
+    halo = CONV_SILU_HALO
+    rows = max(halo, min(int(rows), _pad(t, halo)) // halo * halo)
+    cols = max(LANES, min(int(cols), int(channels)) // LANES * LANES)
+    while int(channels) % cols or int(offset) % cols:
+        cols -= LANES
+    return rows, cols
+
+
+def conv_silu_vmem_bytes(kernel, rows, cols, taps, itemsize):
+    """What one grid step of a short-convolution kernel holds in VMEM:
+    the input tile with its halo and the output tile, double-buffered,
+    and the weight; the backward's dy tile and second halos beside
+    them, the float32 copy of the input tile between its halos that the
+    taps are read from, and the weight gradient's accumulator."""
+    halo = CONV_SILU_HALO
+    need = 2 * (2 * rows + halo) * cols * itemsize + 2 * taps * cols * 4
+    if kernel == "conv_silu_bwd":
+        need += 2 * (rows + 2 * halo) * cols * itemsize \
+            + (rows + 2 * halo) * cols * 4 \
+            + (2 + MIN_SUBLANE) * taps * cols * 4
+    return need
+
+
+def conv_silu_vmem_limit(kernel, rows, cols, taps, itemsize):
+    """A short-convolution kernel's ``vmem_limit_bytes``
+    (:func:`_vmem_limit`)."""
+    return _vmem_limit(conv_silu_vmem_bytes(kernel, rows, cols, taps,
+                                            itemsize))
 
 
 def decode_attn_block_pages(batch, pages, dtype, interpret=False,

@@ -229,6 +229,69 @@ def test_delta_rule_kernels_are_named_and_compile_at_real_widths(
     assert "while" not in compiled and "triangular" not in compiled
 
 
+# ((B, T, W), the parts, taps), dtype: the projection's output of a
+# Qwen3-Next linear layer in the cell; two sequences that end inside
+# their last row tile, unequal parts, float32, two taps
+_CONV_SILU_SHAPES = [(((1, 8192, 12288), (2048, 2048, 4096), 4),
+                      jnp.bfloat16),
+                     (((2, 600, 640), (128, 256, 128), 2), jnp.float32)]
+
+
+@pytest.mark.parametrize("shape,dtype", _CONV_SILU_SHAPES)
+def test_conv_silu_kernels_are_named_and_compile_at_real_widths(
+        one_chip, shape, dtype):
+    """Forward alone: a ``causal_conv_silu_fwd`` a part and no slice or
+    pad of the convolved columns. Under ``jax.grad``: the forward under
+    the forward half of the ``linear_attention`` scope and a
+    ``causal_conv_silu_bwd`` a part under the backward half (what
+    ``linear_attention_*_ms_per_step`` join on), the parts' input
+    gradients written into one array that no copy or concatenation
+    touches, and Mosaic takes both tile programs at these widths."""
+    from mxnet_tpu.ops import conv_silu_kernels
+
+    (b, t, width), parts, taps = shape
+
+    def over_the_sequence(compiled, *ops):
+        """Lines of the compiled program that run one of ``ops`` on a
+        (B, T, ..) tensor (the weight's are a few KB)."""
+        return [line for line in compiled.splitlines()
+                if any(f" {op}(" in line for op in ops)
+                and f"[{b},{t}," in line]
+
+    def through(x, w):
+        with jax.named_scope("linear_attention"):
+            return conv_silu_kernels.causal_conv_silu_kernels(x, w, parts)
+
+    args = [jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+            for s in ((b, t, width), (sum(parts), taps))]
+    forward = jax.jit(lambda x, w: through(x, w)[:-1]).lower(*args)
+    text = forward.as_text(debug_info=True)
+    assert 'kernel_name = "causal_conv_silu_fwd"' in text
+    assert "linear_attention/causal_conv_silu_fwd/pallas_call" in text
+    compiled = forward.compile().as_text()
+    assert compiled.count("custom_call_target=\"tpu_custom_call\"") == \
+        len(parts)
+    assert not over_the_sequence(compiled, "slice", "pad")
+
+    lowered = jax.jit(jax.grad(
+        lambda x, w: sum(jnp.sum(o.astype(jnp.float32) ** 2)
+                         for o in through(x, w)), argnums=(0, 1))).lower(
+                             *args)
+    text = lowered.as_text(debug_info=True)
+    for kernel, half in (("causal_conv_silu_fwd", "jvp(linear_attention)"),
+                         ("causal_conv_silu_bwd",
+                          "transpose(jvp(linear_attention))")):
+        assert f'kernel_name = "{kernel}"' in text
+        named = [line for line in text.splitlines()
+                 if f"{kernel}/pallas_call" in line]
+        assert named and all(f"{half}/{kernel}" in line for line in named), \
+            named[:2]
+    compiled = lowered.compile().as_text()
+    assert compiled.count("custom_call_target=\"tpu_custom_call\"") == \
+        2 * len(parts)
+    assert not over_the_sequence(compiled, "copy", "concatenate", "pad")
+
+
 def test_no_pallas_call_in_the_package_is_left_unnamed():
     """A kernel added later is named the same way (docs/observability.md,
     "The program's own names")."""

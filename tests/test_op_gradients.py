@@ -717,6 +717,7 @@ EXPLICIT = {
     # tests/test_qwen3_next.py: gradients against the float32 reference
     "RMSNorm", "rotary_embedding", "causal_conv1d", "gated_delta_rule",
     "moe_router", "moe_experts",
+    "causal_conv_silu",     # tests/test_conv_silu_kernels.py too
 }
 
 

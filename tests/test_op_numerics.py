@@ -508,6 +508,7 @@ def test_coverage_fraction():
         # test_qwen3_next.py (against the float32 reference)
         "RMSNorm", "rotary_embedding", "causal_conv1d", "gated_delta_rule",
         "moe_router", "moe_experts",
+        "causal_conv_silu",     # test_conv_silu_kernels.py too
         # test_image_ops.py
         "_image_to_tensor", "_image_normalize", "_image_flip_left_right",
         "_image_flip_top_bottom", "_image_random_flip_left_right",

@@ -253,6 +253,44 @@ def test_gated_deltanet_matches_the_reference_forward_and_backward():
         _close(got[key], want[key], atol=1e-4)
 
 
+def test_gated_deltanet_through_the_kernels_matches_the_jnp_path(
+        monkeypatch):
+    """At widths on the lane grid, what the dispatch takes on a TPU (the
+    short convolution's kernels and the delta rule's, here in interpret
+    mode): the output and every parameter's gradient equal the
+    ``jax.numpy`` path's."""
+    from mxnet_tpu.ops import (conv_silu_kernels, delta_rule_kernels,
+                               pallas_kernels)
+
+    blk = contrib_nn.GatedDeltaNet(32, 1, 2, 128, 128)
+    blk.initialize(mx.initializer.Xavier())
+    x = _array(_rng(8), 2, 70, 32)
+    fn, params = _call(blk, x)
+
+    def loss(p):
+        return jnp.sum(jnp.sin(fn(p)))
+
+    want, want_grads = fn(params), _grad(loss)(params)
+    taken = []
+    for mod, name in ((conv_silu_kernels, "causal_conv_silu_kernels"),
+                      (delta_rule_kernels, "gated_delta_rule_kernels")):
+        def interpreted(*args, _real=getattr(mod, name), _name=name,
+                        **kwargs):
+            taken.append(_name)
+            return _real(*args, **dict(kwargs, interpret=True))
+
+        monkeypatch.setattr(mod, name, interpreted)
+    monkeypatch.setattr(pallas_kernels, "pallas_available", lambda: True)
+    fn, _ = _call(blk, x)       # traced again, through the kernels
+    _close(fn(params), want, atol=1e-5)
+    got_grads = _grad(loss)(params)
+    assert set(taken) == {"causal_conv_silu_kernels",
+                          "gated_delta_rule_kernels"}
+    assert set(got_grads) == set(want_grads) and len(got_grads) == 7
+    for key in want_grads:
+        _close(got_grads[key], want_grads[key], atol=1e-4)
+
+
 # ------------------------------------------------------------ expert layer
 
 def _moe_weights(rng, experts=16, d=32, inner=16):

@@ -288,6 +288,76 @@ def test_remat_default_gradients_are_the_recomputed_ones(remat_halves, kind):
         np.testing.assert_array_equal(a, b)
 
 
+def _conv_half():
+    """A Remat half around the short convolution's kernels (interpret
+    mode): a projection, the convolution + SiLU handed on in two parts
+    and the columns past them, an elementwise pass, a projection."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ndarray.ndarray import NDArray
+    from mxnet_tpu.ops import conv_silu_kernels
+
+    class Half(gluon.HybridBlock):
+        def __init__(self, **kwargs):
+            super().__init__(**kwargs)
+            with self.name_scope():
+                self.proj = nn.Dense(512, flatten=False, use_bias=False)
+                self.conv_weight = self.params.get(
+                    "conv_weight", shape=(384, 4),
+                    init=mx.initializer.Uniform(0.5))
+                self.out = nn.Dense(8, flatten=False, use_bias=False)
+
+        def forward(self, x):
+            parts = conv_silu_kernels.causal_conv_silu_kernels(
+                self.proj(x).data_, self.conv_weight.data().data_,
+                (128, 256), interpret=True)
+            return self.out(NDArray(jnp.tanh(
+                jnp.concatenate(parts, axis=-1))))
+
+    return Half()
+
+
+def test_remat_default_runs_the_short_convolution_again():
+    """One pass over its input, cheaper to run again than to keep: the
+    forward kernel names no residual, so under Remat's default a half's
+    gradient holds it twice a part (forward, recomputation) and the
+    backward kernel once, exactly the program ``'nothing_saveable'``
+    gives; nothing of the forward's results is kept."""
+    import jax
+    import jax.numpy as jnp
+
+    texts = {}
+    for policy in (None, "nothing_saveable"):
+        mx.random.seed(7)
+        inner = _conv_half()
+        inner.initialize(mx.initializer.Xavier(rnd_type="gaussian"))
+        x = np.random.RandomState(0).rand(1, 48, 16).astype(np.float32)
+        inner(mx.nd.array(x))
+        net = gluon.contrib.Remat(inner, policy=policy)
+        fwd = parallel.functional_call(net, train=True)
+        params = parallel.param_arrays(net)
+        aux = parallel.aux_arrays(net)
+
+        def loss(p):
+            out, _ = fwd(p, aux, x)
+            return jnp.sum(out.astype(jnp.float32) ** 2)
+
+        texts[policy] = str(jax.make_jaxpr(jax.grad(loss))(params))
+        assert all(np.any(np.asarray(g) != 0) for g in
+                   jax.tree_util.tree_leaves(jax.grad(loss)(params)))
+    kept = texts[None]
+    assert kept.count("name=causal_conv_silu_fwd") == 2 * 2
+    assert kept.count("name=causal_conv_silu_bwd") == 2
+    assert "kernel_residual" not in kept
+    # the same program but for the policy's own name in the jaxpr
+    import re
+
+    def policy_less(text):
+        return re.sub(r"policy=<function .*>", "policy=_", text)
+
+    assert policy_less(kept) == policy_less(texts["nothing_saveable"])
+
+
 @pytest.mark.parametrize("entry", ["with_grad", "with_lse"])
 def test_residual_names_lower_to_nothing_outside_checkpoint(monkeypatch,
                                                             entry):
