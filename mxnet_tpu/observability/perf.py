@@ -56,6 +56,7 @@ import re
 import threading
 import time
 import weakref
+from collections import deque
 
 from . import _STATS
 from . import metrics as _metrics
@@ -414,15 +415,37 @@ def clear():
 # ------------------------------------------------- the program's own names
 
 _HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
-_HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
+_HLO_INSTRUCTION = re.compile(r"^\s+(ROOT )?%?([\w.\-]+) = ")
 _HLO_OP_NAME = re.compile(r'\bop_name="((?:[^"\\]|\\.)*)"')
-_HLO_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+# the opcode and its operand list: the first `` word(`` after `` = `` (a
+# shape's layout ``{1,0:T(8,128)}`` and a tuple shape's ``, f32[`` hold
+# no space before a parenthesis)
+_HLO_OPERANDS = re.compile(r" ([a-z][\w\-]*)\(([^)]*)\)")
+_HLO_REF = re.compile(r"%?([\w.\-]+)")
+# every computation an instruction runs
+_HLO_CALLED = re.compile(
+    r"\b(calls|to_apply|body|condition|true_computation|false_computation|"
+    r"select|scatter|branch_computations|called_computations)="
+    r"(?:\{([^}]*)\}|%?([\w.\-]+))")
+# where a computation's parameter 0 sits among its caller's operands: a
+# conditional's branch k takes operand k + 1, every other caller hands
+# operand i to parameter i
+_HLO_FIRST_OPERAND = {"true_computation": 1, "false_computation": 2}
 _PALLAS_TARGET = 'custom_call_target="tpu_custom_call"'
+
+
+def _is_name(op_name):
+    """A name the program gave: jax's name stack, ``jit(<label>)/...``.
+    A bare ``op_name`` -- what a compiler pass gives the op it makes
+    (``ragged-dot-none``, ``reduce_sum``), an argument's (``x``) -- is
+    not one."""
+    return "/" in op_name
 
 
 def parse_op_names(hlo_text):
     """Optimised HLO text -> ``{instruction name: {"op_name", "kernel",
-    "called"}}`` for every instruction of every computation.
+    "called", "owner", "via"}}`` for every instruction of every
+    computation.
 
     ``op_name`` is the instruction's ``metadata={op_name=...}``: jax's
     name stack at the point the op was traced, e.g.
@@ -435,12 +458,35 @@ def parse_op_names(hlo_text):
     (the scope the call sits in), else "". ``called`` lists the distinct
     ``op_name`` values of the instructions inside a fusion's computation,
     in program order, so a reader can tell a fusion that mixes blocks or
-    directions from one that does not."""
+    directions from one that does not.
+
+    ``owner`` is, for an instruction with no name of the program's (no
+    name stack of its own -- "" or a compiler pass's bare op name such
+    as ``ragged-dot-none`` -- and none inside it), the name of the part
+    it was made for; "" elsewhere. ``via`` says how it was found:
+    ``"user"`` -- the nearest named instruction that reads the result,
+    walking forward through instructions with no name (tuples,
+    get-tuple-elements, bitcasts, copies, an async pair's ``-start`` to
+    its ``-done``, a computation's ROOT to the instruction that calls
+    it): a fill is made for what reads it, a layout copy for the
+    consumer that wants the layout; else ``"operand"`` -- the nearest
+    named instruction it reads, walking backward by the same moves (a
+    computation's parameter to its caller's operand); "" where neither
+    finds one (the entry computation's parameters, a constant nothing
+    named reads). A fusion with no name of its own and names inside is
+    named by the last of them. One walk each way over one index of
+    users: linear in the text. Facts, not verdicts: which phase or
+    scope an owner means is the reader's to decide."""
     inside, current, out = {}, None, {}
+    # the def-use graph, by index in program order
+    names, named, operands, runs = [], [], [], []
+    roots, params, entry_params = {}, {}, set()
     for line in hlo_text.splitlines():
         if not line.startswith(" "):
             m = _HLO_COMPUTATION.match(line)
             current = inside.setdefault(m.group(1), {}) if m else None
+            if m:
+                comp, is_entry = m.group(1), line.startswith("ENTRY")
             continue
         m = _HLO_INSTRUCTION.match(line)
         if m is None or current is None:
@@ -451,18 +497,96 @@ def parse_op_names(hlo_text):
         head = line[:at] if at >= 0 else line
         found = _HLO_OP_NAME.search(line, at) if at >= 0 else None
         op_name = found.group(1).replace("\\'", "'") if found else ""
+        name = m.group(2)
         kernel = ""
         if _PALLAS_TARGET in head:
             scopes = op_name.split("/")
             kernel = scopes[-2] if len(scopes) > 1 \
-                and scopes[-1] == "pallas_call" else m.group(1)
-        calls = _HLO_CALLS.search(head)
+                and scopes[-1] == "pallas_call" else name
+        i = len(names)
+        runs.append([])
+        called = []
+        for kind, many, one in _HLO_CALLED.findall(head):
+            targets = _HLO_REF.findall(many) if many else [one]
+            for k, target in enumerate(targets):
+                runs[i].append((target, k + 1 if kind == "branch_computations"
+                                else _HLO_FIRST_OPERAND.get(kind, 0)))
+            if kind == "calls" and not called:
+                called = list(inside.get(targets[0], ()))
         if op_name:
             current[op_name] = None     # a dict keeps them in order, once
-        out[m.group(1)] = {"op_name": op_name, "kernel": kernel,
-                           "called": list(inside.get(calls.group(1), ()))
-                           if calls else []}
+        out[name] = {"op_name": op_name, "kernel": kernel, "called": called,
+                     "owner": "", "via": ""}
+        names.append(name)
+        inner = [c for c in called if _is_name(c)]
+        named.append(op_name if _is_name(op_name)
+                     else inner[-1] if inner else "")
+        ops = _HLO_OPERANDS.search(head, m.end() - 1)
+        opcode, listed = ops.groups() if ops else ("", "")
+        operands.append([] if opcode in ("parameter", "constant")
+                        else _HLO_REF.findall(listed))
+        if opcode == "parameter":
+            params.setdefault(comp, {})[int(listed)] = i
+            if is_entry:
+                entry_params.add(i)
+        if m.group(1):
+            roots[comp] = i
+    _find_owners(out, names, named, operands, runs, roots, params,
+                 entry_params)
     return out
+
+
+def _find_owners(out, names, named, operands, runs, roots, params,
+                 entry_params):
+    """The two walks of :func:`parse_op_names`, each breadth-first from
+    every named instruction at once, in program order: the nearest name
+    wins, the earlier one on a tie. ``runs[i]`` lists the computations
+    instruction ``i`` runs, each with the operand its parameter 0 takes."""
+    index = {name: i for i, name in enumerate(names)}
+    n = len(names)
+    args = [[index[a] for a in ops if a in index] for ops in operands]
+    users = [[] for _ in range(n)]      # (user, operand position)
+    callers = {}                         # computation -> [caller]
+    root_of = {i: comp for comp, i in roots.items()}
+    for i in range(n):
+        for pos, a in enumerate(args[i]):
+            users[a].append((i, pos))
+        for comp, _ in runs[i]:
+            callers.setdefault(comp, []).append(i)
+    open_ = [not named[i] and i not in entry_params for i in range(n)]
+
+    def toward_operands(i):
+        # i reads its operands, and the ROOT of each computation it runs
+        yield from args[i]
+        for comp, _ in runs[i]:
+            if comp in roots:
+                yield roots[comp]
+
+    def toward_users(i):
+        # i is read by its users, by the parameter of a computation its
+        # user runs, and, as a ROOT, by the callers of its computation
+        for user, pos in users[i]:
+            yield user
+            for comp, first in runs[user]:
+                param = params.get(comp, {}).get(pos - first)
+                if param is not None:
+                    yield param
+        if i in root_of:
+            yield from callers.get(root_of[i], ())
+
+    for steps, via in ((toward_operands, "user"), (toward_users, "operand")):
+        label = list(named)
+        queue = deque(i for i in range(n) if named[i])
+        while queue:
+            i = queue.popleft()
+            for j in steps(i):
+                if label[j] or not open_[j]:
+                    continue
+                label[j] = label[i]
+                queue.append(j)
+                entry = out[names[j]]
+                if not entry["owner"]:
+                    entry["owner"], entry["via"] = label[i], via
 
 
 def op_names(key):

@@ -76,7 +76,7 @@ def test_map_covers_the_module_and_names_phases_blocks_and_attention(impl):
                                   re.M))
     assert instructions and set(names) == instructions
     for entry in names.values():
-        assert set(entry) == {"op_name", "kernel", "called"}
+        assert set(entry) == {"op_name", "kernel", "called", "owner", "via"}
     ops = [n["op_name"] for n in names.values() if n["op_name"]]
     forward = [o for o in ops if "jvp(" in o and "transpose(" not in o]
     backward = [o for o in ops if "transpose(jvp(" in o]
@@ -142,14 +142,144 @@ ENTRY %main.4 (x: f32[8]) -> f32[8] {
                           "flash_attention_fwd.7", "while.8"}
     assert names["fusion.5"] == {
         "op_name": "jit(s)/optimizer/mul", "kernel": "",
-        "called": ["jit(s)/jvp(n0)/n0_d0/add", "jit(s)/optimizer/mul"]}
+        "called": ["jit(s)/jvp(n0)/n0_d0/add", "jit(s)/optimizer/mul"],
+        "owner": "", "via": ""}
     assert names["fusion.5.remat"]["op_name"] == "jit(s)/jvp(n0)/n0_d0/add"
-    assert names["copy.6"] == {"op_name": "", "kernel": "", "called": []}
+    assert names["copy.6"] == {
+        "op_name": "", "kernel": "", "called": [],
+        "owner": "jit(s)/jvp(n0)/n0_a/attention/flash_attention_fwd/"
+                 "pallas_call", "via": "user"}
     assert names["x"]["op_name"] == "params['w']"
     assert names["flash_attention_fwd.7"]["kernel"] == "flash_attention_fwd"
     assert names["flash_attention_fwd.7"]["op_name"].endswith("pallas_call")
     assert names["tuple.9"]["op_name"].endswith("while/body/add")
     assert names["while.8"]["called"] == []
+
+
+# every case the owner walk has to meet: an async copy pair between two
+# named ops, a zero fill read by a named scatter inside a while body, an
+# op whose only user is the body's ROOT tuple, a compiler kernel with a
+# bare op_name between a named gather and a named multiply, a copy with
+# no named user, entry parameters
+_OWNED = """HloModule jit_s, is_scheduled=true
+
+%scatter_add (a: f32[], b: f32[]) -> f32[] {
+  %a = f32[] parameter(0)
+  %b = f32[] parameter(1)
+  ROOT %sum = f32[] add(%a, %b)
+}
+
+%body (t: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %t = (s32[], f32[8]{0}) parameter(0)
+  %i = s32[] get-tuple-element(%t), index=0
+  %acc = f32[8]{0} get-tuple-element(%t), index=1
+  %c0 = f32[] constant(0)
+  %one = s32[] constant(1)
+  %zeros = f32[8]{0:T(128)} broadcast(%c0), dimensions={}
+  %scatter.1 = f32[8]{0} scatter(%zeros, %i, %acc), update_window_dims={}, to_apply=%scatter_add, metadata={op_name="jit(s)/transpose(jvp(n0))/n0_moe/moe_experts/while/body/scatter-add"}
+  %next = s32[] add(%i, %one)
+  ROOT %tuple.2 = (s32[], f32[8]{0}) tuple(%next, %scatter.1)
+}
+
+%cond (t.c: (s32[], f32[8])) -> pred[] {
+  %t.c = (s32[], f32[8]{0}) parameter(0)
+  %i.c = s32[] get-tuple-element(%t.c), index=0
+  %n = s32[] constant(4)
+  ROOT %lt = pred[] compare(%i.c, %n), direction=LT, metadata={op_name="jit(s)/transpose(jvp(n0))/n0_moe/moe_experts/while/cond/lt"}
+}
+
+ENTRY %main (x: f32[8], w: f32[8]) -> (f32[8]) {
+  %x = f32[8]{0} parameter(0), sharding={replicated}, metadata={op_name="x"}
+  %w = f32[8]{0} parameter(1)
+  %gather.3 = f32[8]{0} gather(%x, %w), offset_dims={}, metadata={op_name="jit(s)/jvp(n0)/n0_moe/moe_experts/gather"}
+  %ragged-dot-none.4 = f32[8]{0:T(128)} custom-call(%gather.3, %w), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}, backend_config={"custom_call_config":{"body":"eA=="}}
+  %multiply.5 = f32[8]{0} multiply(%ragged-dot-none.4, %x), metadata={op_name="jit(s)/jvp(n0)/n0_moe/moe_experts/mul"}
+  %copy-start.6 = (f32[8]{0}, f32[8]{0:S(1)}, u32[]{:S(2)}) copy-start(%multiply.5)
+  %copy-done.7 = f32[8]{0:S(1)} copy-done(%copy-start.6)
+  %add.8 = f32[8]{0} add(%copy-done.7, %w), metadata={op_name="jit(s)/jvp(n0)/n0_out/add"}
+  %zero = s32[] constant(0)
+  %init = (s32[], f32[8]{0}) tuple(%zero, %add.8)
+  %while.9 = (s32[], /*index=1*/f32[8]{0}) while(%init), condition=%cond, body=%body, metadata={op_name="jit(s)/transpose(jvp(n0))/n0_moe/moe_experts/while"}
+  %out.10 = f32[8]{0} get-tuple-element(%while.9), index=1
+  %copy.11 = f32[8]{0} copy(%out.10)
+  ROOT %tuple.12 = (f32[8]{0}) tuple(%copy.11)
+}
+"""
+_S = "jit(s)/transpose(jvp(n0))/n0_moe/moe_experts/while"
+
+
+@pytest.mark.parametrize("instruction,owner,via", [
+    # the async pair between two named ops: made for the reader
+    ("copy-start.6", "jit(s)/jvp(n0)/n0_out/add", "user"),
+    ("copy-done.7", "jit(s)/jvp(n0)/n0_out/add", "user"),
+    # the zero fill of a scatter inside the while's body, and its constant
+    ("zeros", _S + "/body/scatter-add", "user"),
+    ("c0", _S + "/body/scatter-add", "user"),
+    # read only by the body's ROOT: made for the while that runs it
+    ("next", _S, "user"),
+    ("tuple.2", _S, "user"),
+    # the scatter's reducer, through its ROOT to the scatter
+    ("sum", _S + "/body/scatter-add", "user"),
+    # a compiler kernel with a bare op_name: for the multiply it feeds
+    ("ragged-dot-none.4", "jit(s)/jvp(n0)/n0_moe/moe_experts/mul", "user"),
+    # the while's operand
+    ("init", _S, "user"),
+    # no named user (the entry's ROOT): back through the get-tuple-element
+    # to the while that made the value
+    ("copy.11", _S, "operand"),
+    ("out.10", _S, "operand"),
+    ("tuple.12", _S, "operand"),
+    # entry parameters, named by argument or not, stay without one
+    ("x", "", ""),
+    ("w", "", ""),
+    # named instructions have none
+    ("gather.3", "", ""),
+    ("while.9", "", ""),
+])
+def test_every_instruction_with_no_name_gets_an_owner(instruction, owner,
+                                                      via):
+    names = perf.parse_op_names(_OWNED)
+    assert (names[instruction]["owner"], names[instruction]["via"]) \
+        == (owner, via)
+    assert names["ragged-dot-none.4"]["op_name"] == "ragged-dot-none"
+
+
+def test_owners_are_found_in_one_walk_over_a_long_chain():
+    """A chain of nameless copies: a search per instruction would take
+    minutes here; one walk over a users index takes well under a
+    second."""
+    n = 20000
+    lines = ["HloModule jit_s", "", "ENTRY %main (x: f32[8]) -> f32[8] {",
+             "  %x = f32[8]{0} parameter(0)",
+             "  %copy.0 = f32[8]{0} copy(%x)"]
+    lines += [f"  %copy.{i} = f32[8]{{0}} copy(%copy.{i - 1})"
+              for i in range(1, n)]
+    lines += [f'  ROOT %neg = f32[8]{{0}} negate(%copy.{n - 1}), '
+              'metadata={op_name="jit(s)/jvp(n0)/neg"}', "}"]
+    names = perf.parse_op_names("\n".join(lines))
+    assert all(names[f"copy.{i}"]["owner"] == "jit(s)/jvp(n0)/neg"
+               for i in range(n))
+    assert names["copy.0"]["via"] == "user"
+
+
+def test_a_compiled_step_leaves_no_op_without_an_owner():
+    """On XLA-CPU's compile of a small step, every instruction with no
+    name of the program's gets an owner, but for what holds no work:
+    parameters, constants, tuples, get-tuple-elements and bitcasts."""
+    _, trainer = _train_one_step()
+    text = next(iter(trainer._step._entries.values())).as_text()
+    names = perf.op_names(_step_key())
+    opcode = dict(re.findall(r"^\s+(?:ROOT )?%?([\w.\-]+) = (?:\(.*?\)|\S+) "
+                             r"([a-z][\w\-]*)\(", text, re.M))
+    nameless = [k for k, e in names.items() if "/" not in e["op_name"]
+                and not any("/" in c for c in e["called"])]
+    assert nameless
+    left = [k for k in nameless if not names[k]["owner"]
+            and opcode[k] not in ("parameter", "constant", "tuple",
+                                  "get-tuple-element", "bitcast")]
+    assert not left, [(k, opcode[k]) for k in left]
+    assert {names[k]["via"] for k in nameless} <= {"user", "operand", ""}
+    assert any(names[k]["via"] == "user" for k in nameless)
 
 
 def test_a_traced_compile_keeps_its_names_after_its_owner(tracing):
@@ -206,6 +336,7 @@ def test_untraced_nothing_is_recorded_read_or_scoped(monkeypatch):
         raise AssertionError("as_text() called with nobody asking")
 
     monkeypatch.setattr(jax.stages.Compiled, "as_text", refuse)
+    monkeypatch.setattr(perf, "parse_op_names", refuse)
     net, trainer = _train_one_step()
     assert trace.spans() == []
     # the eager path opens no scope: a block call outside a
