@@ -24,7 +24,9 @@ Phases (any failure -> non-zero exit, no result line):
            five gradients in bf16 against float32 autodiff of the
            ``jax.numpy`` form, beside what that form reads in bf16),
            the short convolution + SiLU kernels the same way, with each
-           kernel's time alone beside the ``jax.numpy`` form's,
+           kernel's time alone beside the ``jax.numpy`` form's, an
+           expert layer under ``Remat`` (output and gradients against
+           the scan's checkpoint placed inside its skip, both routings),
            paged decode attention (bf16 and int8 KV)
            and the int8 conv / FC ops, compiled, against the XLA dense
            composition in f32-highest.
@@ -67,6 +69,9 @@ FULL = {"image": 224, "batch": 256, "steps": 5,
         # ((B, T, W), the parts handed on, taps): the projection's output
         # of one of that cell's linear layers, [q | k | v | z]
         "conv_silu": ((1, 8192, 12288), (2048, 2048, 4096), 4),
+        # (tokens, d, experts routed over, held, top k, inner): an expert
+        # layer of that cell
+        "moe": (8192, 2048, 512, 16, 10, 512),
         "decode": {"b": 8, "h": 12, "d": 64, "page": 16, "pages": 256},
         # ResNet-18 stage-2 3x3 conv and the classifier, batch 128
         "conv": {"data": (128, 128, 28, 28), "weight": (128, 128, 3, 3)},
@@ -78,6 +83,7 @@ REHEARSAL = {"image": 64, "batch": 8, "steps": 2,
              "window": ((1, 2, 256, 64), 100),
              "delta_rule": (1, 150, 1, 2, 128, 128),
              "conv_silu": ((1, 70, 640), (128, 256, 128), 4),
+             "moe": (256, 64, 256, 16, 10, 32),
              "decode": {"b": 2, "h": 2, "d": 32, "page": 8, "pages": 8},
              "conv": {"data": (2, 8, 8, 8), "weight": (8, 8, 3, 3)},
              "fc": {"data": (4, 32), "weight": (16, 32)},
@@ -519,21 +525,146 @@ def conv_silu_check(shape, parts, taps, interpret, tag):
 
     if interpret:       # a time is the chip's or it is not written
         return
-
-    def ms(fn, *args, calls=30):
-        jax.block_until_ready(fn(*args))
-        t0 = time.perf_counter()
-        for _ in range(calls):
-            out = fn(*args)
-        jax.block_until_ready(out)
-        return (time.perf_counter() - t0) / calls * 1e3
-
-    times = {label: (ms(forward(fn), xh, wh), ms(backward(fn), xh, wh, dh))
+    times = {label: (wall_ms(forward(fn), xh, wh),
+                     wall_ms(backward(fn), xh, wh, dh))
              for label, fn in (("kernels", kernels), ("jax.numpy", form))}
     log(f"{tag} causal conv + SiLU, a layer alone, ms forward / backward "
         "(wall time over back-to-back calls): " + "; ".join(
             f"{label} {f:.3f} / {bw:.3f}"
             for label, (f, bw) in times.items()))
+
+
+def wall_ms(fn, *args, calls=30):
+    """Milliseconds a call of ``fn`` over back-to-back calls, after one
+    call that compiles it."""
+    import jax
+
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(calls):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / calls * 1e3
+
+
+def _moe_experts_skip_outside(data, weights, experts, gate_up, down):
+    """``ops.moe.moe_experts``'s result (``first_expert`` 0) with the
+    scan's per-block checkpoint inside the per-block skip instead of
+    around it: the arrangement whose backward pass stacks the scan's
+    loop-invariant inputs once per block, which the single block then
+    fills with zeros. The same arithmetic in the same order."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops.moe import _block_of_rows
+
+    held, d = gate_up.shape[0], data.shape[-1]
+    top_k = experts.shape[-1]
+    x = data.reshape(-1, d)
+    tokens = x.shape[0]
+    local = experts.reshape(tokens, top_k)
+    key = jnp.where((local >= 0) & (local < held), local, held).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
+                    dtype=jnp.int32)
+    flat_w = weights.reshape(-1).astype(jnp.float32)
+    offsets = jnp.concatenate([jnp.zeros(1, jnp.int32), jnp.cumsum(sizes)])
+    n_held = offsets[-1]
+
+    def block_of_rows(block):
+        return _block_of_rows(x, flat_w, order, offsets, gate_up, down,
+                              top_k, block)
+
+    def every_block():
+        def one(total, block):
+            part = jax.lax.cond(
+                block * tokens < n_held, jax.checkpoint(block_of_rows),
+                lambda _: jnp.zeros(x.shape, jnp.float32), block)
+            return total + part, None
+
+        return jax.lax.scan(one, jnp.zeros(x.shape, jnp.float32),
+                            jnp.arange(min(top_k, held)))[0]
+
+    out = jax.lax.cond(n_held <= tokens, lambda: block_of_rows(0),
+                       every_block)
+    return out.astype(x.dtype).reshape(data.shape)
+
+
+def moe_remat_check(shape, interpret, tag):
+    """One expert layer's router and grouped products in bf16 under
+    ``Remat``'s default policy, as a half-layer of Qwen3-Next runs them:
+    the output and the gradients of the tokens, the router weight and
+    both expert weights, against the same layer with the scan's
+    checkpoint inside its skip (``_moe_experts_skip_outside``), on the
+    same seed, for a free routing (the single block runs) and one that
+    holds every choice (the scan runs): largest difference over the
+    largest entry; then each form's step alone."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu import remat
+    from mxnet_tpu.ops.moe import moe_experts, moe_router
+
+    tokens, d, n_experts, held, top_k, inner = shape
+    rs = np.random.RandomState(6)
+
+    def draw(*dims, scale=1.0, dtype=jnp.bfloat16):
+        return jnp.asarray(rs.randn(*dims) * scale, dtype)
+
+    x = draw(tokens, d)
+    router_w = draw(n_experts, d, scale=d ** -0.5, dtype=jnp.float32)
+    gate_up = draw(held, d, 2 * inner, scale=d ** -0.5)
+    down = draw(held, inner, d, scale=inner ** -0.5)
+    weight = draw(tokens, d, dtype=jnp.float32)
+    counts = jnp.zeros(held + 1, jnp.float32)
+    names = ("out", "dx", "drouter", "dgate_up", "ddown")
+
+    def step(experts_of, bias):
+        def layer(x, router_w, gate_up, down):
+            weights, experts = moe_router(x, router_w, bias, top_k=top_k)
+            return experts_of(x, weights, experts, gate_up, down)
+
+        def loss(*a):
+            out = layer(*a)
+            return jnp.sum(out.astype(jnp.float32) * weight), out
+
+        grad = jax.value_and_grad(
+            jax.checkpoint(loss, policy=remat.resolve_policy(None)),
+            argnums=(0, 1, 2, 3), has_aux=True)
+
+        def run(*a):
+            (_, out), grads = grad(*a)
+            return (out,) + grads
+
+        return jax.jit(run)
+
+    forms = {"change": lambda *a: moe_experts(*a, counts)[0],
+             "skip outside": _moe_experts_skip_outside}
+    routings = {"free routing": None,
+                "every choice held": jnp.zeros(n_experts, jnp.float32)
+                .at[:held].set(30.0)}
+    for routing, bias in routings.items():
+        steps = {label: step(fn, bias) for label, fn in forms.items()}
+        got, want = (steps[label](x, router_w, gate_up, down)
+                     for label in forms)
+        n_held = int(jnp.sum(moe_router(x, router_w, bias,
+                                        top_k=top_k)[1] < held))
+        errs = [max_err(a, b) / max(float(jnp.max(jnp.abs(
+            b.astype(jnp.float32)))), 1e-30) for a, b in zip(got, want)]
+        log(f"{tag} expert layer {shape} bf16 under Remat, {routing} "
+            f"({n_held} held assignments, {tokens} tokens): the change "
+            "against the checkpoint inside the skip, largest err/largest "
+            "entry: " + " ".join(f"{n} {e:.2e}" for n, e in zip(names, errs)))
+        check(max(errs) <= BF16_ATOL,
+              f"expert layer under Remat, {routing}, outside bf16 "
+              f"tolerance: {errs}")
+        if not interpret:   # a time is the chip's or it is not written
+            times = {label: wall_ms(fn, x, router_w, gate_up, down)
+                     for label, fn in steps.items()}
+            log(f"{tag} expert layer, {routing}, ms a forward + "
+                "backward alone (wall time over back-to-back calls): "
+                + "; ".join(f"{k} {v:.3f}" for k, v in times.items()))
 
 
 def kernels_phase(sz, interpret, tag):
@@ -601,6 +732,7 @@ def kernels_phase(sz, interpret, tag):
     window_check(*sz["window"], interpret, tag)
     delta_rule_check(sz["delta_rule"], interpret, tag)
     conv_silu_check(*sz["conv_silu"], interpret, tag)
+    moe_remat_check(sz["moe"], interpret, tag)
 
     b_, h_, t_, d_ = sz["flash"][0]
     blocks = tune.schedule.flash_fwd_blocks(b_ * h_, t_, d_, "bfloat16",

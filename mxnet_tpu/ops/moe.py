@@ -18,7 +18,13 @@ held fit it (the usual case: a ``lax.cond`` on the count), and a
 ``lax.scan`` over the blocks of the worst case (every choice of every
 token held here), each recomputed in the backward pass and skipped
 when it holds no assignment, otherwise. Both are compiled, one runs,
-and neither keeps more than a block's intermediates.
+and neither keeps more than a block's intermediates. The scan's
+per-block checkpoint encloses the skip, so what the scan keeps for the
+backward pass is what the checkpoint closes over (the tokens, the
+expert weights, the sorted order), loop-invariant and kept once, never
+a copy per block: a ``lax.cond``'s branches all return the residuals
+of every branch, so per-block copies would be zeros that the single
+block, where it runs, writes and nothing reads.
 """
 from __future__ import annotations
 
@@ -121,12 +127,17 @@ def moe_experts(data, weights, experts, gate_up, down, counts,
                               top_k, block)
 
     def every_block():
-        # every choice of every token held here is min(k, held) blocks
-        def one(total, block):
-            part = jax.lax.cond(
-                block * tokens < n_held, jax.checkpoint(block_of_rows),
+        # every choice of every token held here is min(k, held) blocks;
+        # the checkpoint encloses the skip, so the scan keeps what it
+        # closes over once, not a copy per block
+        @jax.checkpoint
+        def part(block):
+            return jax.lax.cond(
+                block * tokens < n_held, block_of_rows,
                 lambda _: jnp.zeros(x.shape, jnp.float32), block)
-            return total + part, None
+
+        def one(total, block):
+            return total + part(block), None
 
         return jax.lax.scan(one, jnp.zeros(x.shape, jnp.float32),
                             jnp.arange(min(top_k, held)))[0]

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 import mxnet_tpu as mx
-from mxnet_tpu import parallel
+from mxnet_tpu import parallel, remat
 from mxnet_tpu.gluon import nn
 from mxnet_tpu.gluon.contrib import nn as contrib_nn
 from mxnet_tpu.gluon.model_zoo import qwen3_next_lm
@@ -402,27 +402,82 @@ def test_a_skewed_router_drops_no_token(favoured, expect):
         assert per_expert.sum() == 300 and idle == 0
 
 
-def test_one_block_of_rows_and_the_scan_over_blocks_agree():
-    """The same assignments through the single block (they fit the
-    tokens' count) and, the tokens given twice over so that they do not
-    fit half of it, through the scan: both are the reference's."""
+def _every_choice_held():
+    """60 tokens whose 3 choices all fall on the 7 experts held from 2:
+    180 assignments, 3 blocks of 60."""
     rng = _rng(13)
     w = _moe_weights(rng)
     x = _array(rng, 60, 32).at[..., 0].set(1.0)
     w["router_w"] = w["router_w"].at[2:9, 0].set(30.0)  # all 3 choices held
     weights, experts = moe_ops.moe_router(x, w["router_w"], top_k=3)
-    sizes = dict(SIZES, first_expert=2)
-    out, counts = moe_ops.moe_experts(x, weights, experts, w["gate_up"][2:9],
-                                      w["down"][2:9], jnp.zeros(8),
-                                      first_expert=2)
-    assert counts[:7].sum() == 180 and counts[7] == 0   # 3 blocks of 60
-    _close(out, ref.routed(x, _held(w, 2, 7), sizes))
-    grads = _grad(lambda x: jnp.sum(jnp.sin(moe_ops.moe_experts(
-        x, weights, experts, w["gate_up"][2:9], w["down"][2:9],
-        jnp.zeros(8), first_expert=2)[0])))(x)
-    want = _grad(lambda x: jnp.sum(jnp.sin(
-        _routed_with(x, weights, experts, w, 2, 7))))(x)
-    _close(grads, want, atol=1e-4)
+    return w, x, weights, experts
+
+
+@pytest.mark.parametrize("path", ["one block", "scan"])
+def test_one_block_of_rows_and_the_scan_over_blocks_agree(path):
+    """The same assignments through the single block (120 more tokens
+    that choose no held expert, so that the 180 fit the 180 tokens) and
+    through the scan (the 60 tokens alone, which 180 do not fit): the
+    result and, under ``Remat``'s default policy, the gradients of the
+    tokens and of both expert weights are the reference's."""
+    w, x, weights, experts = _every_choice_held()
+    idle = 120 if path == "one block" else 0
+    x = jnp.concatenate([x, _array(_rng(14), idle, 32)])
+    weights = jnp.concatenate([weights, jnp.full((idle, 3), 1 / 3)])
+    experts = jnp.concatenate([experts, jnp.tile(jnp.asarray(
+        [[0, 1, 12]], jnp.int32), (idle, 1))])
+    gate_up, down = w["gate_up"][2:9], w["down"][2:9]
+
+    def layer(x, gate_up, down):
+        return moe_ops.moe_experts(x, weights, experts, gate_up, down,
+                                   jnp.zeros(8), first_expert=2)
+
+    out, counts = layer(x, gate_up, down)
+    assert counts[:7].sum() == 180 and counts[7] == idle
+    _close(out, _routed_with(x, weights, experts, w, 2, 7))
+    _close(out[:60], ref.routed(x[:60], _held(w, 2, 7),
+                                dict(SIZES, first_expert=2)))
+    checkpointed = jax.checkpoint(lambda *a: layer(*a)[0],
+                                  policy=remat.resolve_policy(None))
+    grads = _grad(lambda *a: jnp.sum(jnp.sin(checkpointed(*a))),
+                  argnums=(0, 1, 2))(x, gate_up, down)
+
+    def reference(x, gate_up, down):
+        held = dict(w, gate_up=w["gate_up"].at[2:9].set(gate_up),
+                    down=w["down"].at[2:9].set(down))
+        return jnp.sum(jnp.sin(_routed_with(x, weights, experts, held, 2,
+                                            7)))
+
+    want = _grad(reference, argnums=(0, 1, 2))(x, gate_up, down)
+    for got_one, want_one in zip(grads, want):
+        _close(got_one, want_one, atol=1e-4)
+
+
+def test_the_scan_keeps_no_per_block_copy_of_its_inputs():
+    """The backward pass of the expert layer under ``Remat``'s default
+    policy: the scan over blocks keeps what its checkpoint closes over
+    once, not a stack of ``min(top_k, held)`` copies of the tokens or
+    the expert weights, which the single block that runs would then have
+    to fill with zeros."""
+    w, x, weights, experts = _every_choice_held()
+    gate_up, down = w["gate_up"][2:9], w["down"][2:9]
+    blocks = min(experts.shape[-1], gate_up.shape[0])
+    assert blocks > 1
+
+    def loss(x, gate_up, down):
+        return jnp.sum(jnp.sin(moe_ops.moe_experts(
+            x, weights, experts, gate_up, down, jnp.zeros(8),
+            first_expert=2)[0]))
+
+    grad = jax.grad(jax.checkpoint(loss, policy=remat.resolve_policy(None)),
+                    argnums=(0, 1, 2))
+    jaxpr = str(jax.make_jaxpr(grad)(x, gate_up, down))
+    hlo = jax.jit(grad).lower(x, gate_up, down).as_text()
+    stacks = [(blocks,) + a.shape for a in (x, gate_up, down)]
+    found = [s for s in stacks
+             if f"f32[{','.join(map(str, s))}]" in jaxpr
+             or f"tensor<{'x'.join(map(str, s))}xf32>" in hlo]
+    assert not found
 
 
 def _routed_with(x, weights, experts, w, first, count):
