@@ -4,12 +4,15 @@ over the forward time under the ``moe_experts`` scope (sort, gather,
 the grouped products, weighting and scatter).
 
 Least time of one layer = max(FLOPs / bf16 peak, bytes / HBM peak),
-summed over the expert layers. FLOPs: 2 x 3 x d x inner for each
-assignment the layer's ``expert_tokens`` state counted in the last step
-(gate, up and down of one expert). Bytes: the held experts' three
-matrices read once, each assignment's row read once and its result
-written once, in the compute dtype. Counts are the program's, shapes
-the configuration's, peaks the chip's published ones. Layer: moe."""
+summed over the expert layers. An expert has m matrices of d x inner,
+counted from the configuration: m = 2 where its own key says the experts
+are ungated (``mlp_hidden_act`` ``relu2``, as in ``nemotron_h``: down of
+relu(up x) squared, no gate), else m = 3 (gate, up and down). FLOPs:
+2 x m x d x inner for each assignment the layer's ``expert_tokens``
+state counted in the last step. Bytes: the held experts' m matrices
+read once, each assignment's row read once and its result written once,
+in the compute dtype. Counts are the program's, shapes the
+configuration's, peaks the chip's published ones. Layer: moe."""
 from benchmarks import scopes
 
 # the scope this reader needs in the program's names: a program that
@@ -19,13 +22,19 @@ SCOPE = "moe_experts"
 _BYTES = {"bfloat16": 2, "float16": 2, "float32": 4, None: 4}
 
 
+def matrices(config):
+    """An expert's matrices of d x inner: 2 ungated, 3 gated."""
+    return 2 if config.get("mlp_hidden_act") == "relu2" else 3
+
+
 def least_ms(config, assignments, peaks):
     """Least time in ms of one expert layer's grouped products for
     ``assignments`` (token, expert) pairs on the experts held."""
     d, inner = config["hidden_size"], config["moe_intermediate_size"]
     size = _BYTES[config["train"]["compute_dtype"]]
-    flops = 2 * 3 * d * inner * assignments
-    moved = (config["num_experts"] * 3 * d * inner
+    m = matrices(config)
+    flops = 2 * m * d * inner * assignments
+    moved = (config["num_experts"] * m * d * inner
              + 2 * assignments * d) * size
     return 1e3 * max(flops / peaks["bf16_flops_per_s"],
                      moved / peaks["hbm_bytes_per_s"])

@@ -1,18 +1,22 @@
-"""The accepted benchmark can grow: a sixth cell is laid over a copy of
-the manifest in a temporary directory by new files and entries alone (a
-configuration with ``reduced`` and ``published``, a model and a
-reference file that need not run, a rehearsal preset, the balanced
-traffic that is there, one new per-layer entry with its reader, the
-cell's name appended to every list an expert cell with one
-full-attention layer in five belongs to) and meets every rule of
+"""The accepted benchmark can grow, and grow again: one cell more is laid
+over a copy of the checkout in a temporary directory by new files and
+entries alone (a configuration with ``reduced`` and ``published``, a
+model and a reference file that need not run, a rehearsal preset, the
+balanced traffic that is there, one new per-layer entry with its reader,
+the cell's name appended to every list an ungated expert cell with one
+full-attention layer in seven belongs to) and meets every rule of
 ``rules.py``; then it is broken five ways, and each fault is refused by
-the rule that owns it. What a later ``model_config`` PR will do is
+the rule that owns it. The same is done over a copy that has grown once
+already, the way a ``model_config`` PR lays its cell, so no test here
+counts on the number of cells the checkout holds: each count is taken
+from the tree it reads. What a later ``model_config`` PR will do is
 rehearsed here, so that no test of the accepted benchmark has to give
 way for it."""
 import json
 import os
 import shutil
 import sys
+from typing import NamedTuple
 
 import pytest
 
@@ -26,16 +30,37 @@ from tests.benchmarks import rules  # noqa: E402
 CONFIG, CELL = "grown-lm", "grownlm_train_seq8192_balanced"
 TRAFFIC = "train_seq8192_bs1_balanced"
 NEW_METRIC = "grown_mixer_fwd_roofline"
-# an expert cell with one full-attention layer in five: the lists of
-# every training cell, attention's two times (its rooflines count every
-# layer as attention and are not for it), the four of the expert layers
+# an expert cell with attention layers: the lists of every training cell,
+# attention's two times (its rooflines count every layer as attention and
+# are not for it), the four of the expert layers
 TIMES = ("attention_fwd_ms_per_step", "attention_bwd_ms_per_step")
 MOE = ("moe_fwd_ms_per_step", "moe_bwd_ms_per_step",
        "moe_experts_fwd_roofline", "moe_busiest_expert_tokens")
+MOST = 24       # cells a manifest may hold
 
+# ungated experts (relu2: down of relu(up x) squared), 8 of 128 held,
+# top-6, one attention layer in seven
 CONFIG_FILE = {
     "name": CONFIG, "source": "https://example.org/grown-lm/config.json",
     "model": "grown_lm", "reference": "grown_lm", "item": "token",
+    "hidden_size": 2688, "moe_intermediate_size": 1856,
+    "mlp_hidden_act": "relu2", "num_attention_heads": 32,
+    "num_key_value_heads": 2, "head_dim": 128, "conv_kernel": 4,
+    "num_experts_per_tok": 6, "num_experts": 8, "vocab_size": 16384,
+    "num_layers": 7,
+    "layer_types": ["moe", "mamba", "moe", "mamba", "moe", "mamba",
+                    "full_attention"],
+    "published": {"num_experts": 128, "vocab_size": 131072,
+                  "num_hidden_layers": 52},
+    "deployment": {"chips_per_layer": 16, "first_expert": 0},
+    "reduced": ["num_layers", "num_experts", "vocab_size", "layer_types"],
+    "train": {"compute_dtype": "bfloat16"}}
+# the cell a grown copy already holds: gated experts, 8 of 32 held,
+# top-4, one full-attention layer in five
+EARLIER_FILE = {
+    "name": "earlier-lm",
+    "source": "https://example.org/earlier-lm/config.json",
+    "model": "earlier_lm", "reference": "earlier_lm", "item": "token",
     "hidden_size": 2048, "intermediate_size": 7168,
     "moe_intermediate_size": 1792, "num_attention_heads": 32,
     "num_key_value_heads": 8, "head_dim": 64, "conv_L_cache": 3,
@@ -75,6 +100,29 @@ def read(run):
 '''
 
 
+class Laid(NamedTuple):
+    """One cell as a PR lays it: its configuration file, its name, its
+    own per-layer entry and the two lines of ``why``."""
+    config: dict
+    cell: str
+    metric: str
+    config_why: str
+    cell_why: str
+
+
+GROWN = Laid(CONFIG_FILE, CELL, NEW_METRIC,
+             "Mamba-2 mixers, ungated experts and plain GQA attention: one "
+             "chip of 16 holds 8 of 128 experts, 1/8 vocabulary",
+             "1 x 8192 tokens at an even load: 3072 held rows a layer, "
+             "ungated experts")
+EARLIER = Laid(EARLIER_FILE, "earlierlm_train_seq8192_balanced",
+               "earlier_mixer_fwd_roofline",
+               "short convolutions and full attention 4:1, top-4 of 32 "
+               "experts: one chip of 4 holds 8, 1/8 vocabulary",
+               "1 x 8192 tokens at an even load: 8192 held rows a layer, "
+               "the edge of the expert layers' block rule")
+
+
 def _write(root, relative, text):
     path = os.path.join(root, *relative.split("/"))
     assert not os.path.exists(path), f"{relative} is there: that is an edit"
@@ -96,36 +144,34 @@ def _lists_of_every_training_cell(manifest):
             if set(rules.ACCEPTED_CELLS) <= set(m.get("workloads", ()))]
 
 
-def _lay_a_sixth_cell(root):
+def _lay(root, laid):
     """New files, and entries appended to BENCHMARK.json."""
-    _write(root, f"benchmarks/configs/{CONFIG}.json",
-           json.dumps(CONFIG_FILE, indent=1))
-    _write(root, "benchmarks/models/grown_lm.py", MODEL_FILE)
-    _write(root, "benchmarks/references/grown_lm.py",
+    config = laid.config
+    _write(root, f"benchmarks/configs/{config['name']}.json",
+           json.dumps(config, indent=1))
+    _write(root, f"benchmarks/models/{config['model']}.py", MODEL_FILE)
+    _write(root, f"benchmarks/references/{config['reference']}.py",
            '"""A plain reference that need not run."""\n')
-    _write(root, f"benchmarks/rehearsal/{CELL}.json",
+    _write(root, f"benchmarks/rehearsal/{laid.cell}.json",
            json.dumps({"config": {"hidden_size": 64}, "traffic": {}}))
-    _write(root, f"benchmarks/layer_metrics/{NEW_METRIC}.py", READER_FILE)
+    _write(root, f"benchmarks/layer_metrics/{laid.metric}.py", READER_FILE)
 
     def entries(manifest):
         manifest["configs"].append({
-            "name": CONFIG, "source": CONFIG_FILE["source"],
-            "file": f"benchmarks/configs/{CONFIG}.json",
-            "reduced": CONFIG_FILE["reduced"],
-            "why": "short convolutions and full attention 4:1, top-4 of "
-                   "32 experts: one chip of 4 holds 8, 1/8 vocabulary"})
+            "name": config["name"], "source": config["source"],
+            "file": f"benchmarks/configs/{config['name']}.json",
+            "reduced": config["reduced"], "why": laid.config_why})
         manifest["workloads"].append({
-            "name": CELL, "config": CONFIG, "traffic": TRAFFIC, "chips": 1,
-            "why": "1 x 8192 tokens at an even load: 8192 held rows a "
-                   "layer, the edge of the expert layers' block rule"})
+            "name": laid.cell, "config": config["name"], "traffic": TRAFFIC,
+            "chips": 1, "why": laid.cell_why})
         listed = {m["name"]: m for m in manifest["per_layer"]}
         for entry in _lists_of_every_training_cell(manifest) \
                 + [listed[name] for name in TIMES + MOE]:
-            entry["workloads"].append(CELL)
+            entry["workloads"].append(laid.cell)
         manifest["per_layer"].append({
-            "name": NEW_METRIC, "unit": "%", "better": "higher",
+            "name": laid.metric, "unit": "%", "better": "higher",
             "source": "device_trace", "layer": "kernels",
-            "moves": rules.RATE, "workloads": [CELL]})
+            "moves": rules.RATE, "workloads": [laid.cell]})
 
     _manifest(root, entries)
 
@@ -142,60 +188,110 @@ def _copy_of_the_checkout(root):
 def grown(tmp_path_factory):
     root = str(tmp_path_factory.mktemp("grown"))
     _copy_of_the_checkout(root)
-    _lay_a_sixth_cell(root)
+    _lay(root, GROWN)
     return root
 
 
-@pytest.mark.parametrize("rule", rules.ALL, ids=lambda rule: rule.__name__)
-def test_a_sixth_cell_laid_over_the_manifest_meets(grown, rule):
-    bench = rules.Bench(grown)
-    assert len(bench.cells) == len(rules.ACCEPTED_CELLS) + 1
+@pytest.fixture(scope="module")
+def earlier(tmp_path_factory):
+    """The checkout as a ``model_config`` PR leaves it: one cell more."""
+    root = str(tmp_path_factory.mktemp("earlier"))
+    _copy_of_the_checkout(root)
+    _lay(root, EARLIER)
+    return root
+
+
+@pytest.fixture(scope="module")
+def grown_twice(earlier, tmp_path_factory):
+    root = str(tmp_path_factory.mktemp("grown_twice"))
+    shutil.copytree(earlier, root, dirs_exist_ok=True)
+    _lay(root, GROWN)
+    return root
+
+
+def _meets(root, was, rule):
+    """One cell more than ``was`` holds, an expert cell, and the rule."""
+    bench = rules.Bench(root)
+    assert len(bench.cells) == len(rules.Bench(was).cells) + 1
     assert CELL in bench.cells_of("moe_busiest_expert_tokens")
     rule(bench)
 
 
-def test_the_sixth_cell_came_by_new_files_and_entries_alone(grown):
-    """No file of the checkout differs in the grown copy but
-    BENCHMARK.json, and there every entry that was accepted is as it was
-    but for the names appended to its list."""
-    for folder, _, files in os.walk(os.path.join(ROOT, "benchmarks")):
+@pytest.mark.parametrize("rule", rules.ALL, ids=lambda rule: rule.__name__)
+def test_a_sixth_cell_laid_over_the_manifest_meets(grown, rule):
+    """One cell more than the checkout holds (the sixth of six while
+    five are accepted)."""
+    _meets(grown, ROOT, rule)
+
+
+@pytest.mark.parametrize("rule", rules.ALL, ids=lambda rule: rule.__name__)
+def test_a_cell_laid_over_a_grown_copy_meets(earlier, grown_twice, rule):
+    _meets(grown_twice, earlier, rule)
+
+
+def _came_by_new_files_and_entries_alone(was, now):
+    """No file under ``benchmarks/`` of ``was`` differs in ``now``, and
+    in BENCHMARK.json every entry of ``was`` is as it was but for the
+    names appended to its list."""
+    for folder, _, files in os.walk(os.path.join(was, "benchmarks")):
         if os.path.basename(folder) in ("__pycache__", "fixtures"):
             continue
         for fname in files:
             path = os.path.join(folder, fname)
             with open(path, "rb") as f, open(os.path.join(
-                    grown, os.path.relpath(path, ROOT)), "rb") as g:
+                    now, os.path.relpath(path, was)), "rb") as g:
                 assert f.read() == g.read(), path
-    was, now = rules.Bench().manifest, rules.Bench(grown).manifest
+    before_all, after_all = rules.Bench(was).manifest, \
+        rules.Bench(now).manifest
     for key in ("command", "paths", "run_seconds"):
-        assert now[key] == was[key]
+        assert after_all[key] == before_all[key]
     for key in ("configs", "workloads", "end_to_end", "per_layer"):
-        assert len(now[key]) >= len(was[key])
-        for before, after in zip(was[key], now[key]):
+        assert len(after_all[key]) >= len(before_all[key])
+        for before, after in zip(before_all[key], after_all[key]):
             before, after = dict(before), dict(after)
             cells, more = before.pop("workloads", []), \
                 after.pop("workloads", [])
             assert after == before and more[:len(cells)] == cells
 
 
-def _a_seventh_cell_on_four_chips(root):
-    _write(root, "benchmarks/rehearsal/grownlm_train_dp4.json",
-           json.dumps({"config": {}, "traffic": {}}))
+def test_the_sixth_cell_came_by_new_files_and_entries_alone(grown):
+    _came_by_new_files_and_entries_alone(ROOT, grown)
 
+
+def test_the_cell_laid_over_a_grown_copy_came_by_new_files_and_entries(
+        earlier, grown_twice):
+    _came_by_new_files_and_entries_alone(earlier, grown_twice)
+
+
+def _four_chip_cells_past_a_quarter(root):
+    """Cells on four chips until they are one more than a quarter of the
+    cells allows (one always may): new ones while the manifest has room,
+    then one-chip cells laid after the accepted ones moved to four chips.
+    Returns the number of cells the rule's message names."""
     def entries(manifest):
-        manifest["workloads"].append({
-            "name": "grownlm_train_dp4", "config": CONFIG,
-            "traffic": "train_dp4_bs1024", "chips": 4,
-            "why": "a second cell that asks for four chips, of seven"})
-        for entry in _lists_of_every_training_cell(manifest):
-            entry["workloads"].append("grownlm_train_dp4")
+        cells = manifest["workloads"]
+        movable = [w for w in cells if w["chips"] == 1
+                   and w["name"] not in rules.ACCEPTED_CELLS]
+        while sum(w["chips"] == 4 for w in cells) \
+                <= max(1, len(cells) // 4):
+            if len(cells) == MOST:
+                movable.pop()["chips"] = 4
+                continue
+            name = f"grownlm_train_dp4_{len(cells)}"
+            cells.append({
+                "name": name, "config": CONFIG,
+                "traffic": f"train_dp4_bs1024_{len(cells)}", "chips": 4,
+                "why": "one more cell that asks for four chips"})
+            for entry in _lists_of_every_training_cell(manifest):
+                entry["workloads"].append(name)
 
     _manifest(root, entries)
+    return len(rules.Bench(root).cells)
 
 
 def _a_width_in_reduced(root):
     path = os.path.join(root, "benchmarks", "configs", CONFIG + ".json")
-    cut = dict(CONFIG_FILE, moe_intermediate_size=896, reduced=CONFIG_FILE[
+    cut = dict(CONFIG_FILE, moe_intermediate_size=928, reduced=CONFIG_FILE[
         "reduced"] + ["moe_intermediate_size"])
     with open(path, "w", encoding="utf-8") as f:
         json.dump(cut, f)
@@ -226,10 +322,12 @@ def _a_reader_file_no_entry_lists(root):
            READER_FILE)
 
 
-@pytest.mark.parametrize("fault,owner,says", [
-    (_a_seventh_cell_on_four_chips,
+# (fault, the rule that owns it, what the rule says: ``{cells}`` is the
+# count the fault returns)
+FAULTS = [
+    (_four_chip_cells_past_a_quarter,
      rules.accepted_cells_stand_and_few_take_four_chips,
-     "take four chips, of 7 cells"),
+     "take four chips, of {cells} cells"),
     (_a_width_in_reduced,
      rules.configurations_have_their_files_and_cut_no_width,
      "reduced names the width"),
@@ -241,15 +339,54 @@ def _a_reader_file_no_entry_lists(root):
     (_a_reader_file_no_entry_lists,
      rules.every_reader_file_is_listed_and_has_a_read,
      "orphan_ms_per_step.py is in neither"),
-], ids=lambda case: getattr(case, "__name__", None))
+]
+
+
+def _fault_name(case):
+    return getattr(case, "__name__", None)
+
+
+def _refused(tree, root, fault, owner, says):
+    shutil.copytree(tree, root)
+    owner(rules.Bench(root))        # sound before the fault
+    cells = fault(root)
+    with pytest.raises(AssertionError, match=says.format(cells=cells)):
+        owner(rules.Bench(root))
+
+
+@pytest.mark.parametrize("fault,owner,says", FAULTS, ids=_fault_name)
 def test_a_fault_is_refused_by_the_rule_that_owns_it(grown, tmp_path, fault,
                                                      owner, says):
-    root = str(tmp_path / "broken")
+    _refused(grown, str(tmp_path / "broken"), fault, owner, says)
+
+
+@pytest.mark.parametrize("fault,owner,says", FAULTS, ids=_fault_name)
+def test_a_fault_of_a_grown_copy_is_refused_by_the_rule_that_owns_it(
+        grown_twice, tmp_path, fault, owner, says):
+    _refused(grown_twice, str(tmp_path / "broken"), fault, owner, says)
+
+
+@pytest.mark.parametrize("count", [12, MOST - 1, MOST])
+def test_too_many_four_chip_cells_are_refused_at_any_count(grown, tmp_path,
+                                                          count):
+    """The grown copy filled with one-chip cells up to ``count`` (or as
+    it is, where it holds more), then the four-chip fault: its count, and
+    the rule's message, follow the tree."""
+    root = str(tmp_path / "filled")
     shutil.copytree(grown, root)
-    owner(rules.Bench(root))        # sound before the fault
-    fault(root)
-    with pytest.raises(AssertionError, match=says):
-        owner(rules.Bench(root))
+
+    def fill(manifest):
+        cells = manifest["workloads"]
+        while len(cells) < count:
+            cells.append({"name": f"filler_{len(cells)}", "config": CONFIG,
+                          "traffic": f"filler_{len(cells)}", "chips": 1,
+                          "why": "a one-chip cell that fills the manifest"})
+
+    _manifest(root, fill)
+    _refused(root, str(tmp_path / "broken"),
+             _four_chip_cells_past_a_quarter,
+             rules.accepted_cells_stand_and_few_take_four_chips,
+             "take four chips, of {cells} cells")
 
 
 def test_a_cell_listed_under_a_reader_that_cannot_count_it_fails_here(
