@@ -5,11 +5,14 @@ Concurrent/HybridConcurrent (parallel branches, concatenated),
 Identity, SparseEmbedding, SyncBatchNorm. Beyond the reference: Remat,
 MultiHeadAttention, and the blocks of today's decoder layers --
 GatedAttention (grouped K/V heads, rotary on part of a head, an output
-gate), GatedDeltaNet (linear attention by the gated delta rule),
-GatedMLP and SparseMoE (an expert layer told which experts it holds).
+gate), GroupedQueryAttention (grouped K/V heads and nothing else),
+GatedDeltaNet (linear attention by the gated delta rule), Mamba2Mixer
+(the Mamba-2 state-space mixer), GatedMLP, SquaredReLUMLP and SparseMoE
+(an expert layer told which experts it holds).
 """
 from __future__ import annotations
 
+import math as _math
 import time
 import warnings
 
@@ -20,8 +23,9 @@ from ...observability import trace as _obs_trace
 from ..block import Block, HybridBlock
 
 __all__ = ["Remat", "Concurrent", "HybridConcurrent", "Identity", "SparseEmbedding",
-           "SyncBatchNorm", "GatedAttention",
-           "GatedDeltaNet", "GatedMLP", "SparseMoE"]
+           "SyncBatchNorm", "GatedAttention", "GroupedQueryAttention",
+           "GatedDeltaNet", "Mamba2Mixer", "GatedMLP", "SquaredReLUMLP",
+           "SparseMoE"]
 
 
 class Concurrent(_nn.Sequential):
@@ -469,6 +473,164 @@ class GatedDeltaNet(HybridBlock):
         return self.out_proj(F.reshape(out, shape=(b, t, value_dim)))
 
 
+class GroupedQueryAttention(HybridBlock):
+    """Causal grouped-query self-attention with nothing around its core,
+    as ``nemotron_h``'s attention layers have it: ``q_proj`` gives
+    ``num_heads`` queries of ``head_dim``, ``k_proj`` and ``v_proj``
+    ``num_kv_heads`` keys and values, each serving ``num_heads /
+    num_kv_heads`` query heads; softmax of ``q k^T / sqrt(head_dim)``
+    over every earlier key; ``out_proj``. No positions (rotary or any
+    other), no gate, no norm on q or k, no biases. ``impl`` as
+    MultiHeadAttention's 'dense' / 'flash'; scores, softmax and values
+    under the scope ``attention``. x (B, T, units)."""
+
+    def __init__(self, units, num_heads, num_kv_heads, head_dim,
+                 impl="dense", **kwargs):
+        super().__init__(**kwargs)
+        if num_heads % num_kv_heads:
+            raise ValueError(f"num_heads {num_heads} not divisible by "
+                             f"num_kv_heads {num_kv_heads}")
+        if impl not in ("dense", "flash"):
+            raise ValueError(f"unknown impl {impl!r}")
+        self._heads, self._kv, self._dim = num_heads, num_kv_heads, head_dim
+        self._impl = impl
+        with self.name_scope():
+            self.q_proj = _dense(num_heads * head_dim, units, "q_")
+            self.k_proj = _dense(num_kv_heads * head_dim, units, "k_")
+            self.v_proj = _dense(num_kv_heads * head_dim, units, "v_")
+            self.out_proj = _dense(units, num_heads * head_dim, "out_")
+
+    def hybrid_forward(self, F, x):
+        b, t = x.shape[0], x.shape[1]
+        h, kv, d = self._heads, self._kv, self._dim
+
+        def heads_first(proj, n):
+            return F.transpose(F.reshape(proj(x), shape=(b, t, n, d)),
+                               axes=(0, 2, 1, 3))
+
+        q = heads_first(self.q_proj, h)
+        k, v = heads_first(self.k_proj, kv), heads_first(self.v_proj, kv)
+        if h != kv:
+            k = F.repeat(k, repeats=h // kv, axis=1)
+            v = F.repeat(v, repeats=h // kv, axis=1)
+        with _jit.scope("attention"):
+            out = F.scaled_dot_product_attention(
+                q, k, v, causal=True,
+                impl="flash" if self._impl == "flash" else "xla")
+        out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
+                        shape=(b, t, h * d))
+        return self.out_proj(out)
+
+
+class Mamba2Mixer(HybridBlock):
+    """The Mamba-2 mixer (Dao & Gu, arXiv:2405.21060), as
+    ``nemotron_h``'s ``M`` layers have it. ``in_proj`` gives, laid
+    [x | B | C | z | dt]: x (``num_heads`` of ``head_dim``), B and C
+    (``n_groups`` of ``state_size`` each), the output gate z (as wide as
+    x) and dt (one a head). [x, B, C] pass a causal depthwise
+    convolution of ``conv_kernel`` taps with a bias, then SiLU
+    (``causal_conv_silu``: on a TPU one Pallas kernel pass each way that
+    hands x, B, C on as three arrays); ``dt = softplus(dt + dt_bias)``
+    (no clamp: the published ``time_step_limit`` ``(0, inf)`` holds
+    every softplus), ``A = -exp(A_log)``, in float32; the chunked state-space scan (``ops/state_space.py``)
+    with the skip ``D x``; the gated RMSNorm ``norm(y * silu(z))`` over
+    ``n_groups`` groups of the channels, float32 inside, times its
+    weight; ``out_proj``. No other biases. All but the two projections
+    sits under the scope ``ssm``. x (B, T, units).
+
+    Initial values as the published modeling code sets them:
+    ``A_log = log(1 .. num_heads)``, ``D = 1``, ``dt_bias`` the inverse
+    softplus of a step drawn log-uniform in ``dt_init`` = (min, max,
+    floor); the convolution uniform in +-``conv_kernel ** -0.5``."""
+
+    def __init__(self, units, num_heads, head_dim, n_groups, state_size,
+                 conv_kernel=4, chunk=128, epsilon=1e-5,
+                 dt_init=(0.001, 0.1, 1e-4), **kwargs):
+        super().__init__(**kwargs)
+        if num_heads % n_groups:
+            raise ValueError(f"num_heads {num_heads} not divisible by "
+                             f"n_groups {n_groups}")
+        self._h, self._p, self._g, self._n = (num_heads, head_dim, n_groups,
+                                              state_size)
+        self._chunk, self._eps = int(chunk), float(epsilon)
+        inner = num_heads * head_dim
+        conv_dim = inner + 2 * n_groups * state_size
+        from ... import ndarray as nd
+
+        def dt_bias(shape):
+            lo, hi, floor = (float(v) for v in dt_init)
+            dt = nd.exp(nd.random.uniform(0, 1, shape)
+                        * (float(_math.log(hi)) - float(_math.log(lo)))
+                        + float(_math.log(lo)))
+            dt = nd.clip(dt, floor, float("inf"))
+            return dt + nd.log(-nd.expm1(-dt))     # softplus(this) = dt
+
+        with self.name_scope():
+            self.in_proj = _dense(conv_dim + inner + num_heads, units, "in_")
+            self.conv_weight = self.params.get(
+                "conv_weight", shape=(conv_dim, conv_kernel),
+                init=_init.Uniform(conv_kernel ** -0.5))
+            self.conv_bias = self.params.get(
+                "conv_bias", shape=(conv_dim,),
+                init=_init.Uniform(conv_kernel ** -0.5))
+            self.A_log = self.params.get(
+                "A_log", shape=(num_heads,), init=_Own(
+                    lambda shape: nd.log(nd.arange(1, shape[0] + 1))))
+            self.D = self.params.get("D", shape=(num_heads,),
+                                     init=_Own(nd.ones))
+            self.dt_bias = self.params.get("dt_bias", shape=(num_heads,),
+                                           init=_Own(dt_bias))
+            self.norm_weight = self.params.get("norm_weight", shape=(inner,),
+                                               init=_Own(nd.ones))
+            self.out_proj = _dense(units, inner, "out_")
+
+    def hybrid_forward(self, F, x, conv_weight, conv_bias, A_log, D, dt_bias,
+                       norm_weight):
+        b, t = x.shape[0], x.shape[1]
+        h, p, g, n = self._h, self._p, self._g, self._n
+        inner = h * p
+        proj = self.in_proj(x)
+
+        with _jit.scope("ssm"):
+            xs, bs, cs, rest = F.causal_conv_silu(
+                proj, conv_weight, conv_bias, parts=(inner, g * n, g * n))
+            z = F.slice_axis(rest, axis=-1, begin=0, end=inner)
+            dt = F.Activation(
+                F.cast(F.slice_axis(rest, axis=-1, begin=inner,
+                                    end=inner + h), dtype="float32")
+                + F.cast(dt_bias, dtype="float32"), act_type="softrelu")
+            y = F.mamba_chunk_scan(
+                F.reshape(xs, shape=(b, t, h, p)), dt,
+                -F.exp(F.cast(A_log, dtype="float32")),
+                F.reshape(bs, shape=(b, t, g, n)),
+                F.reshape(cs, shape=(b, t, g, n)), D, chunk=self._chunk)
+            gated = F.cast(F.reshape(y, shape=(b, t, g, inner // g)),
+                           dtype="float32") * F.Activation(
+                F.cast(F.reshape(z, shape=(b, t, g, inner // g)),
+                       dtype="float32"), act_type="silu")
+            # the weight as (groups, channels of a group): one norm a group
+            y = F.RMSNorm(gated, F.reshape(norm_weight,
+                                           shape=(g, inner // g)),
+                          eps=self._eps)
+            y = F.cast(F.reshape(y, shape=(b, t, inner)),
+                       dtype=str(proj.dtype))
+        return self.out_proj(y)
+
+
+class SquaredReLUMLP(HybridBlock):
+    """Ungated MLP without biases: ``down(relu(up x)^2)``
+    (``nemotron_h``'s ``relu2``)."""
+
+    def __init__(self, units, hidden, **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            self.up = _dense(hidden, units, "up_")
+            self.down = _dense(units, hidden, "down_")
+
+    def hybrid_forward(self, F, x):
+        return self.down(F.square(F.relu(self.up(x))))
+
+
 class GatedMLP(HybridBlock):
     """SiLU-gated MLP without biases:
     ``down(silu(gate x) * up x)``, gate and up in one projection
@@ -505,6 +667,11 @@ class SparseMoE(HybridBlock):
     expert behind a sigmoid gate, ``sigmoid(shared_gate x) * shared(x)``
     (``shared_gate=False``: ``shared(x)`` as it is).
 
+    ``activation='relu2'`` takes ungated experts, each
+    ``down_e(relu(up_e x)^2)`` with its up matrix ``experts_up_weight``
+    (count, units, hidden), and the shared expert a ``SquaredReLUMLP``
+    (``nemotron_h``'s); the default 'swiglu' is the gated form above.
+
     ``score_func='sigmoid'`` scores each expert on its own;
     ``route_scale`` multiplies the chosen weights; ``expert_bias=True``
     keeps a per-expert state (all ``num_experts_total``, no gradient,
@@ -521,8 +688,15 @@ class SparseMoE(HybridBlock):
     def __init__(self, units, hidden, num_experts_total, top_k,
                  experts_held=None, shared_hidden=0, renormalize=True,
                  score_func="softmax", route_scale=1.0, expert_bias=False,
-                 shared_gate=True, **kwargs):
+                 shared_gate=True, activation="swiglu", **kwargs):
         super().__init__(**kwargs)
+        from ...ops.moe import ACTIVATIONS
+
+        if activation not in ACTIVATIONS:
+            raise ValueError(f"unknown activation {activation!r}; known: "
+                             f"{ACTIVATIONS}")
+        gated = activation == "swiglu"
+        self._activation = activation
         if experts_held is None:
             experts_held = (0, num_experts_total)
         if isinstance(experts_held, range):
@@ -541,9 +715,15 @@ class SparseMoE(HybridBlock):
         with self.name_scope():
             self.router_weight = self.params.get(
                 "router_weight", shape=(num_experts_total, units))
-            self.experts_gate_up_weight = self.params.get(
-                "experts_gate_up_weight", shape=(count, units, 2 * hidden),
-                init=per_expert(units, hidden))
+            if gated:
+                self.experts_gate_up_weight = self.params.get(
+                    "experts_gate_up_weight",
+                    shape=(count, units, 2 * hidden),
+                    init=per_expert(units, hidden))
+            else:
+                self.experts_up_weight = self.params.get(
+                    "experts_up_weight", shape=(count, units, hidden),
+                    init=per_expert(units, hidden))
             self.experts_down_weight = self.params.get(
                 "experts_down_weight", shape=(count, hidden, units),
                 init=per_expert(hidden, units))
@@ -555,25 +735,28 @@ class SparseMoE(HybridBlock):
                     "expert_bias", shape=(num_experts_total,),
                     grad_req="null", init="zeros", differentiable=False)
             if shared_hidden:
-                self.shared = GatedMLP(units, shared_hidden,
-                                       prefix="shared_")
+                self.shared = (GatedMLP if gated else SquaredReLUMLP)(
+                    units, shared_hidden, prefix="shared_")
                 self.shared_gate = _dense(1, units, "shared_gate_") \
                     if shared_gate else None
             else:
                 self.shared = self.shared_gate = None
 
-    def hybrid_forward(self, F, x, router_weight, experts_gate_up_weight,
-                       experts_down_weight, expert_tokens, expert_bias=None):
+    def hybrid_forward(self, F, x, router_weight, experts_down_weight,
+                       expert_tokens, experts_gate_up_weight=None,
+                       experts_up_weight=None, expert_bias=None):
         with _jit.scope("moe"):
             with _jit.scope("moe_router"):
                 scored = (x, router_weight) if expert_bias is None \
                     else (x, router_weight, expert_bias)
                 weights, experts = F.moe_router(*scored, **self._route)
             with _jit.scope("moe_experts"):
-                out = F.moe_experts(x, weights, experts,
-                                    experts_gate_up_weight,
+                up = experts_gate_up_weight if experts_up_weight is None \
+                    else experts_up_weight
+                out = F.moe_experts(x, weights, experts, up,
                                     experts_down_weight, expert_tokens,
-                                    first_expert=self._first)
+                                    first_expert=self._first,
+                                    activation=self._activation)
             if self.shared is not None:
                 if self.shared_gate is None:
                     out = out + self.shared(x)

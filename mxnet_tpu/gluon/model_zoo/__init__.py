@@ -6,3 +6,5 @@ from . import qwen3_next
 from .qwen3_next import Qwen3NextBlock, Qwen3NextLM, qwen3_next_lm
 from . import trinity
 from .trinity import TrinityBlock, TrinityLM, trinity_lm
+from . import nemotron_h
+from .nemotron_h import NemotronHBlock, NemotronHLM, nemotron_h_lm

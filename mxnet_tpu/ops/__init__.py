@@ -11,6 +11,7 @@ from . import nn as _nn                # noqa: F401  neural-net kernels
 from . import rnn as _rnn              # noqa: F401  fused RNN
 from . import linear_attention as _la  # noqa: F401  gated delta rule
 from . import moe as _moe              # noqa: F401  router, grouped experts
+from . import state_space as _ssm      # noqa: F401  Mamba-2 chunked scan
 from . import optimizer_ops as _opt    # noqa: F401  optimizer updates
 from . import random_ops as _rand      # noqa: F401  samplers
 from . import detection as _det        # noqa: F401  SSD/R-CNN contrib ops

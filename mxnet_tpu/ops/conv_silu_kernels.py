@@ -35,6 +35,13 @@ A sequence that is not a multiple of the row tile hangs over the end of
 its last tile: going forward what is read there only reaches rows that
 are not written; going back those rows are masked.
 
+A per-channel bias, where the call has one (Mamba-2's short
+convolution), is added to the taps' sum before the SiLU: it travels as
+one more row of the weight block, read like a tap whose input is a row
+of ones, so its gradient is the sum of ``d pre`` over the rows, kept in
+the weight gradient's accumulator beside the taps'. A call without one
+builds the kernels as they are without the row.
+
 None of the forward's results is named ``remat.KERNEL_RESIDUAL``: it is
 one pass over its input, cheaper to run again than to keep.
 
@@ -137,23 +144,27 @@ def _conv(inputs, w):
     return pre
 
 
-def _fwd_kernel(x_ref, prev_ref, w_ref, o_ref, *, taps, rows, cols):
+def _fwd_kernel(x_ref, prev_ref, w_ref, o_ref, *, taps, rows, cols,
+                biased=False):
     """Grid (B, column tiles, row tiles): x_ref, o_ref (1, rows, cols);
     prev_ref (1, halo, cols), the rows before the tile; w_ref (taps,
-    cols) float32. A pass hands the next its last 8 rows."""
+    cols) float32, and the bias as one more row where ``biased``. A pass
+    hands the next its last 8 rows."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     f32 = jnp.float32
     for lanes in _passes(cols):
-        w = _taps_of(w_ref, taps, lanes)
+        w = _taps_of(w_ref, taps + biased, lanes)
 
         def one_pass(r, before, lanes=lanes, w=w):
             r0 = pl.multiple_of(r * _SUB, _SUB)
             x = x_ref[0, pl.ds(r0, _SUB), lanes].astype(f32)
             pre = _conv([_behind(before, x, taps - 1 - j)
-                         for j in range(taps)], w)
+                         for j in range(taps)], w[:taps])
+            if biased:
+                pre = pre + w[taps]
             o_ref[0, pl.ds(r0, _SUB), lanes] = (pre * _sigmoid(pre)).astype(
                 o_ref.dtype)
             return x[_SUB - _TILE:]
@@ -164,14 +175,15 @@ def _fwd_kernel(x_ref, prev_ref, w_ref, o_ref, *, taps, rows, cols):
                       prev_ref[0, -_TILE:, lanes].astype(f32)))
 
 
-def _bwd_kernel(*refs, taps, rows, cols, t, aliased):
+def _bwd_kernel(*refs, taps, rows, cols, t, aliased, biased=False):
     """Grid as the forward's, the row axis sequential: x_ref, dy_ref,
     dx_ref (1, rows, cols); prev_ref, next_ref, dnext_ref (1, halo,
     cols): the input's rows before and after the tile, dy's after it;
     w_ref (taps, cols) float32; dw_ref (1, taps, cols) float32, written
     at the column's last row tile; xbuf (halo + rows + halo, cols)
     float32 scratch, the input [before | tile | after]; acc (taps, 8,
-    cols) float32 scratch. The passes run from the tile's last to its
+    cols) float32 scratch. ``biased``: w_ref, dw_ref and acc have one
+    row more, the bias's. The passes run from the tile's last to its
     first, each handing the one above it the first 8 rows of its
     ``d pre``. ``aliased``: the array dx is written into comes first
     among the operands, untouched."""
@@ -193,7 +205,7 @@ def _bwd_kernel(*refs, taps, rows, cols, t, aliased):
         acc[...] = jnp.zeros_like(acc)
 
     def columns(lanes):
-        w = _taps_of(w_ref, taps, lanes)
+        w = _taps_of(w_ref, taps + biased, lanes)
         # the input in float32; zeros past the sequence's end (what is
         # read there may be anything)
         xbuf[0:halo, lanes] = jnp.where(i == 0, 0.0,
@@ -218,7 +230,9 @@ def _bwd_kernel(*refs, taps, rows, cols, t, aliased):
             before = xbuf[pl.ds(at - _TILE, _TILE), lanes]
             x = xbuf[pl.ds(at, _SUB), lanes]
             inputs = [_behind(before, x, taps - 1 - j) for j in range(taps)]
-            pre = _conv(inputs, w)
+            pre = _conv(inputs, w[:taps])
+            if biased:
+                pre = pre + w[taps]
             sig = _sigmoid(pre)
             d = dy.astype(f32) * (sig * (1.0 + pre * (1.0 - sig)))
             if masked:
@@ -231,18 +245,20 @@ def _bwd_kernel(*refs, taps, rows, cols, t, aliased):
             inputs, d = through_silu(
                 r0, dy_ref[0, pl.ds(r0, _SUB), lanes], ragged)
             dx = _conv([_ahead(d, after, taps - 1 - j) for j in range(taps)],
-                       w)
+                       w[:taps])
             dx_ref[0, pl.ds(r0, _SUB), lanes] = dx.astype(dx_ref.dtype)
+            # the bias's input is a row of ones: its term is d itself
             sums = tuple(
-                s + sum((d * x)[m:m + _TILE] for m in range(0, _SUB, _TILE))
-                for s, x in zip(sums, inputs))
+                s + sum((d if x is None else d * x)[m:m + _TILE]
+                        for m in range(0, _SUB, _TILE))
+                for s, x in zip(sums, inputs + [None] * biased))
             return d[:_TILE], sums
 
         _, below = through_silu(rows, dnext_ref[0, :_SUB, lanes], True)
         _, sums = jax.lax.fori_loop(
             0, n, back,
             (below[:_TILE], tuple(jnp.zeros((_TILE, lanes.size), f32)
-                                  for _ in range(taps))))
+                                  for _ in range(taps + biased))))
         for j, s in enumerate(sums):
             acc[j, :, lanes] += s
 
@@ -256,12 +272,13 @@ def _bwd_kernel(*refs, taps, rows, cols, t, aliased):
 
 @functools.lru_cache(maxsize=64)
 def _build(kind, b, t, width, offset, channels, taps, rows, cols, dtype_str,
-           aliased, interpret):
+           aliased, interpret, biased=False):
     """The ``pallas_call`` of one kernel (``kind``: 'fwd', 'bwd') over
     the ``channels`` columns from ``offset`` of a (B, T, ``width``)
     input, at one dtype and tile. The backward writes its columns of a
     (B, T, ``width``) gradient: a new array, or (``aliased``) its first
-    operand."""
+    operand. ``biased``: the weight block carries the bias as one more
+    row, and the backward's weight gradient one more row, the bias's."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -291,18 +308,20 @@ def _build(kind, b, t, width, offset, channels, taps, rows, cols, dtype_str,
             lambda n, c, i: (n, jnp.minimum((i + 1) * per, last_halo),
                              at + c))
 
-    w_spec = pl.BlockSpec((taps, cols), lambda n, c, i: (0, c))
+    rows_w = taps + biased      # the weight block's rows
+    w_spec = pl.BlockSpec((rows_w, cols), lambda n, c, i: (0, c))
     part = jax.ShapeDtypeStruct((b, t, channels), dtype)
     params = dict(
         grid=(b, channels // cols, -(-t // rows)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=sched.conv_silu_vmem_limit(
-                name, rows, cols, taps, dtype.itemsize)),
+                name, rows, cols, rows_w, dtype.itemsize)),
         interpret=interpret)
     if not back:
         return pl.pallas_call(
-            functools.partial(_fwd_kernel, taps=taps, rows=rows, cols=cols),
+            functools.partial(_fwd_kernel, taps=taps, rows=rows, cols=cols,
+                              biased=biased),
             in_specs=[tile(first), before(first), w_spec],
             out_specs=tile(0), out_shape=part, name="causal_" + name,
             **params)
@@ -313,25 +332,26 @@ def _build(kind, b, t, width, offset, channels, taps, rows, cols, dtype_str,
         params["input_output_aliases"] = {0: 0}
     return pl.pallas_call(
         functools.partial(_bwd_kernel, taps=taps, rows=rows, cols=cols, t=t,
-                          aliased=aliased),
+                          aliased=aliased, biased=biased),
         in_specs=in_specs,
         out_specs=[tile(first),
-                   pl.BlockSpec((1, taps, cols), lambda n, c, i: (n, 0, c))],
+                   pl.BlockSpec((1, rows_w, cols), lambda n, c, i: (n, 0, c))],
         out_shape=[jax.ShapeDtypeStruct((b, t, width), dtype),
-                   jax.ShapeDtypeStruct((b, taps, channels), f32)],
+                   jax.ShapeDtypeStruct((b, rows_w, channels), f32)],
         scratch_shapes=[pltpu.VMEM((rows + 2 * halo, cols), f32),
-                        pltpu.VMEM((taps, _TILE, cols), f32)],
+                        pltpu.VMEM((rows_w, _TILE, cols), f32)],
         name="causal_" + name, **params)
 
 
 def causal_conv_silu_kernels(x, weight, parts, interpret=False, rows=None,
-                             cols=None, bwd_rows=None, bwd_cols=None):
+                             cols=None, bwd_rows=None, bwd_cols=None,
+                             bias=None):
     """``causal_conv_silu`` through the kernels: same arguments, same
     results (a tuple: the parts, then the columns of ``x`` past them),
-    differentiable in ``x`` and ``weight``. ``rows`` / ``cols`` and
-    ``bwd_rows`` / ``bwd_cols`` override the schedules' tiles (the
-    search driver's candidates). Raises ``ScheduleError`` for a shape
-    the kernels do not take
+    differentiable in ``x``, ``weight`` and ``bias`` (C,) where there is
+    one. ``rows`` / ``cols`` and ``bwd_rows`` / ``bwd_cols`` override
+    the schedules' tiles (the tuning search's candidates). Raises
+    ``ScheduleError`` for a shape the kernels do not take
     (``tune.schedule.conv_silu_shape_supported``)."""
     import jax
     import jax.numpy as jnp
@@ -348,6 +368,7 @@ def causal_conv_silu_kernels(x, weight, parts, interpret=False, rows=None,
             f"{sched.LANES}-lane grid, 1 to {sched.CONV_SILU_MAX_TAPS} taps)")
     dtype = x.dtype
     interpret = bool(interpret)
+    biased = bias is not None
     offsets = [sum(parts[:n]) for n in range(len(parts))]
 
     def calls(kind, want_rows, want_cols):
@@ -360,7 +381,7 @@ def causal_conv_silu_kernels(x, weight, parts, interpret=False, rows=None,
                 interpret=interpret, rows=want_rows, cols=want_cols)
             out.append((_build(kind, b, t, width, at, size, taps, *tile,
                                str(dtype), kind == "bwd" and n > 0,
-                               interpret), at, size))
+                               interpret, biased), at, size))
         return out
 
     def by_tap(w, at, size):
@@ -390,4 +411,9 @@ def causal_conv_silu_kernels(x, weight, parts, interpret=False, rows=None,
 
     f = jax.custom_vjp(forward)
     f.defvjp(f_fwd, f_bwd)
-    return f(x, weight)
+    if not biased:
+        return f(x, weight)
+    # the bias as the weight's last column, a tap the kernels read as one
+    # over a row of ones; jax splits the gradient back along the column
+    return f(x, jnp.concatenate(
+        [weight, jnp.asarray(bias).astype(weight.dtype)[:, None]], axis=1))

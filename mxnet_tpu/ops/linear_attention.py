@@ -68,34 +68,50 @@ def _on_tpu(x):
     return next(iter(x.devices())).platform == "tpu"
 
 
-@register("causal_conv_silu", num_outputs=lambda p: len(p["parts"]) + 1)
-def causal_conv_silu(data, weight, parts):
-    """``silu(causal_conv1d(data[..., :C], weight))`` handed on in
+def causal_conv_silu(data, weight, parts, bias=None):
+    """``silu(causal_conv1d(data[..., :C], weight) + bias)`` handed on in
     column ``parts`` (widths that sum to C, the rows of ``weight``
     (C, K)), then the columns of ``data`` (B, T, W >= C) past C as they
     are (none: an array of no columns): ``len(parts) + 1`` arrays in
-    ``data``'s dtype. On a TPU with every part on the lane grid
+    ``data``'s dtype. ``bias`` (C,), added before the SiLU, is optional
+    (None: no bias, and the same computation as a function without one).
+    On a TPU with every part on the lane grid
     (``tune.schedule.conv_silu_shape_supported``) the Pallas kernels of
     ``ops/conv_silu_kernels.py``, float32 inside with one rounding;
     ``jax.numpy`` in ``data``'s dtype everywhere else."""
     from ..tune import schedule
 
     data, weight = jnp.asarray(data), jnp.asarray(weight)
+    biases = () if bias is None else (jnp.asarray(bias),)
     parts = tuple(int(p) for p in parts)
     channels = weight.shape[0]
-    if sum(parts) != channels or data.shape[-1] < channels:
+    if sum(parts) != channels or data.shape[-1] < channels \
+            or any(b.shape != (channels,) for b in biases):
         raise ValueError(
             f"causal_conv_silu: parts {parts} of a weight {weight.shape} "
-            f"over data {data.shape}")
+            f"over data {data.shape}"
+            + "".join(f", bias {b.shape}" for b in biases))
     if _on_tpu(data) and schedule.conv_silu_shape_supported(
             parts, weight.shape[1], data.shape[-1]):
         from .conv_silu_kernels import causal_conv_silu_kernels
 
-        return causal_conv_silu_kernels(data, weight, parts)
-    mixed = jax.nn.silu(causal_conv1d(data[..., :channels], weight))
+        extra = {"bias": biases[0]} if biases else {}
+        return causal_conv_silu_kernels(data, weight, parts, **extra)
+    pre = causal_conv1d(data[..., :channels], weight)
+    for b in biases:
+        pre = pre + b.astype(pre.dtype)
+    mixed = jax.nn.silu(pre)
     firsts = [sum(parts[:n]) for n in range(1, len(parts))]
     return tuple(jnp.split(mixed, firsts, axis=-1)) \
         + (data[..., channels:],)
+
+
+@register("causal_conv_silu", num_outputs=lambda p: len(p["parts"]) + 1)
+def _causal_conv_silu_op(data, weight, *bias, parts):
+    """:func:`causal_conv_silu` as an operator: the arrays positional
+    (``data``, ``weight`` and, where there is one, ``bias``), ``parts``
+    a keyword."""
+    return causal_conv_silu(data, weight, parts, *bias)
 
 
 def _l2norm(x, eps=1e-6):

@@ -68,10 +68,12 @@ def moe_router(data, weight, bias=None, top_k=1, renormalize=True,
     return weights, experts.astype(jnp.int32)
 
 
-def _block_of_rows(x, flat_w, order, offsets, gate_up, down, top_k, block):
+def _block_of_rows(x, flat_w, order, offsets, gate_up, down, top_k, block,
+                   activation="swiglu"):
     """What the sorted assignments [block * tokens, (block + 1) *
-    tokens) add to the result: the experts' gated MLPs over those rows,
-    weighted and added back per token, (tokens, d) float32."""
+    tokens) add to the result: the experts' MLPs over those rows
+    (``activation``: :func:`moe_experts`), weighted and added back per
+    token, (tokens, d) float32."""
     n_rows = x.shape[0]
     lo = block * n_rows
     take = jax.lax.dynamic_slice_in_dim(order, lo, n_rows)
@@ -86,9 +88,12 @@ def _block_of_rows(x, flat_w, order, offsets, gate_up, down, top_k, block):
     # gradient coming back) and going out
     xs = jnp.where(valid, x[token], 0)
     h = jax.lax.ragged_dot(xs, gate_up, sizes)
-    inner = down.shape[1]
-    act = (jax.nn.silu(h[:, :inner].astype(jnp.float32))
-           * h[:, inner:].astype(jnp.float32)).astype(x.dtype)
+    if activation == "relu2":
+        act = jnp.square(jax.nn.relu(h.astype(jnp.float32))).astype(x.dtype)
+    else:
+        inner = down.shape[1]
+        act = (jax.nn.silu(h[:, :inner].astype(jnp.float32))
+               * h[:, inner:].astype(jnp.float32)).astype(x.dtype)
     ys = jax.lax.ragged_dot(act, down, sizes)
     # zeroed BEFORE the weights multiply them: selected away afterwards,
     # a row that holds a NaN still gives its weight NaN x 0 = NaN in the
@@ -97,9 +102,12 @@ def _block_of_rows(x, flat_w, order, offsets, gate_up, down, top_k, block):
     return jnp.zeros(x.shape, jnp.float32).at[token].add(ys)
 
 
+ACTIVATIONS = ("swiglu", "relu2")
+
+
 @register("moe_experts", mutate=(5,))
 def moe_experts(data, weights, experts, gate_up, down, counts,
-                first_expert=0):
+                first_expert=0, activation="swiglu"):
     """``data`` (..., d); ``weights`` / ``experts`` (..., k) from
     ``moe_router``; ``gate_up`` (held, d, 2 x inner: gate then up) and
     ``down`` (held, inner, d) of the experts
@@ -107,7 +115,13 @@ def moe_experts(data, weights, experts, gate_up, down, counts,
     ``sum_{e chosen and held} w_e * down_e(silu(gate_e x) * up_e x)``
     and moves ``counts`` (held + 1, float32): the assignments to each
     held expert in this call (every one of them is computed), and the
-    tokens that chose no held expert."""
+    tokens that chose no held expert. ``activation='relu2'`` takes
+    ungated experts (``nemotron_h``'s): ``gate_up`` is then the up
+    matrix alone, (held, d, inner), and each expert is
+    ``down_e(relu(up_e x)^2)``."""
+    if activation not in ACTIVATIONS:
+        raise ValueError(f"unknown activation {activation!r}; known: "
+                         f"{ACTIVATIONS}")
     held, d = gate_up.shape[0], data.shape[-1]
     top_k = experts.shape[-1]
     x = data.reshape(-1, d)
@@ -124,7 +138,7 @@ def moe_experts(data, weights, experts, gate_up, down, counts,
 
     def block_of_rows(block):
         return _block_of_rows(x, flat_w, order, offsets, gate_up, down,
-                              top_k, block)
+                              top_k, block, activation)
 
     def every_block():
         # every choice of every token held here is min(k, held) blocks;

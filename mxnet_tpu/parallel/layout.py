@@ -123,7 +123,9 @@ class SpecLayout:
         (``attn_q_``/``attn_k_``/``attn_v_``, ``linattn_qkvz_``/
         ``linattn_ba_``/``linattn_out_``, ``moe_experts_*``,
         ``moe_shared_gate_up_``/``moe_shared_down_``) and trinity's
-        (``attn_gate_``, ``mlp_gate_up_``/``mlp_down_``); first match wins
+        (``attn_gate_``, ``mlp_gate_up_``/``mlp_down_``) and nemotron_h's
+        (``mamba_in_``/``mamba_out_``, ``moe_experts_up_``,
+        ``moe_shared_up_``); first match wins
         and anything unmatched — norms, positional table, small biases,
         a router, a depthwise convolution — replicates, which is exactly
         the layout's intent.
@@ -133,9 +135,10 @@ class SpecLayout:
             (r".*attn_qkv_bias$", self.column_bias()),
             (r".*attn_([qkv]|gate)_weight$", self.qkv_projection()),
             (r".*linattn_(qkvz|ba)_weight$", self.qkv_projection()),
-            (r".*(attn|linattn)_out_weight$", self.attn_output()),
-            (r".*moe_experts_(gate_up|down)_weight$", self.experts()),
-            (r".*(moe_shared|mlp)_gate_up_weight$", self.ffn_up()),
+            (r".*mamba_in_weight$", self.qkv_projection()),
+            (r".*(attn|linattn|mamba)_out_weight$", self.attn_output()),
+            (r".*moe_experts_(gate_up|up|down)_weight$", self.experts()),
+            (r".*(moe_shared|mlp)_(gate_up|up)_weight$", self.ffn_up()),
             (r".*(moe_shared|mlp)_down_weight$", self.ffn_down()),
             (r".*ff1_weight$", self.ffn_up()),
             (r".*ff1_bias$", self.column_bias()),

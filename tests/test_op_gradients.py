@@ -718,6 +718,8 @@ EXPLICIT = {
     "RMSNorm", "rotary_embedding", "causal_conv1d", "gated_delta_rule",
     "moe_router", "moe_experts",
     "causal_conv_silu",     # tests/test_conv_silu_kernels.py too
+    # tests/test_nemotron_h.py: against the token-by-token recurrence
+    "mamba_chunk_scan",
 }
 
 

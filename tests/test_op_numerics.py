@@ -509,6 +509,8 @@ def test_coverage_fraction():
         "RMSNorm", "rotary_embedding", "causal_conv1d", "gated_delta_rule",
         "moe_router", "moe_experts",
         "causal_conv_silu",     # test_conv_silu_kernels.py too
+        # test_nemotron_h.py (against the token-by-token recurrence)
+        "mamba_chunk_scan",
         # test_image_ops.py
         "_image_to_tensor", "_image_normalize", "_image_flip_left_right",
         "_image_flip_top_bottom", "_image_random_flip_left_right",
