@@ -72,6 +72,11 @@ FULL = {"image": 224, "batch": 256, "steps": 5,
         # (tokens, d, experts routed over, held, top k, inner): an expert
         # layer of that cell
         "moe": (8192, 2048, 512, 16, 10, 512),
+        # the same, and its activation, through the grouped-product
+        # kernels against ragged_dot: Nemotron-H's cell (ungated, inner
+        # 1856 off the lane grid) and Trinity-Mini's
+        "moe_kernels": [((8192, 2688, 128, 8, 6, 1856), "relu2"),
+                        ((8192, 2048, 128, 8, 8, 1024), "swiglu")],
         "decode": {"b": 8, "h": 12, "d": 64, "page": 16, "pages": 256},
         # ResNet-18 stage-2 3x3 conv and the classifier, batch 128
         "conv": {"data": (128, 128, 28, 28), "weight": (128, 128, 3, 3)},
@@ -84,6 +89,8 @@ REHEARSAL = {"image": 64, "batch": 8, "steps": 2,
              "delta_rule": (1, 150, 1, 2, 128, 128),
              "conv_silu": ((1, 70, 640), (128, 256, 128), 4),
              "moe": (256, 64, 256, 16, 10, 32),
+             "moe_kernels": [((256, 96, 32, 8, 6, 48), "relu2"),
+                             ((256, 64, 32, 8, 8, 32), "swiglu")],
              "decode": {"b": 2, "h": 2, "d": 32, "page": 8, "pages": 8},
              "conv": {"data": (2, 8, 8, 8), "weight": (8, 8, 3, 3)},
              "fc": {"data": (4, 32), "weight": (16, 32)},
@@ -667,6 +674,91 @@ def moe_remat_check(shape, interpret, tag):
                 + "; ".join(f"{k} {v:.3f}" for k, v in times.items()))
 
 
+def moe_kernels_check(shape, activation, interpret, tag):
+    """One expert layer's router and grouped products in bf16 under
+    ``Remat``'s default policy, the grouped products as the Pallas
+    kernels (``ops/grouped_matmul_kernels.py``) against XLA's
+    ``jax.lax.ragged_dot`` (what the kernels replace on the chip), on
+    the same seed and a free routing: the output and the gradients of
+    the tokens, the router weight and both expert weights, largest
+    difference over the largest entry; then each form's step alone."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu import remat
+    from mxnet_tpu.ops import moe
+    from mxnet_tpu.ops.grouped_matmul_kernels import grouped_matmul_kernels
+
+    tokens, d, n_experts, held, top_k, inner = shape
+    rs = np.random.RandomState(7)
+
+    def draw(*dims, scale=1.0, dtype=jnp.bfloat16):
+        return jnp.asarray(rs.randn(*dims) * scale, dtype)
+
+    x = draw(tokens, d)
+    router_w = draw(n_experts, d, scale=d ** -0.5, dtype=jnp.float32)
+    up = inner if activation == "relu2" else 2 * inner
+    gate_up = draw(held, d, up, scale=d ** -0.5)
+    down = draw(held, inner, d, scale=inner ** -0.5)
+    weight = draw(tokens, d, dtype=jnp.float32)
+    counts = jnp.zeros(held + 1, jnp.float32)
+    names = ("out", "dx", "drouter", "dup", "ddown")
+
+    def step():
+        """The layer's step, every function new: jax keeps the trace of
+        a checkpointed function by the function, so a form must not
+        reuse the other's."""
+        def loss(x, router_w, gate_up, down):
+            weights, experts = moe.moe_router(x, router_w, top_k=top_k)
+            out = moe.moe_experts(x, weights, experts, gate_up, down,
+                                  counts, activation=activation)[0]
+            return jnp.sum(out.astype(jnp.float32) * weight), out
+
+        grad = jax.value_and_grad(
+            jax.checkpoint(loss, policy=remat.resolve_policy(None)),
+            argnums=(0, 1, 2, 3), has_aux=True)
+
+        def run(*a):
+            (_, out), grads = grad(*a)
+            return (out,) + grads
+
+        return jax.jit(run)
+
+    forms = {"kernels": lambda lhs, rhs, sizes: grouped_matmul_kernels(
+                 lhs, rhs, sizes, interpret=interpret),
+             "ragged_dot": jax.lax.ragged_dot}
+    steps, results = {}, {}
+    real = moe._grouped
+    for label, grouped in forms.items():
+        moe._grouped = grouped      # traced now: the step keeps this form
+        try:
+            steps[label] = step()
+            results[label] = jax.block_until_ready(
+                steps[label](x, router_w, gate_up, down))
+        finally:
+            moe._grouped = real
+    got, want = results["kernels"], results["ragged_dot"]
+    n_held = int(jnp.sum(moe.moe_router(x, router_w, top_k=top_k)[1]
+                         < held))
+    errs = [max_err(a, b) / max(float(jnp.max(jnp.abs(
+        b.astype(jnp.float32)))), 1e-30) for a, b in zip(got, want)]
+    log(f"{tag} expert layer {shape} {activation} bf16 under Remat "
+        f"({n_held} held assignments, {tokens} tokens): the grouped-product "
+        "kernels against ragged_dot, largest err/largest entry: "
+        + " ".join(f"{n} {e:.2e}" for n, e in zip(names, errs)))
+    check(all(bool(jnp.all(jnp.isfinite(a))) for a in got)
+          and max(errs) <= BF16_ATOL,
+          f"expert layer {shape} through the kernels outside bf16 "
+          f"tolerance: {errs}")
+    if not interpret:   # a time is the chip's or it is not written
+        times = {label: wall_ms(fn, x, router_w, gate_up, down)
+                 for label, fn in steps.items()}
+        log(f"{tag} expert layer {shape}, ms a forward + backward alone "
+            "(wall time over back-to-back calls): "
+            + "; ".join(f"{k} {v:.3f}" for k, v in times.items()))
+
+
 def kernels_phase(sz, interpret, tag):
     import jax
     import jax.numpy as jnp
@@ -733,6 +825,8 @@ def kernels_phase(sz, interpret, tag):
     delta_rule_check(sz["delta_rule"], interpret, tag)
     conv_silu_check(*sz["conv_silu"], interpret, tag)
     moe_remat_check(sz["moe"], interpret, tag)
+    for shape, activation in sz["moe_kernels"]:
+        moe_kernels_check(shape, activation, interpret, tag)
 
     b_, h_, t_, d_ = sz["flash"][0]
     blocks = tune.schedule.flash_fwd_blocks(b_ * h_, t_, d_, "bfloat16",

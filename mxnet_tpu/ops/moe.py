@@ -8,10 +8,9 @@ result that its own experts give, for the tokens routed to them; the
 exchange between devices adds the parts up. ``moe_experts`` is that
 part: told the first expert it holds (it holds as many as its weights
 have rows), it sorts the (token, expert) assignments that fall on its
-experts, runs ``jax.lax.ragged_dot`` over the sorted rows with the
-per-expert counts as group sizes (a grouped-matmul kernel on the TPU
-whose work follows the counts), and adds the weighted rows back to
-their tokens. No token is dropped for any routing: there is no capacity
+experts, runs grouped matrix products over the sorted rows with the
+per-expert counts as group sizes (:func:`_grouped`), and adds the
+weighted rows back to their tokens. No token is dropped for any routing: there is no capacity
 factor. Shapes are static, so the sorted rows are taken as many at a
 time as there are tokens: one such block serves while the assignments
 held fit it (the usual case: a ``lax.cond`` on the count), and a
@@ -25,6 +24,16 @@ expert weights, the sorted order), loop-invariant and kept once, never
 a copy per block: a ``lax.cond``'s branches all return the residuals
 of every branch, so per-block copies would be zeros that the single
 block, where it runs, writes and nothing reads.
+
+The grouped products, ``jax.lax.ragged_dot``'s mathematics: on a TPU,
+for a block whose rows lie on the sublane grid
+(``tune.schedule.grouped_mm_shape_supported``), the Pallas kernels
+``grouped_matmul`` / ``grouped_matmul_t`` of
+``ops/grouped_matmul_kernels.py`` under one ``jax.custom_vjp``, tiles
+per shape from the schedule table: their grid visits only the row tiles
+that hold a group's rows. Everywhere else ``jax.lax.ragged_dot``
+itself. Either leaves the rows past the assignments held undefined,
+and ``_block_of_rows`` selects them away.
 """
 from __future__ import annotations
 
@@ -68,6 +77,31 @@ def moe_router(data, weight, bias=None, top_k=1, renormalize=True,
     return weights, experts.astype(jnp.int32)
 
 
+def _on_tpu(x):
+    """Whether a computation on ``x`` lands on a TPU: where ``x`` lives;
+    a tracer has no device and lands on jax's default backend. A copy of
+    ``ops.linear_attention``'s: graftlint's trace-safety pass (TS001)
+    proves a helper static only inside its own module."""
+    from .pallas_kernels import pallas_available
+
+    if isinstance(x, jax.core.Tracer):
+        return pallas_available()
+    return next(iter(x.devices())).platform == "tpu"
+
+
+def _grouped(lhs, rhs, sizes):
+    """``jax.lax.ragged_dot(lhs, rhs, sizes)``: the kernels where the
+    computation lands on a TPU and the block's rows lie on their grid,
+    ``ragged_dot`` everywhere else (the module's docstring)."""
+    from ..tune import schedule
+
+    if _on_tpu(lhs) and schedule.grouped_mm_shape_supported(lhs.shape[0]):
+        from .grouped_matmul_kernels import grouped_matmul_kernels
+
+        return grouped_matmul_kernels(lhs, rhs, sizes)
+    return jax.lax.ragged_dot(lhs, rhs, sizes)
+
+
 def _block_of_rows(x, flat_w, order, offsets, gate_up, down, top_k, block,
                    activation="swiglu"):
     """What the sorted assignments [block * tokens, (block + 1) *
@@ -87,14 +121,14 @@ def _block_of_rows(x, flat_w, order, offsets, gate_up, down, top_k, block,
     # the buffer held), so they are zeroed going in (which zeroes their
     # gradient coming back) and going out
     xs = jnp.where(valid, x[token], 0)
-    h = jax.lax.ragged_dot(xs, gate_up, sizes)
+    h = _grouped(xs, gate_up, sizes)
     if activation == "relu2":
         act = jnp.square(jax.nn.relu(h.astype(jnp.float32))).astype(x.dtype)
     else:
         inner = down.shape[1]
         act = (jax.nn.silu(h[:, :inner].astype(jnp.float32))
                * h[:, inner:].astype(jnp.float32)).astype(x.dtype)
-    ys = jax.lax.ragged_dot(act, down, sizes)
+    ys = _grouped(act, down, sizes)
     # zeroed BEFORE the weights multiply them: selected away afterwards,
     # a row that holds a NaN still gives its weight NaN x 0 = NaN in the
     # backward pass, and through it the router and every layer before

@@ -87,6 +87,14 @@ CONV_SILU_ROW_CANDIDATES = (2048, 1024, 512, 256, 128, 64, 32, 16)
 CONV_SILU_COL_CANDIDATES = (2048, 1024, 512, 256, 128)
 CONV_SILU_HALO = 16
 CONV_SILU_MAX_TAPS = 8
+# The expert layers' grouped products (ops/grouped_matmul_kernels.py):
+# the sorted rows, the contraction and the output's columns one grid
+# step takes. Rows legalize to a divisor of the block's rows on the grid
+# of a 16-bit sublane tile; either width to whole lane tiles, as few as
+# the tile asked for allows (4096: every width the cells have in one),
+# the last one ragged where the width is off the lane grid.
+GROUPED_MM_ROW_CANDIDATES = (1024, 512, 256, 128)
+GROUPED_MM_COL_CANDIDATES = (4096, 2048, 1024, 512, 256, 128)
 SEARCH_SPACE = {
     # Pallas streaming flash-attention forward (ops/pallas_kernels.py);
     # also the ring-attention per-hop kernel, keyed at the hop's local
@@ -122,13 +130,24 @@ SEARCH_SPACE = {
                       "cols": CONV_SILU_COL_CANDIDATES},
     "conv_silu_bwd": {"rows": CONV_SILU_ROW_CANDIDATES,
                       "cols": CONV_SILU_COL_CANDIDATES},
+    # the grouped products (ops/grouped_matmul_kernels.py): the (rows,
+    # contraction, columns) tile of ``grouped_matmul`` (the forward, and
+    # the input gradient on the transposed weights, keyed apart) and of
+    # ``grouped_matmul_t`` (the weight gradient), keyed per (rows,
+    # contraction, columns, groups) shape
+    "grouped_mm": {"tm": GROUPED_MM_ROW_CANDIDATES,
+                   "tk": GROUPED_MM_COL_CANDIDATES,
+                   "tn": GROUPED_MM_COL_CANDIDATES},
+    "grouped_mm_t": {"tm": GROUPED_MM_ROW_CANDIDATES,
+                     "tk": GROUPED_MM_COL_CANDIDATES,
+                     "tn": GROUPED_MM_COL_CANDIDATES},
 }
 
 # What a kernel runs when the table has no entry (block sizes are
 # legalized down to the shape). The flash forward's and backward's, the
-# gated delta rule's and the short convolution's are chip-measured
-# (PERF.md, PRs 28, 31, 33 and 39); the others are the hand-written
-# pre-autotune constants.
+# gated delta rule's, the short convolution's and the grouped products'
+# are chip-measured (PERF.md, PRs 28, 31, 33, 39 and 45); the others are
+# the hand-written pre-autotune constants.
 DEFAULT_SCHEDULES = {
     "flash_fwd": {"block_q": 512, "block_k": 512},
     "flash_bwd": {"block_q": 512, "block_k": 512},
@@ -140,6 +159,11 @@ DEFAULT_SCHEDULES = {
     "delta_rule_bwd": {"chunks": 4},
     "conv_silu_fwd": {"rows": 512, "cols": 1024},
     "conv_silu_bwd": {"rows": 1024, "cols": 512},
+    # 256 rows with both widths whole read within 1.2 x of the best tile
+    # in all 18 kernels of the expert cells (PR 45); a width past 2048
+    # is tiled, so no default outgrows VMEM
+    "grouped_mm": {"tm": 256, "tk": 2048, "tn": 2048},
+    "grouped_mm_t": {"tm": 256, "tk": 2048, "tn": 2048},
 }
 
 _LOCK = threading.Lock()
@@ -367,6 +391,14 @@ def conv_silu_shape_key(b, t, channels, taps):
     """Short-convolution table key: batch, tokens, the channels of the
     part a call convolves, taps."""
     return f"b{int(b)}-t{int(t)}-c{int(channels)}-k{int(taps)}"
+
+
+def grouped_mm_shape_key(m, k, n, groups, transpose_rhs=False):
+    """Grouped-product table key: the block's rows, the contraction,
+    the output's columns, the groups; ``-rt`` where the kernel reads the
+    weights transposed (the input gradient)."""
+    key = f"m{int(m)}-k{int(k)}-n{int(n)}-g{int(groups)}"
+    return key + "-rt" if transpose_rhs else key
 
 
 def decode_shape_key(batch, pages):
@@ -737,6 +769,83 @@ def conv_silu_vmem_limit(kernel, rows, cols, taps, itemsize):
     (:func:`_vmem_limit`)."""
     return _vmem_limit(conv_silu_vmem_bytes(kernel, rows, cols, taps,
                                             itemsize))
+
+
+# ------------------------------------------- grouped-product resolution
+
+def grouped_mm_shape_supported(m):
+    """Whether the grouped-product kernels take a block of ``m`` sorted
+    rows: a row tile divides the rows on the sublane grid of a 16-bit
+    operand (two :data:`MIN_SUBLANE`); any contraction and any columns
+    (a ragged last tile is masked). What ``ops.moe`` asks before it
+    takes the kernels; everything else runs ``jax.lax.ragged_dot``."""
+    return int(m) > 0 and int(m) % (2 * MIN_SUBLANE) == 0
+
+
+def _lane_tiles(width, want):
+    """A tile of ``width`` columns in whole lane tiles: as few tiles as
+    ``want`` allows, each as narrow as covers the width in that many
+    (the last one ragged where the width is off the lane grid: 1856 in
+    one tile of 1920, two of 1024 or four of 512)."""
+    width = int(width)
+    cap = max(LANES, int(want) // LANES * LANES)
+    tiles = -(-width // cap)
+    return _pad(-(-width // tiles), LANES)
+
+
+def grouped_mm_tiles(kernel, m, k, n, groups, dtype, transpose_rhs=False,
+                     interpret=False, tm=None, tk=None, tn=None):
+    """The (rows, contraction, columns) tile of ``kernel``
+    (``grouped_mm``: lhs (m, k) x rhs[g] (k, n), ``transpose_rhs`` where
+    rhs[g] is read as (n, k); ``grouped_mm_t``: the (k, n) product of a
+    group's rows of lhs (m, k) and rhs (m, n)): the caller's (a sweep's
+    candidates), else the table's, else the default's. Rows legalize to
+    the largest multiple of the 16-bit sublane tile that is no larger
+    and divides ``m``; the widths by :func:`_lane_tiles` (never a block
+    off the lane grid: a tile that runs past its array's edge is masked
+    in the kernels where it is contracted, and cut where it is
+    written)."""
+    if tm is None or tk is None or tn is None:
+        sched = kernel_schedule(
+            kernel, grouped_mm_shape_key(m, k, n, groups, transpose_rhs),
+            str(dtype), resolve_backend(interpret))
+        tm = sched["tm"] if tm is None else tm
+        tk = sched["tk"] if tk is None else tk
+        tn = sched["tn"] if tn is None else tn
+    grain = 2 * MIN_SUBLANE
+    rows = max(grain, min(int(tm), int(m)) // grain * grain)
+    while int(m) % rows:
+        rows -= grain
+    return rows, _lane_tiles(k, tk), _lane_tiles(n, tn)
+
+
+def grouped_mm_row_tiles(m, tm, groups):
+    """The most row tiles a grouped product's grid visits: every tile
+    once, and once more for each group that starts inside a tile (the
+    weight gradient's empty groups, visited to write their zeros,
+    included)."""
+    return int(m) // int(tm) + int(groups) - 1
+
+
+def grouped_mm_vmem_bytes(kernel, tm, tk, tn, itemsize):
+    """What one grid step of a grouped-product kernel holds in VMEM: its
+    two operand tiles and its output tile, double-buffered, the float32
+    accumulator and product, and the float32 copies the masks make of
+    the operand tiles (the weight gradient's: both, and the rows'
+    transpose)."""
+    if kernel == "grouped_mm_t":
+        tiles = 2 * tm * (tk + tn) * itemsize + 2 * tk * tn * itemsize
+        work = 2 * tk * tn * 4 + tm * (2 * tk + tn) * 4
+    else:
+        tiles = 2 * (tm * tk + tk * tn) * itemsize + 2 * tm * tn * itemsize
+        work = 2 * tm * tn * 4 + (tm * tk + tk * tn) * 4
+    return tiles + work
+
+
+def grouped_mm_vmem_limit(kernel, tm, tk, tn, itemsize):
+    """A grouped-product kernel's ``vmem_limit_bytes``
+    (:func:`_vmem_limit`)."""
+    return _vmem_limit(grouped_mm_vmem_bytes(kernel, tm, tk, tn, itemsize))
 
 
 def decode_attn_block_pages(batch, pages, dtype, interpret=False,

@@ -292,6 +292,60 @@ def test_conv_silu_kernels_are_named_and_compile_at_real_widths(
     assert not over_the_sequence(compiled, "copy", "concatenate", "pad")
 
 
+# (rows, d, inner wide, held experts): an expert's two products in the
+# three expert cells (inner wide: the matrix going up, both halves of a
+# gated expert), a block of 8192 sorted rows
+_GROUPED_SHAPES = [(8192, 2688, 1856, 8), (8192, 2048, 2048, 8),
+                   (8192, 2048, 1024, 16)]
+
+
+@pytest.mark.parametrize("rows,d,wide,held", _GROUPED_SHAPES)
+def test_grouped_matmul_kernels_are_named_and_compile_at_real_widths(
+        one_chip, rows, d, wide, held):
+    """Under ``jax.grad`` of an expert's products (up, a squared ReLU,
+    down) through the kernels under the ``moe_experts`` scope:
+    ``grouped_matmul`` under the forward half (both products),
+    ``grouped_matmul`` again for the input gradients and
+    ``grouped_matmul_t`` for the weight gradients under the backward
+    half (what ``moe_*_ms_per_step`` join on), Mosaic takes the tile
+    programs at these widths (off the lane grid in Nemotron-H's), and
+    no ``ragged_dot`` is left."""
+    from mxnet_tpu.ops import grouped_matmul_kernels
+
+    inner = wide if held == 8 and d != wide else wide // 2
+
+    def loss(x, up, down, sizes):
+        with jax.named_scope("moe_experts"):
+            h = grouped_matmul_kernels.grouped_matmul_kernels(x, up, sizes)
+            a = jnp.square(jax.nn.relu(h[:, :inner]))
+            y = grouped_matmul_kernels.grouped_matmul_kernels(a, down, sizes)
+        return jnp.sum(jnp.square(y.astype(jnp.float32)))
+
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in (
+        ((rows, d), jnp.bfloat16), ((held, d, wide), jnp.bfloat16),
+        ((held, inner, d), jnp.bfloat16), ((held,), jnp.int32))]
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*args)
+    text = lowered.as_text(debug_info=True)
+    for kernel in ("grouped_matmul", "grouped_matmul_t"):
+        assert f'kernel_name = "{kernel}"' in text
+    # each kernel is traced once a shape and tile and bound again at
+    # every call site: the names are read where XLA leaves them, the
+    # compiled program's op_name of each custom call
+    compiled = lowered.compile().as_text()
+    names = [line.split('op_name="')[1].split('"')[0]
+             for line in compiled.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(names) == 6 and all(
+        "jvp(moe_experts)" in name for name in names), names
+    backward = "transpose(jvp(moe_experts))"
+    halves = sorted((name.split("/")[-2], backward in name)
+                    for name in names)
+    assert halves == [("grouped_matmul", False)] * 2 + [
+        ("grouped_matmul", True)] * 2 + [("grouped_matmul_t", True)] * 2, \
+        names
+    assert "ragged" not in compiled
+
+
 def test_no_pallas_call_in_the_package_is_left_unnamed():
     """A kernel added later is named the same way (docs/observability.md,
     "The program's own names")."""
